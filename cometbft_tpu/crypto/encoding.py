@@ -5,7 +5,7 @@ PubKeyFromTypeAndBytes, with the typed length/unsupported errors).
 The wire form is the tmproto.PublicKey oneof — field 1 = ed25519
 bytes, field 2 = secp256k1 bytes, field 3 = bls12381 bytes — exactly
 what utils/codec.encode_pubkey emits; this module is the *typed* API
-layer over it with the reference's error taxonomy.
+layer over it with the reference's error classes.
 """
 
 from __future__ import annotations
@@ -59,7 +59,7 @@ def pubkey_from_proto(b: bytes) -> PubKey:
 
 
 def pubkey_from_type_and_bytes(key_type: str, raw: bytes) -> PubKey:
-    """PubKeyFromTypeAndBytes with the reference's error taxonomy."""
+    """PubKeyFromTypeAndBytes with the reference's error classes."""
     want = _KEY_LENS.get(key_type)
     if want is None:
         raise ErrUnsupportedKey(key_type)

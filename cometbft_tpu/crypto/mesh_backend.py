@@ -24,9 +24,9 @@ _MIN_TPU_BATCH floor, set_min_tpu_batch(1) forces) goes to the mesh.
 
 from __future__ import annotations
 
-import threading
-from typing import List, Optional, Tuple
+from typing import List, Tuple
 
+from ..utils import device
 from ..utils.log import get_logger
 from .batch import (
     BatchVerifier,
@@ -38,27 +38,15 @@ from .keys import Ed25519PubKey, PubKey
 
 _log = get_logger("crypto.mesh")
 
-_DEVICES: Optional[int] = None
-_DEVICES_LOCK = threading.Lock()
-
 # Introspection: how the last mesh-backend verify dispatched
 # (tests + the bench verify-sched leg's parity gate).
 LAST_MESH = {"path": None, "n": 0, "devices": 0}
 
 
-def mesh_devices(refresh: bool = False) -> int:
-    """Local device count (cached — jax enumeration is not free), or
-    0 when the backend cannot initialize. A mesh exists when > 1."""
-    global _DEVICES
-    with _DEVICES_LOCK:
-        if _DEVICES is None or refresh:
-            try:
-                import jax
-
-                _DEVICES = len(jax.devices())
-            except Exception:  # pragma: no cover - uninitializable
-                _DEVICES = 0
-        return _DEVICES
+def mesh_devices() -> int:
+    """Local device count (memoised in utils/device); a mesh exists
+    when > 1. A backend that cannot start raises."""
+    return device.backend().count
 
 
 class MeshBatchVerifier(BatchVerifier):

@@ -70,7 +70,6 @@ WRAP = (1 << (NLIMBS * LIMB_BITS)) % P  # 2^260 mod p == 608
 # compile check), tuple form on real accelerators.
 
 _COMPACT = None  # True/False forced, None = auto
-_COMPACT_AUTO = None  # cached auto decision
 
 
 def set_compact(v) -> None:
@@ -80,20 +79,14 @@ def set_compact(v) -> None:
 
 
 def compact_mode() -> bool:
-    global _COMPACT_AUTO
     if _COMPACT is not None:
         return _COMPACT
     env = os.environ.get("GRAFT_COMPACT_FIELD")
     if env is not None:
         return env == "1"
-    if _COMPACT_AUTO is None:
-        try:
-            import jax
+    from ..utils import device
 
-            _COMPACT_AUTO = jax.default_backend() == "cpu"
-        except Exception:  # pragma: no cover - uninitializable backend
-            _COMPACT_AUTO = False
-    return _COMPACT_AUTO
+    return device.on_cpu()
 
 
 def to_limbs(x: int) -> np.ndarray:

@@ -33,6 +33,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils import device
 from . import curve25519 as curve
 from . import fe25519 as fe
 
@@ -57,27 +58,19 @@ def block_sublanes() -> int:
 
 def min_lanes() -> int:
     """Width floor for the default-on pallas ladder (bulk widths
-    only). Measured on v5e silicon (r5 first contact, docs/PERF.md):
-    at 131072 lanes the VMEM ladder is 2.5x the XLA ladder (801k vs
-    320k verifies/s); at replay widths (<=32768 lanes) both are
-    dispatch/transfer-bound and indistinguishable in steady state,
-    while the Mosaic compile is ~10x costlier per lane bucket
-    (~7-9 min vs ~40 s) and the persistent compilation cache cannot
-    amortize it (nondeterministic program fingerprint, see PERF.md) —
-    so small widths stay on the XLA ladder by default."""
+    only). The builders' r5 first contact put the VMEM ladder at 2.5x
+    the XLA ladder at 131072 lanes and found the two indistinguishable
+    at replay widths (<=32768 lanes); neither has been re-measured on
+    the current chip (ROADMAP S4), so small widths stay on the XLA
+    ladder by default. Compile cost is no longer a reason either way:
+    on a v5e the whole verify program costs ~13 min per lane bucket
+    with either ladder (757 s XLA at 32768 lanes, 773 s Pallas at
+    65536), the Mosaic kernel alone compiles in seconds, and the
+    persistent compilation cache hits for both — the Pallas program
+    only from the same checkout path with the kernel's sources
+    unmoved, because Mosaic's payload keeps their MLIR locations
+    (PERF.md, PR 24)."""
     return int(os.environ.get("GRAFT_PALLAS_MIN_LANES", "65536"))
-
-
-@functools.lru_cache(maxsize=1)
-def _accelerator_backend() -> bool:
-    """Is the default jax backend a real accelerator? Memoized: the
-    backend identity cannot change once initialized in-process (the
-    env knobs that CAN flip mid-process are read dynamically and are
-    part of ops/ed25519._ladder_backend_key)."""
-    try:
-        return jax.default_backend() != "cpu"
-    except Exception:  # pragma: no cover - backend init failure
-        return False
 
 
 def pallas_enabled(n: "int | None" = None) -> bool:
@@ -98,7 +91,13 @@ def pallas_enabled(n: "int | None" = None) -> bool:
         return False
     if n is not None and n < min_lanes():
         return False
-    return _accelerator_backend()
+    return not device.on_cpu()
+
+
+def interpret_mode() -> bool:
+    """The Pallas interpreter stands in for Mosaic on the CPU platform
+    only (tests, the virtual-device dryrun); never on an accelerator."""
+    return device.on_cpu()
 
 
 def _tree_select16(digit, entries):
@@ -272,7 +271,7 @@ def straus_pallas(ds, dh, A, shape, interpret=None):
     if s is None:
         return None
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_mode()
 
     ext = curve.identity(shape)
     entries = [curve.to_cached(ext)]

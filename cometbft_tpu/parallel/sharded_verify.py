@@ -23,23 +23,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import shard_map
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
-try:  # jax >= 0.8: top-level shard_map, check_rep renamed check_vma
-    from jax import shard_map as _shard_map
-
-    # default mirrors the jax.experimental.shard_map fallback (True) so
-    # call sites behave identically across jax versions
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=True):
-        return _shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=check_rep,
-        )
-
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 
 from ..ops import ed25519 as ed
 from .mesh import DATA_AXIS
@@ -106,9 +93,47 @@ def make_sharded_core(mesh, mode="precomp"):
         mesh=mesh,
         in_specs=in_specs,
         out_specs=spec_vec,
-        check_rep=False,
+        check_vma=False,
     )
-    return jax.jit(fn)
+    return jax.jit(fn, in_shardings=_on_mesh(mesh, in_specs))
+
+
+def _on_mesh(mesh, specs):
+    """NamedShardings for a pytree of PartitionSpecs. Given as the
+    jit's ``in_shardings`` they make a HOST array go to the devices
+    shard by shard; without them a host array lands whole on the
+    first device and is resharded from there."""
+    return jax.tree.map(
+        lambda spec: NamedSharding(mesh, spec),
+        specs,
+        is_leaf=lambda x: isinstance(x, P),
+    )
+
+
+def quorum_program(mesh):
+    """The jitted sharded tally itself: weighted sum of the verdict
+    lanes per device, one ``psum`` over ICI, the quorum compare.
+    (ok, powers, threshold) -> (quorum, tally, ok)."""
+    spec_vec = P(DATA_AXIS)
+
+    def local(ok, powers, threshold):
+        local_tally = jnp.sum(
+            jnp.where(ok, powers, 0), dtype=jnp.int32
+        )
+        tally = jax.lax.psum(local_tally, DATA_AXIS)  # rides ICI
+        return tally > threshold, tally, ok
+
+    in_specs = (spec_vec, spec_vec, P())
+    return jax.jit(
+        shard_map(
+            local,
+            mesh=mesh,
+            in_specs=in_specs,
+            out_specs=(P(), P(), spec_vec),
+            check_vma=False,
+        ),
+        in_shardings=_on_mesh(mesh, in_specs),
+    )
 
 
 def make_quorum_reducer(mesh):
@@ -125,28 +150,10 @@ def make_quorum_reducer(mesh):
     trip per job (reference VerifyCommit semantics,
     types/validation.go:30).
     """
-    spec_vec = P(DATA_AXIS)
-
-    def local(ok, powers, threshold):
-        local_tally = jnp.sum(
-            jnp.where(ok, powers, 0), dtype=jnp.int32
-        )
-        tally = jax.lax.psum(local_tally, DATA_AXIS)  # rides ICI
-        return tally > threshold, tally, ok
-
-    fn = shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(spec_vec, spec_vec, P()),
-        out_specs=(P(), P(), spec_vec),
-        check_rep=False,
-    )
-    jitted = jax.jit(fn)
+    jitted = quorum_program(mesh)
 
     def step(ok, powers, threshold):
-        import numpy as _np
-
-        total = int(_np.asarray(powers, dtype=_np.int64).sum())
+        total = int(np.asarray(powers, dtype=np.int64).sum())
         if total >= 2**31:
             raise ValueError(
                 "total voting power overflows the int32 device tally; "

@@ -21,9 +21,6 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 
 def main():
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     n_blocks = int(sys.argv[1]) if len(sys.argv) > 1 else 1500
     window = int(sys.argv[2]) if len(sys.argv) > 2 else 128
 

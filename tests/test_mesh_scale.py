@@ -73,11 +73,8 @@ import os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=6"
 sys.path.insert(0, {REPO!r})
-import jax
-jax.config.update("jax_platforms", "cpu")
-jax.config.update(
-    "jax_compilation_cache_dir", os.path.join({REPO!r}, ".jax_cache")
-)
+from cometbft_tpu.utils.device import setup_compile_cache
+setup_compile_cache()
 import numpy as np
 from cometbft_tpu.crypto import batch as cb
 from cometbft_tpu.crypto import ref_ed25519 as ref

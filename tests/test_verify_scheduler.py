@@ -92,8 +92,8 @@ class FakeDeviceHandle:
             for msg, pk, sig in ed_items
         ]
 
-    def wait_fetch(self):
-        pass
+    def wait(self):
+        return self
 
     def result(self):
         return self.verdicts
@@ -266,7 +266,7 @@ def test_mesh_route_dispatches_device(sched, restore_routing, monkeypatch):
     crypto_batch.set_default_backend("mesh")
     crypto_batch.set_min_tpu_batch(1)  # force past the batch floor
     monkeypatch.setattr(
-        mesh_mod, "mesh_devices", lambda refresh=False: 8
+        mesh_mod, "mesh_devices", lambda: 8
     )
     dispatched = []
 
@@ -291,7 +291,7 @@ def test_mesh_degrades_without_mesh(sched, restore_routing, monkeypatch):
     crypto_batch.set_default_backend("mesh")
     crypto_batch.set_min_tpu_batch(1)
     monkeypatch.setattr(
-        mesh_mod, "mesh_devices", lambda refresh=False: 1
+        mesh_mod, "mesh_devices", lambda: 1
     )
 
     def boom(ed_items):  # pragma: no cover - must never be reached
@@ -317,7 +317,7 @@ def test_mesh_degrades_on_dispatch_failure(
     crypto_batch.set_default_backend("mesh")
     crypto_batch.set_min_tpu_batch(1)
     monkeypatch.setattr(
-        mesh_mod, "mesh_devices", lambda refresh=False: 8
+        mesh_mod, "mesh_devices", lambda: 8
     )
 
     def boom(ed_items):
@@ -357,7 +357,7 @@ def test_mesh_backend_sharded_path(restore_routing, monkeypatch):
 
     crypto_batch.set_min_tpu_batch(1)
     monkeypatch.setattr(
-        mesh_mod, "mesh_devices", lambda refresh=False: 8
+        mesh_mod, "mesh_devices", lambda: 8
     )
     monkeypatch.setattr(
         ops_ed,
@@ -381,7 +381,7 @@ def test_mesh_backend_degrades_on_kernel_error(
 
     crypto_batch.set_min_tpu_batch(1)
     monkeypatch.setattr(
-        mesh_mod, "mesh_devices", lambda refresh=False: 8
+        mesh_mod, "mesh_devices", lambda: 8
     )
 
     def boom(ed_items):
