@@ -1,0 +1,3 @@
+"""scheduler (crypto/scheduler.py): stats()['degraded'] over the window, count. Moves verify_rate."""
+
+from benchmark.record import degraded_dispatches as read  # noqa: F401

@@ -1,0 +1,3 @@
+"""scheduler (crypto/scheduler.py): signatures in device dispatches over signatures submitted, %. Moves verify_rate."""
+
+from benchmark.record import device_sig_share as read  # noqa: F401
