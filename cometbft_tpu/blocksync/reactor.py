@@ -174,7 +174,15 @@ class BlockSyncReactor:
             # decode/apply — docs/PERF.md "overlapped replay dispatch")
             window = self.pool.peek_window(self.window * 2)
             if len(window) < 2:
-                await self.pool.wait_for_block()
+                # held across the await (like verify_wait below): the
+                # loop's wait for the peer, which no window span covers
+                sp = self.tracer.annotated_span(
+                    "blocksync.window.fetch_wait", tid="blocksync"
+                )
+                try:
+                    await self.pool.wait_for_block()
+                finally:
+                    sp.end()
                 continue
             try:
                 if self.ingestor is None:
@@ -209,20 +217,20 @@ class BlockSyncReactor:
         routine's plain path goes through _process_window_overlapped,
         which parks the verify wait in an executor instead."""
         t0 = time.monotonic()
-        with self.tracer.span(
+        with self.tracer.annotated_span(
             "blocksync.window.prepare", tid="blocksync"
         ):
             prep = self._prepare_window(window)
         if prep is None:
             return 0
         window, jobs, handle = prep
-        with self.tracer.span(
+        with self.tracer.annotated_span(
             "blocksync.window.verify_wait", tid="blocksync",
             jobs=len(jobs),
         ):
             errors = handle.result()
         pre = self._predispatch_lookahead(len(jobs))
-        with self.tracer.span(
+        with self.tracer.annotated_span(
             "blocksync.window.apply", tid="blocksync", jobs=len(jobs)
         ):
             applied = self._apply_window(window, jobs, errors, pre)
@@ -238,7 +246,7 @@ class BlockSyncReactor:
         threads WHILE this pass's host apply runs — overlap with no
         device required."""
         t0 = time.monotonic()
-        with self.tracer.span(
+        with self.tracer.annotated_span(
             "blocksync.window.prepare", tid="blocksync"
         ):
             prep = self._prepare_window(window)
@@ -247,7 +255,7 @@ class BlockSyncReactor:
         window, jobs, handle = prep
         # the executor-parked wait is where the verify plane's wall
         # hides (PR 3): its span length vs apply's is the overlap
-        sp = self.tracer.span(
+        sp = self.tracer.annotated_span(
             "blocksync.window.verify_wait", tid="blocksync",
             jobs=len(jobs),
         )
@@ -258,7 +266,7 @@ class BlockSyncReactor:
         finally:
             sp.end()
         pre = self._predispatch_lookahead(len(jobs))
-        with self.tracer.span(
+        with self.tracer.annotated_span(
             "blocksync.window.apply", tid="blocksync", jobs=len(jobs)
         ):
             applied = self._apply_window(window, jobs, errors, pre)
@@ -476,7 +484,7 @@ class BlockSyncReactor:
                 if self.block_store.height() < h:
                     entries.append((blk, parts, nxt.last_commit))
             if entries:
-                with self.tracer.span(
+                with self.tracer.annotated_span(
                     "blocksync.window.persist", tid="blocksync",
                     blocks=len(entries),
                 ):

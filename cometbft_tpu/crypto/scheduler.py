@@ -38,12 +38,13 @@ tests/test_verify_scheduler.py and gated in-bench by the
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
 from typing import List, Optional, Sequence, Tuple
 
-from ..trace import global_tracer
+from ..trace import global_tracer, ticket_scope
 from ..utils.log import get_logger
 from . import batch as crypto_batch
 from .keys import Ed25519PubKey
@@ -64,6 +65,19 @@ CLASS_NAMES = ("live", "light", "catchup")
 DEFAULT_PROMOTE_AFTER_S = 0.25
 DEFAULT_PROMOTE_EVERY = 4
 
+# Process-unique ticket ids: every span a ticket leaves on the process
+# tracer carries ``ticket=<id>`` (docs/TRACE.md "One ticket, one
+# timeline"); ``next()`` of a count is atomic under the GIL.
+_TICKET_IDS = itertools.count(1)
+
+# Span rows (``tid``), one a thread's role: the ticket's root and its
+# queue wait, the dispatcher thread, a device watcher thread, and
+# whichever thread resolves a host-routed ticket.
+_TID_TICKET = "crypto.sched"
+_TID_DISPATCHER = "crypto.sched.dispatcher"
+_TID_WATCHER = "crypto.sched.watcher"
+_TID_HOST = "crypto.sched.host"
+
 
 def _clamp_priority(priority) -> int:
     try:
@@ -80,15 +94,21 @@ class VerifyTicket:
     the validation seam plumbs it through unchanged."""
 
     __slots__ = (
-        "items", "priority", "label", "t_submit", "t_done", "oks",
-        "backend", "_chunks", "_units_left", "_event", "_routed",
+        "id", "items", "priority", "label", "t_submit", "t_submit_ns",
+        "t_done", "oks", "backend", "depth_ahead", "_chunks",
+        "_units_left", "_event", "_routed",
     )
 
     def __init__(self, items, priority: int, label: str) -> None:
+        self.id = next(_TICKET_IDS)
         self.items = items
         self.priority = priority
         self.label = label
         self.t_submit = time.perf_counter()
+        # the same instant on the tracer's clock: where the ticket's
+        # root span and its queue wait start
+        self.t_submit_ns = time.monotonic_ns()
+        self.depth_ahead = 0  # lanes queued ahead of it at submit
         self.t_done: Optional[float] = None
         self.oks: List[bool] = [False] * len(items)
         self.backend: Optional[str] = None
@@ -177,6 +197,7 @@ class VerifyScheduler:
             self.enqueued_by_class[priority] += n
             self._queues[priority].append(ticket)
             depth = self.enqueued_lanes - self.done_lanes
+            ticket.depth_ahead = depth - n
             if depth > self.depth_hwm:
                 self.depth_hwm = depth
             if self._thread is None:
@@ -248,6 +269,17 @@ class VerifyScheduler:
                     self._queues[ticket.priority].remove(ticket)
             try:
                 if chunk is None:
+                    # nobody worked on it since submit: a wait, so
+                    # measured after the fact and on the ring alone
+                    global_tracer().complete(
+                        "crypto.sched.queue_wait",
+                        ticket.t_submit_ns,
+                        time.monotonic_ns() - ticket.t_submit_ns,
+                        tid=_TID_TICKET,
+                        ticket=ticket.id,
+                        cls=CLASS_NAMES[ticket.priority],
+                        depth=ticket.depth_ahead,
+                    )
                     self._route(ticket)
                 else:
                     self._run_chunk(ticket, chunk)
@@ -268,6 +300,31 @@ class VerifyScheduler:
         backend-routing decision (the same decision
         crypto/batch.TpuBatchVerifier._route takes), dispatch the
         device part async, queue the host part as calibrated chunks."""
+        # the span ends before the device dispatch begins:
+        # ops.ed25519.pack / .enqueue are stages of their own
+        with global_tracer().annotated_span(
+            "crypto.sched.route", tid=_TID_DISPATCHER,
+            ticket=ticket.id, lanes=len(ticket.items),
+        ) as sp:
+            plan = self._plan(ticket)
+            sp.set(path=plan[0])
+        path, ed_idx, ed_items = plan
+        if path == "custom":
+            self._finish(ticket, len(ticket.items))
+            return
+        backend = ticket.backend
+        if path == "device" and ed_idx:
+            if self._dispatch_device(ticket, ed_idx, ed_items, backend):
+                return
+            # device dispatch failed: re-route the lanes to host
+            ticket.backend = f"{backend}-degraded"
+            self.degraded += 1
+        self._queue_host_chunks(ticket, ed_idx)
+
+    def _plan(self, ticket: VerifyTicket) -> tuple:
+        """The routing decision itself: (path, ed_idx, ed_items) with
+        path ``device`` | ``host``, or ``custom`` where a registered
+        backend has already resolved every lane."""
         items = ticket.items
         ed_idx: List[int] = []
         ed_items = []
@@ -291,8 +348,7 @@ class VerifyScheduler:
             _, oks = verifier.verify()
             ticket.oks[:] = oks
             ticket._routed = True
-            self._finish(ticket, len(items))
-            return
+            return "custom", ed_idx, ed_items
         n_ed = len(ed_items)
         forced = crypto_batch._MIN_TPU_BATCH <= 1
         cal = crypto_batch.calibration
@@ -332,13 +388,7 @@ class VerifyScheduler:
             pk, msg, sig = items[i]
             ticket.oks[i] = pk.verify(msg, sig)
         ticket._routed = True
-        if use_device and ed_idx:
-            if self._dispatch_device(ticket, ed_idx, ed_items, backend):
-                return
-            # device dispatch failed: re-route the lanes to host
-            ticket.backend = f"{backend}-degraded"
-            self.degraded += 1
-        self._queue_host_chunks(ticket, ed_idx)
+        return ("device" if use_device else "host"), ed_idx, ed_items
 
     def _dispatch_device(
         self, ticket: VerifyTicket, ed_idx, ed_items, backend: str
@@ -351,7 +401,10 @@ class VerifyScheduler:
             from ..ops import ed25519 as _ed
 
             t0 = time.perf_counter()
-            handle = _ed.verify_batch_async(ed_items)
+            # ops.ed25519.pack / .enqueue learn the ticket from the
+            # thread, not from an argument: the call keeps its shape
+            with ticket_scope(ticket.id, _TID_DISPATCHER):
+                handle = _ed.verify_batch_async(ed_items)
         except Exception as e:
             _log.error(
                 "device dispatch failed; host chunks",
@@ -366,9 +419,20 @@ class VerifyScheduler:
         cal = crypto_batch.calibration
 
         def _watch():
+            tr = global_tracer()
+            sp = None
             try:
-                handle.wait()
-                cal.observe_device(n_ed, time.perf_counter() - t0)
+                with tr.annotated_span(
+                    "crypto.sched.device_wait", tid=_TID_WATCHER,
+                    ticket=ticket.id, lanes=n_ed,
+                ):
+                    handle.wait()
+                    wall = time.perf_counter() - t0
+                sp = tr.annotated_span(
+                    "crypto.sched.resolve", tid=_TID_WATCHER,
+                    ticket=ticket.id, lanes=n_ed,
+                )
+                cal.observe_device(n_ed, wall)
                 verdicts = handle.result()
             except Exception as e:
                 _log.error(
@@ -381,7 +445,7 @@ class VerifyScheduler:
                 ]
             for i, v in zip(ed_idx, verdicts):
                 ticket.oks[i] = bool(v)
-            self._unit_done(ticket, n_ed)
+            self._unit_done(ticket, n_ed, sp)
 
         threading.Thread(
             target=_watch, name="verify-sched-dev", daemon=True
@@ -487,32 +551,44 @@ class VerifyScheduler:
 
     # --- completion ----------------------------------------------------
 
-    def _unit_done(self, ticket: VerifyTicket, lanes: int) -> None:
+    def _unit_done(self, ticket: VerifyTicket, lanes: int, sp=None) -> None:
+        """``sp``: the watcher's open ``crypto.sched.resolve`` span,
+        handed on to ``_finish`` (a device dispatch is its ticket's
+        one unit, so always the last)."""
         with self._cv:
             ticket._units_left -= 1
             last = ticket._units_left <= 0 and not ticket._chunks
         if last:
-            self._finish(ticket, len(ticket.items))
+            self._finish(ticket, len(ticket.items), sp)
 
-    def _finish(self, ticket: VerifyTicket, lanes: int) -> None:
+    def _finish(self, ticket: VerifyTicket, lanes: int, sp=None) -> None:
+        tr = global_tracer()
+        if sp is None:
+            # host-routed (or custom-backend) ticket: the verdicts were
+            # written back chunk by chunk, what is left is this
+            sp = tr.annotated_span(
+                "crypto.sched.resolve", tid=_TID_HOST,
+                ticket=ticket.id, lanes=len(ticket.items),
+            )
         ticket.t_done = time.perf_counter()
         with self._cv:
             n = len(ticket.items)
             self.done_lanes += n
             self.done_by_class[ticket.priority] += n
             self._cv.notify_all()
-        tr = global_tracer()
-        if tr.enabled:
-            tr.complete(
-                "crypto.sched.dispatch",
-                time.monotonic_ns()
-                - int((ticket.t_done - ticket.t_submit) * 1e9),
-                int((ticket.t_done - ticket.t_submit) * 1e9),
-                tid="crypto.sched",
-                cls=CLASS_NAMES[ticket.priority],
-                backend=ticket.backend or "?",
-                lanes=len(ticket.items),
-            )
+        sp.end()
+        # the ticket's root: submit (stamped then, on this clock) to
+        # here, so every stage span above lies inside it
+        tr.complete(
+            "crypto.sched.dispatch",
+            ticket.t_submit_ns,
+            time.monotonic_ns() - ticket.t_submit_ns,
+            tid=_TID_TICKET,
+            ticket=ticket.id,
+            cls=CLASS_NAMES[ticket.priority],
+            backend=ticket.backend or "?",
+            lanes=len(ticket.items),
+        )
         ticket._event.set()
 
     # --- observability / lifecycle -------------------------------------

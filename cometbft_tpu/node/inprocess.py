@@ -140,9 +140,9 @@ def build_node(
     # same discipline for the native finalize lane (one GIL-releasing
     # hash/encode pass per block, state/native_finalize.py)
     _native_finalize.prewarm()
-    # tracing plane: one ring per node; cross-node planes (the crypto
-    # worker pool) land on the process-wide tracer, enabled the first
-    # time any tracing node is built
+    # tracing plane: one ring per node; cross-node planes (the verify
+    # path) land on the process-wide tracer, on from import; a node
+    # build anchors its clock
     tracer = TRACE_NOOP
     if config.instrumentation.trace_enabled:
         tracer = Tracer(

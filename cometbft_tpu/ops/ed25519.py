@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..trace import current_ticket, global_tracer
 from ..utils import device
 from . import curve25519 as curve
 from . import fe25519 as fe
@@ -513,10 +514,37 @@ class AsyncVerdicts:
 
 def verify_batch_async(items) -> AsyncVerdicts:
     """Enqueue one verify dispatch WITHOUT blocking on the verdicts
-    (see AsyncVerdicts). Same prep/dispatch as verify_batch."""
+    (see AsyncVerdicts). Same prep/dispatch as verify_batch.
+
+    Two stage spans on the process tracer (docs/TRACE.md "One ticket,
+    one timeline"), carrying the verify ticket the calling thread
+    works for (trace.ticket_scope; None when called directly):
+    ``ops.ed25519.pack`` and ``ops.ed25519.enqueue``."""
     n = len(items)
     if n == 0:
         return AsyncVerdicts(np.zeros(0, bool), np.zeros(0, bool), 0)
+    tr = global_tracer()
+    ticket, tid = current_ticket()
+    tid = tid or "ops.ed25519"
+    with tr.annotated_span(
+        "ops.ed25519.pack", tid=tid, ticket=ticket, sigs=n
+    ) as sp:
+        fn, arrays, tuple_a, put, bad = _pack(items)
+        d = LAST_DISPATCH
+        sp.set(lanes=d["lanes"], cap=d["cap"], mode=d["mode"])
+    with tr.annotated_span(
+        "ops.ed25519.enqueue", tid=tid, ticket=ticket,
+        lanes=d["lanes"], bytes=sum(a.nbytes for a in arrays),
+    ):
+        res = _enqueue(fn, arrays, tuple_a, put)
+    return AsyncVerdicts(res, bad, n)
+
+
+def _pack(items):
+    """Bucket and kernel choice, and the per-item fill of the padded
+    host arrays. Returns (fn, host arrays in argument order, whether A
+    goes as a pytree, put, bad lanes); LAST_DISPATCH says the shape."""
+    n = len(items)
     max_len = max(len(m) for m, _, _ in items)
     cap = bucket_cap(max_len)
     np_ = _pad_n(n)
@@ -592,20 +620,33 @@ def verify_batch_async(items) -> AsyncVerdicts:
     # jnp.asarray would first land every array whole on device 0.
     put = (lambda a: a) if sharded is not None else jnp.asarray
     if tuple_a:
+        fn = sharded or verify_core_precomp_tuple_jit
+    elif use_precomp:
+        fn = sharded or verify_core_precomp_jit
+    else:
+        fn = sharded or verify_core_jit
+    arrays = (
+        (msgs, lens, a_arr, pks, rs, ss)
+        if use_precomp
+        else (msgs, lens, pks, rs, ss)
+    )
+    return fn, arrays, tuple_a, put, bad
+
+
+def _enqueue(fn, arrays, tuple_a: bool, put):
+    """``put`` of the host arrays and the jitted call, until it
+    returns (XLA dispatch is async: the device future)."""
+    if tuple_a:
         # pytree A: 80 separate (N,) arrays, preserving tuple-of-limbs
         # fusion across the jit boundary (lever #6)
-        fn = sharded or verify_core_precomp_tuple_jit
+        msgs, lens, a_arr, pks, rs, ss = arrays
         args = (
             put(msgs), put(lens), a_tree_from_stacked(a_arr, put),
             put(pks), put(rs), put(ss),
         )
-    elif use_precomp:
-        fn = sharded or verify_core_precomp_jit
-        args = tuple(put(a) for a in (msgs, lens, a_arr, pks, rs, ss))
     else:
-        fn = sharded or verify_core_jit
-        args = tuple(put(a) for a in (msgs, lens, pks, rs, ss))
-    return AsyncVerdicts(fn(*args), bad, n)
+        args = tuple(put(a) for a in arrays)
+    return fn(*args)
 
 
 def verify_batch(items) -> np.ndarray:

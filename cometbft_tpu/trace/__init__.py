@@ -12,9 +12,12 @@ Two tracer scopes:
   NodeParts.tracer and attached to the node's consensus state,
   mempool, WAL, blocksync reactor and switch.
 - **process-wide** — ``global_tracer()``: the landing zone for
-  planes shared across in-process nodes (the crypto parallel-verify
-  worker pool). Disabled until the first tracing-enabled node calls
-  ``enable_global()``; worker subprocesses never enable it, so the
+  planes shared across in-process nodes (the verify path: the commit
+  seam, the verify scheduler, the kernel dispatch, the host chunk
+  pool). On from import, like every node's own tracer, so the verify
+  path is recorded where no node is built (a light server, an
+  embedder driving the seam); ``build_node`` anchors its clock;
+  worker subprocesses switch it off (crypto/parallel_verify), so the
   pickled chunk path stays no-op there.
 
 Instrumented classes default ``self.tracer`` to the shared ``NOOP``
@@ -37,7 +40,13 @@ from .timeline import (
     merge_events,
     rebase,
 )
-from .tracer import NOOP, NOOP_SPAN, Tracer
+from .tracer import (
+    NOOP,
+    NOOP_SPAN,
+    Tracer,
+    current_ticket,
+    ticket_scope,
+)
 
 __all__ = [
     "NOOP",
@@ -47,6 +56,7 @@ __all__ = [
     "attribute_heights",
     "attribution_key",
     "chrome_trace",
+    "current_ticket",
     "enable_global",
     "format_summary",
     "format_waterfall",
@@ -57,12 +67,13 @@ __all__ = [
     "rebase",
     "summarize",
     "summarize_by_height",
+    "ticket_scope",
     "write_chrome",
     "write_jsonl",
 ]
 
-# process-wide tracer for cross-node planes (crypto worker pool)
-_GLOBAL = Tracer(name="process", size=8192, enabled=False)
+# process-wide tracer for cross-node planes (the verify path)
+_GLOBAL = Tracer(name="process", size=8192)
 
 
 def global_tracer() -> Tracer:
@@ -71,6 +82,6 @@ def global_tracer() -> Tracer:
 
 def enable_global(enabled: bool = True) -> Tracer:
     """Flip the process-wide tracer; idempotent (called by every
-    tracing-enabled node build)."""
+    tracing-enabled node build, and with False by pool workers)."""
     _GLOBAL.enabled = enabled
     return _GLOBAL

@@ -22,6 +22,13 @@ Design constraints (docs/TRACE.md):
   reads are forbidden in this package (bftlint ASY107): a span whose
   endpoints straddle an NTP step would report negative or garbage
   durations.
+- **One API, two clocks** — ``annotated_span()`` is ``span()`` that
+  also enters a ``jax.profiler.TraceAnnotation`` of the same name, so
+  whenever a profiler session runs the span sits in the ``.xplane.pb``
+  beside the device's events (docs/TRACE.md "On the device trace").
+  JAX is imported on the first such span of an ENABLED tracer, never
+  at import time; ``complete()`` spans (waits measured after the
+  fact) stay ring-only.
 
 Event slot layout (index into the slot list):
     [seq, name, ph, ts_ns, dur_ns, tid, args]
@@ -32,8 +39,9 @@ span, "i" instant, "C" counter.
 from __future__ import annotations
 
 import itertools
+import threading
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 _monotonic_ns = time.monotonic_ns
 
@@ -104,6 +112,39 @@ class _Span:
         )
 
 
+class _AnnotatedSpan(_Span):
+    """A span that is also inside a profiler annotation of its name:
+    entered before the ring's clock starts, left after it stops, so
+    the ring's span lies within the annotation's. Begin and end on
+    the same thread (the profiler records the thread that ends it)."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, tracer, name, tid, args, ann) -> None:
+        self._ann = ann
+        ann.__enter__()
+        _Span.__init__(self, tracer, name, tid, args, _monotonic_ns())
+
+    def end(self) -> None:
+        if self._tracer is None:
+            return
+        _Span.end(self)
+        self._ann.__exit__(None, None, None)
+
+
+def _jax_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where JAX is absent:
+    the spans then land on the ring alone."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except Exception:
+        return None
+    return TraceAnnotation
+
+
+_UNRESOLVED = object()
+
+
 class Tracer:
     """Fixed-size ring of trace events (see module docstring).
 
@@ -115,7 +156,7 @@ class Tracer:
 
     __slots__ = (
         "enabled", "name", "_n", "_ring", "_count", "_observers",
-        "meta",
+        "meta", "annotation",
     )
 
     def __init__(
@@ -137,6 +178,11 @@ class Tracer:
         # cross-node timelines can rebase rings from different
         # processes (ASY107 keeps wall-clock reads out of trace/)
         self.meta: Dict = {}
+        # what annotated_span() enters: ``factory(name, **args)`` giving
+        # a context manager. Resolved to jax.profiler.TraceAnnotation
+        # on first use; tests hand in a recording stand-in, None keeps
+        # every span on the ring alone.
+        self.annotation = _UNRESOLVED
 
     # --- append paths -------------------------------------------------
 
@@ -172,13 +218,31 @@ class Tracer:
             return NOOP_SPAN
         return _Span(self, name, tid, args, _monotonic_ns())
 
+    def annotated_span(self, name: str, tid: Optional[str] = None, **args):
+        """``span()`` that also sits in the profiler's trace, on the
+        device trace's clock, whenever a profiler session is running
+        (with none, the annotation is TSL's inactive TraceMe: one
+        atomic load). For spans of the verify and blocksync-window
+        paths, a dozen a ticket or a window — not for spans that fire
+        per tx or per message. ``args`` given here are the
+        annotation's too; those ``set()`` later reach the ring only."""
+        if not self.enabled:
+            return NOOP_SPAN
+        factory = self.annotation
+        if factory is _UNRESOLVED:
+            factory = self.annotation = _jax_annotation()
+        if factory is None:
+            return _Span(self, name, tid, args, _monotonic_ns())
+        return _AnnotatedSpan(self, name, tid, args, factory(name, **args))
+
     def complete(
         self, name: str, ts_ns: int, dur_ns: int,
         tid: Optional[str] = None, **args,
     ) -> None:
         """Record an already-measured complete span (callers that
         timed the work themselves, e.g. the loop watchdog's lag
-        beats); observers fire exactly as for span().end()."""
+        beats, the verify scheduler's queue wait); observers fire
+        exactly as for span().end(). Ring only: no annotation."""
         if not self.enabled:
             return
         self._append(name, "X", ts_ns, dur_ns, tid, args)
@@ -258,6 +322,41 @@ class Tracer:
             s[_SEQ] = None
             s[_NAME] = None
             s[_ARGS] = None
+
+
+# --- the verify ticket a thread is working for ---------------------------
+# The scheduler's dispatcher sets it around a device dispatch, so that
+# the spans ops/ed25519 opens beneath carry the ticket's id and the
+# dispatcher's row without an argument through verify_batch_async
+# (whose signature the benchmark's wraps replace).
+
+_ticket = threading.local()
+_NO_TICKET: Tuple[Optional[int], Optional[str]] = (None, None)
+
+
+class ticket_scope:
+    """``with ticket_scope(ticket_id, tid):`` names the verify ticket,
+    and the span row, of the calling thread for the block."""
+
+    __slots__ = ("_new", "_old")
+
+    def __init__(self, ticket: Optional[int], tid: Optional[str]) -> None:
+        self._new = (ticket, tid)
+
+    def __enter__(self) -> "ticket_scope":
+        self._old = getattr(_ticket, "v", _NO_TICKET)
+        _ticket.v = self._new
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        _ticket.v = self._old
+        return False
+
+
+def current_ticket() -> Tuple[Optional[int], Optional[str]]:
+    """(ticket id, span row) the calling thread works for, or
+    (None, None) outside any ticket_scope."""
+    return getattr(_ticket, "v", _NO_TICKET)
 
 
 # The shared disabled tracer: instrumented classes default to this so

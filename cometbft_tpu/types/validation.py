@@ -26,6 +26,7 @@ from ..crypto.scheduler import (  # re-exported: consumers pass these
     PRIORITY_LIGHT,
     PRIORITY_LIVE,
 )
+from ..trace import global_tracer
 from .block import BLOCK_ID_FLAG_COMMIT, BlockID, Commit
 from .canonical import (
     PRECOMMIT_TYPE,
@@ -34,6 +35,10 @@ from .canonical import (
 )
 from .signature_cache import SignatureCache
 from .validator_set import ValidatorSet
+
+
+# span row of the seam's two stages: whichever thread calls the seam
+_TID_CALLER = "validation"
 
 
 class CommitVerifyError(Exception):
@@ -153,17 +158,32 @@ class _BatchHandle:
         self._pending = pending
         self._cache = cache
 
-    def result(self):
+    @property
+    def ticket_id(self) -> Optional[int]:
+        """The scheduler ticket's id (the ``ticket`` of its spans), or
+        None where the cache answered every lane."""
+        return None if self._pending is None else self._pending.id
+
+    def wait(self):
+        """Block for the ticket's verdicts (None with no ticket)."""
+        if self._pending is None:
+            return None
+        return self._pending.result()[1]
+
+    def fill(self, verdicts):
+        """Per-item verdicts from the ticket's, cache fed."""
         items, cache = self._items, self._cache
         oks = [True] * len(items)
-        if self._pending is not None:
-            _, verdicts = self._pending.result()
+        if verdicts is not None:
             for i, ok in zip(self._to_verify, verdicts):
                 oks[i] = ok
                 if ok and cache is not None:
                     pk, sb, sig = items[i]
                     cache.add(sb, sig, pk.key_bytes)
         return oks
+
+    def result(self):
+        return self.fill(self.wait())
 
 
 def _run_batch(
@@ -368,6 +388,25 @@ def verify_commits_coalesced_async(
     applying window K's blocks, hiding the device+link latency behind
     host execution (reference blocksync/reactor.go:560-700 is strictly
     sequential per block)."""
+    # the ticket's first stage (docs/TRACE.md "One ticket, one
+    # timeline"): entry to submit() returned, on the caller's thread
+    with global_tracer().annotated_span(
+        "validation.coalesce.build", tid=_TID_CALLER, jobs=len(jobs)
+    ) as sp:
+        items, job_lanes, errors = _coalesce_lanes(chain_id, jobs, light)
+        batch_handle = _run_batch_async(
+            items, cache, priority=priority, label="coalesced"
+        )
+        sp.set(
+            ticket=getattr(batch_handle, "ticket_id", None),
+            lanes=len(items),
+        )
+    return _CoalescedHandle(batch_handle, jobs, job_lanes, errors)
+
+
+def _coalesce_lanes(chain_id: str, jobs, light: bool):
+    """One lane batch for every job's signatures: (items, per-job
+    [(lane_idx, val_idx)], per-job structural error or None)."""
     items = []         # global lane batch
     job_lanes = []     # per job: list of (lane_idx, val_idx)
     errors: list = [None] * len(jobs)
@@ -402,11 +441,7 @@ def verify_commits_coalesced_async(
             errors[j] = e
             lanes = []
         job_lanes.append(lanes)
-
-    batch_handle = _run_batch_async(
-        items, cache, priority=priority, label="coalesced"
-    )
-    return _CoalescedHandle(batch_handle, jobs, job_lanes, errors)
+    return items, job_lanes, errors
 
 
 class _CoalescedHandle:
@@ -422,7 +457,22 @@ class _CoalescedHandle:
         self._errors = errors
 
     def result(self):
-        oks = self._batch.result()
+        batch = self._batch
+        if not isinstance(batch, _BatchHandle):
+            # a stand-in for the batch route (tests, the benchmark's
+            # control): no ticket, so no stage to record
+            return self._fold(batch.result())
+        verdicts = batch.wait()
+        # the ticket's last stage: verdicts in hand to errors returned
+        # (cache feed + tally fold), on the caller's/executor's thread
+        with global_tracer().annotated_span(
+            "validation.coalesce.fold", tid=_TID_CALLER,
+            ticket=batch.ticket_id, jobs=len(self._jobs),
+            lanes=len(batch._items),
+        ):
+            return self._fold(batch.fill(verdicts))
+
+    def _fold(self, oks):
         errors = self._errors
         for j, (vals, block_id, height, commit) in enumerate(
             self._jobs

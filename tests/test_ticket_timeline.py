@@ -1,0 +1,288 @@
+"""One ticket, one timeline (docs/TRACE.md): a verify ticket leaves one
+span a stage on the process tracer, every one carrying its id, in
+order, inside the ticket's root span; and ops/ed25519's two stage spans
+say what LAST_DISPATCH says.
+
+The kernel PROGRAM is stubbed (the jitted callables, one level beneath
+``verify_batch_async``), so ``_pack`` and ``_enqueue`` run for real on
+the CPU backend at the smallest bucket without the minutes of kernel
+compile; the kernel's math has its own lane (test_ed25519_verify.py).
+"""
+
+import copy
+import dataclasses
+
+import numpy as np
+import pytest
+
+from cometbft_tpu.crypto import batch as crypto_batch
+from cometbft_tpu.crypto import mesh_backend as mesh_mod
+from cometbft_tpu.crypto import scheduler as sched_mod
+from cometbft_tpu.crypto.keys import Ed25519PrivKey, Ed25519PubKey
+from cometbft_tpu.node.inprocess import make_genesis
+from cometbft_tpu.ops import ed25519 as ops_ed
+from cometbft_tpu.trace import current_ticket, global_tracer, ticket_scope
+from cometbft_tpu.types.validation import verify_commits_coalesced_async
+from cometbft_tpu.utils.chaingen import make_chain
+
+BUILD = "validation.coalesce.build"
+QUEUE_WAIT = "crypto.sched.queue_wait"
+ROUTE = "crypto.sched.route"
+PACK = "ops.ed25519.pack"
+ENQUEUE = "ops.ed25519.enqueue"
+DEVICE_WAIT = "crypto.sched.device_wait"
+RESOLVE = "crypto.sched.resolve"
+FOLD = "validation.coalesce.fold"
+ROOT = "crypto.sched.dispatch"
+
+STAGES = {
+    "host": [BUILD, QUEUE_WAIT, ROUTE, RESOLVE, FOLD],
+    "device": [
+        BUILD, QUEUE_WAIT, ROUTE, PACK, ENQUEUE, DEVICE_WAIT, RESOLVE, FOLD,
+    ],
+}
+
+
+def _stub_program(*arrays):
+    """What a jitted verify program returns, by host math: the lanes
+    of the padded arrays verified one by one."""
+    msgs, lens, pks, rs, ss = (np.asarray(a) for a in (
+        arrays[0], arrays[1], arrays[-3], arrays[-2], arrays[-1]
+    ))
+    out = np.zeros(lens.shape[0], bool)
+    for i in range(lens.shape[0]):
+        if not pks[:, i].any():
+            continue  # padding
+        out[i] = Ed25519PubKey(pks[:, i].tobytes()).verify(
+            msgs[: lens[i], i].tobytes(),
+            rs[:, i].tobytes() + ss[:, i].tobytes(),
+        )
+    return out
+
+
+@pytest.fixture
+def stubbed_kernel(monkeypatch):
+    """The three kernel programs replaced; one device, so the plain
+    (unsharded) path of verify_batch_async runs whole."""
+    monkeypatch.setattr(ops_ed, "_sharded_fn", lambda mode: (1, None))
+    for name in (
+        "verify_core_jit",
+        "verify_core_precomp_jit",
+        "verify_core_precomp_tuple_jit",
+    ):
+        monkeypatch.setattr(ops_ed, name, _stub_program)
+
+
+@pytest.fixture
+def ring():
+    tr = global_tracer()
+    was = tr.enabled
+    tr.enabled = True
+    tr.clear()
+    yield tr
+    tr.enabled = was
+    tr.clear()
+
+
+@pytest.fixture
+def fresh_scheduler():
+    old_backend = crypto_batch.default_backend()
+    old_floor = crypto_batch._MIN_TPU_BATCH
+    sched_mod.set_scheduler(sched_mod.VerifyScheduler())
+    yield
+    sched_mod.set_scheduler(None)
+    crypto_batch.set_default_backend(old_backend)
+    crypto_batch.set_min_tpu_batch(old_floor)
+
+
+@pytest.fixture(scope="module")
+def chain():
+    gen, pvs = make_genesis(4, chain_id="ticket-timeline")
+    src = make_chain(gen, [pv.priv_key for pv in pvs], 6)
+    yield gen, src
+    src.close_stores()
+
+
+def _jobs(chain, heights, bad_height=None):
+    gen, src = chain
+    vs = gen.validator_set()
+    store = src.block_store
+    jobs = []
+    for h in heights:
+        commit = store.load_seen_commit(h)
+        if h == bad_height:
+            commit = copy.deepcopy(commit)
+            sig = bytearray(commit.signatures[0].signature)
+            sig[0] ^= 1
+            commit.signatures[0] = dataclasses.replace(
+                commit.signatures[0], signature=bytes(sig)
+            )
+        jobs.append((vs, store.load_block_meta(h).block_id, h, commit))
+    return gen.chain_id, jobs
+
+
+def _route(monkeypatch, route):
+    if route == "host":
+        crypto_batch.set_default_backend("cpu")
+    else:
+        crypto_batch.set_default_backend("mesh")
+        crypto_batch.set_min_tpu_batch(1)
+        monkeypatch.setattr(mesh_mod, "mesh_devices", lambda: 8)
+
+
+def _ticket_spans(ring, ticket):
+    return [
+        e for e in ring.snapshot()
+        if e["ph"] == "X" and e["args"].get("ticket") == ticket
+    ]
+
+
+@pytest.mark.parametrize("route", ["host", "device"])
+def test_ticket_leaves_every_stage_in_order(
+    route, chain, ring, fresh_scheduler, stubbed_kernel, monkeypatch
+):
+    _route(monkeypatch, route)
+    chain_id, jobs = _jobs(chain, range(1, 5), bad_height=2)
+    handle = verify_commits_coalesced_async(chain_id, jobs)
+    errors = handle.result()
+    assert [e is None for e in errors] == [True, False, True, True]
+    ticket = handle._batch.ticket_id
+    assert isinstance(ticket, int)
+
+    spans = _ticket_spans(ring, ticket)
+    by_name = {e["name"]: e for e in spans}
+    want = STAGES[route]
+    # one span a stage and the root, nothing per signature or commit
+    assert sorted(e["name"] for e in spans) == sorted(want + [ROOT])
+    end = lambda e: e["ts_ns"] + e["dur_ns"]  # noqa: E731
+    chain_of = [by_name[n] for n in want]
+    # the queue wait starts at the submit stamp, inside build (which
+    # ends when submit() has returned); every later stage starts no
+    # earlier than the one before it ended
+    build, queue_wait = chain_of[0], chain_of[1]
+    assert build["ts_ns"] <= queue_wait["ts_ns"] <= end(build)
+    for before, after in zip(chain_of[1:], chain_of[2:]):
+        assert after["ts_ns"] >= end(before), (before["name"], after["name"])
+    # the root: stamped at submit, holding every stage between the
+    # seam's two
+    root = by_name[ROOT]
+    inner = chain_of[1:-1]
+    assert root["ts_ns"] == queue_wait["ts_ns"]
+    assert end(root) >= end(by_name[RESOLVE])
+    assert sum(e["dur_ns"] for e in inner) <= root["dur_ns"]
+    assert by_name[FOLD]["ts_ns"] >= end(root)
+    # the counts each span carries
+    lanes = sum(3 for _ in jobs)  # light: 3 of 4 equal validators
+    assert build["args"]["jobs"] == len(jobs)
+    assert build["args"]["lanes"] == lanes
+    assert by_name[FOLD]["args"]["jobs"] == len(jobs)
+    assert queue_wait["args"]["cls"] == "catchup"
+    assert queue_wait["args"]["depth"] == 0
+    assert by_name[ROUTE]["args"] == {
+        "ticket": ticket, "lanes": lanes, "path": route,
+    }
+    assert root["args"]["lanes"] == lanes
+    # rows: a thread's role
+    assert build["tid"] == by_name[FOLD]["tid"] == "validation"
+    assert by_name[ROUTE]["tid"] == "crypto.sched.dispatcher"
+    if route == "device":
+        assert by_name[PACK]["tid"] == "crypto.sched.dispatcher"
+        assert by_name[PACK]["args"]["sigs"] == lanes
+        assert by_name[DEVICE_WAIT]["tid"] == "crypto.sched.watcher"
+        assert by_name[RESOLVE]["tid"] == "crypto.sched.watcher"
+    else:
+        assert by_name[RESOLVE]["tid"] == "crypto.sched.host"
+
+
+@pytest.mark.parametrize("route", ["host", "device"])
+def test_span_count_does_not_grow_with_the_ticket(
+    route, chain, ring, fresh_scheduler, stubbed_kernel, monkeypatch
+):
+    _route(monkeypatch, route)
+    counts = []
+    for heights in (range(1, 2), range(1, 6)):
+        handle = verify_commits_coalesced_async(*_jobs(chain, heights))
+        assert handle.result() == [None] * len(heights)
+        counts.append(len(_ticket_spans(ring, handle._batch.ticket_id)))
+    assert counts[0] == counts[1] == len(STAGES[route]) + 1
+
+
+def test_second_ticket_sees_the_lanes_queued_ahead(
+    chain, ring, fresh_scheduler, monkeypatch
+):
+    """``depth`` of the queue wait: lanes not yet resolved at submit."""
+    crypto_batch.set_default_backend("cpu")
+    sched = sched_mod.scheduler()
+    with sched._cv:  # the dispatcher cannot pop while both are queued
+        a = verify_commits_coalesced_async(*_jobs(chain, range(1, 3)))
+        b = verify_commits_coalesced_async(*_jobs(chain, range(3, 4)))
+    assert a.result() == [None, None] and b.result() == [None]
+    waits = {
+        e["args"]["ticket"]: e["args"]["depth"]
+        for e in ring.snapshot() if e["name"] == QUEUE_WAIT
+    }
+    assert waits == {a._batch.ticket_id: 0, b._batch.ticket_id: 6}
+
+
+def test_pack_and_enqueue_say_what_last_dispatch_says(ring, stubbed_kernel):
+    sk = Ed25519PrivKey.generate()
+    pk = sk.pub_key().key_bytes
+    items = []
+    for i in range(5):
+        msg = b"pack-enqueue-%d" % i
+        items.append((msg, pk, sk.sign(msg)))
+    items[3] = (items[3][0] + b"!", pk, items[3][2])
+    assert current_ticket() == (None, None)
+    got = ops_ed.verify_batch_async(items).wait().result()
+    assert list(got) == [True, True, True, False, True]
+    with ticket_scope(42, "some.row"):
+        assert current_ticket() == (42, "some.row")
+        ops_ed.verify_batch_async(items).result()
+    assert current_ticket() == (None, None)
+
+    last = ops_ed.LAST_DISPATCH
+    assert last["lanes"] == ops_ed.PAD_MIN  # the smallest bucket
+    ev = [e for e in ring.snapshot() if e["name"].startswith("ops.ed25519.")]
+    assert [e["name"] for e in ev] == [PACK, ENQUEUE, PACK, ENQUEUE]
+    for pack, enqueue, ticket, tid in (
+        (ev[0], ev[1], None, "ops.ed25519"),
+        (ev[2], ev[3], 42, "some.row"),
+    ):
+        assert pack["args"] == {
+            "ticket": ticket, "sigs": len(items), "lanes": last["lanes"],
+            "cap": last["cap"], "mode": last["mode"],
+        }
+        lanes = last["lanes"]
+        # msgs + lens + A (precomp) + pks, rs, ss
+        want_bytes = last["cap"] * lanes + 4 * lanes + 3 * 32 * lanes
+        if last["precomp"]:
+            want_bytes += 4 * 20 * 4 * lanes
+        assert enqueue["args"] == {
+            "ticket": ticket, "lanes": lanes, "bytes": want_bytes,
+        }
+        assert pack["tid"] == enqueue["tid"] == tid
+        assert enqueue["ts_ns"] >= pack["ts_ns"] + pack["dur_ns"]
+
+
+def test_stand_in_batch_route_still_folds(chain, ring, monkeypatch):
+    """The benchmark's control replaces ``_run_batch_async`` with an
+    object that has only ``result()``: the seam must take it (no
+    ticket, so no fold stage), not raise."""
+    from cometbft_tpu.types import validation
+
+    class AllValid:
+        def __init__(self, n):
+            self.n = n
+
+        def result(self):
+            return [True] * self.n
+
+    monkeypatch.setattr(
+        validation, "_run_batch_async",
+        lambda items, cache, priority=None, label="": AllValid(len(items)),
+    )
+    chain_id, jobs = _jobs(chain, range(1, 4), bad_height=2)
+    assert verify_commits_coalesced_async(chain_id, jobs).result() == [None] * 3
+    seam = [e for e in ring.snapshot() if e["name"].startswith("validation.")]
+    assert [e["name"] for e in seam] == [BUILD]
+    assert seam[0]["args"]["ticket"] is None
