@@ -29,6 +29,7 @@ fallback behavior, can be reproduced by AND-reducing the lane mask).
 from __future__ import annotations
 
 import functools
+import itertools
 import os
 
 import jax
@@ -519,7 +520,9 @@ def verify_batch_async(items) -> AsyncVerdicts:
     Two stage spans on the process tracer (docs/TRACE.md "One ticket,
     one timeline"), carrying the verify ticket the calling thread
     works for (trace.ticket_scope; None when called directly):
-    ``ops.ed25519.pack`` and ``ops.ed25519.enqueue``."""
+    ``ops.ed25519.pack`` (the bulk fill of the padded arrays; its
+    ``bad`` counts the lanes refused before the device) and
+    ``ops.ed25519.enqueue``."""
     n = len(items)
     if n == 0:
         return AsyncVerdicts(np.zeros(0, bool), np.zeros(0, bool), 0)
@@ -531,7 +534,10 @@ def verify_batch_async(items) -> AsyncVerdicts:
     ) as sp:
         fn, arrays, tuple_a, put, bad = _pack(items)
         d = LAST_DISPATCH
-        sp.set(lanes=d["lanes"], cap=d["cap"], mode=d["mode"])
+        sp.set(
+            lanes=d["lanes"], cap=d["cap"], mode=d["mode"],
+            bad=int(np.count_nonzero(bad)),
+        )
     with tr.annotated_span(
         "ops.ed25519.enqueue", tid=tid, ticket=ticket,
         lanes=d["lanes"], bytes=sum(a.nbytes for a in arrays),
@@ -540,13 +546,37 @@ def verify_batch_async(items) -> AsyncVerdicts:
     return AsyncVerdicts(res, bad, n)
 
 
+# one 32-byte key as one array element: distinct keys by np.unique
+_KEY32 = np.dtype((np.void, 32))
+
+
+def _join(field, good) -> bytes:
+    """The good lanes' bytes of one field, end to end."""
+    return b"".join(itertools.compress(field, good.tolist()))
+
+
+def _batch_last(rows, at, lanes: int):
+    """(len(at), width) rows of the lanes ``at`` -> (width, lanes)
+    C-contiguous, every other lane zero."""
+    out = np.zeros((lanes, rows.shape[1]), np.uint8)
+    out[at] = rows
+    return np.ascontiguousarray(out.T)
+
+
 def _pack(items):
-    """Bucket and kernel choice, and the per-item fill of the padded
-    host arrays. Returns (fn, host arrays in argument order, whether A
-    goes as a pytree, put, bad lanes); LAST_DISPATCH says the shape."""
+    """Bucket and kernel choice, and the bulk fill of the padded host
+    arrays: the lanes' bytes are joined once a field and scattered by
+    array operations, with no Python step a lane. Returns (fn, host
+    arrays in argument order, whether A goes as a pytree, put, bad
+    lanes); LAST_DISPATCH says the shape.
+
+    A lane is ``bad`` (refused before the device, all zero in every
+    array) when its key is not 32 bytes, its signature not 64, or, in
+    the precomp forms, its key fails ZIP-215 decompression."""
     n = len(items)
-    max_len = max(len(m) for m, _, _ in items)
-    cap = bucket_cap(max_len)
+    ms, pk_t, sig_t = zip(*items)
+    m_lens = np.fromiter(map(len, ms), np.int32, n)
+    cap = bucket_cap(int(m_lens.max()))  # over ALL items, bad ones too
     np_ = _pad_n(n)
     n_dev, probe = _sharded_fn("precomp")
     if probe is not None and np_ % n_dev:
@@ -568,30 +598,48 @@ def _pack(items):
     if probe is not None:
         _, sharded = _sharded_fn(mode)
 
-    msgs = np.zeros((cap, np_), np.uint8)
-    lens = np.zeros(np_, np.int32)
-    pks = np.zeros((32, np_), np.uint8)
-    rs = np.zeros((32, np_), np.uint8)
-    ss = np.zeros((32, np_), np.uint8)
-    a_arr = (
-        np.zeros((4, fe.NLIMBS, np_), np.int32) if use_precomp else None
+    good = (np.fromiter(map(len, pk_t), np.int32, n) == 32) & (
+        np.fromiter(map(len, sig_t), np.int32, n) == 64
     )
+    pk_rows = np.frombuffer(_join(pk_t, good), np.uint8).reshape(-1, 32)
+    a_arr = None
+    if use_precomp:
+        # each DISTINCT key is expanded once a dispatch (a validator
+        # set has a few hundred of them for thousands of lanes)
+        uniq, inverse = np.unique(
+            pk_rows.view(_KEY32).ravel(), return_inverse=True
+        )
+        table = np.zeros((4, fe.NLIMBS, len(uniq)), np.int32)
+        key_ok = np.zeros(len(uniq), bool)
+        for j, key in enumerate(uniq):
+            A = _expand_pubkey(key.tobytes())
+            if A is not None:  # else: fails ZIP-215 decompression
+                table[:, :, j] = A
+                key_ok[j] = True
+        a_arr = np.zeros((4, fe.NLIMBS, np_), np.int32)
+        a_arr[:, :, np.flatnonzero(good)] = table[:, :, inverse]
+        on_curve = key_ok[inverse]
+        pk_rows = pk_rows[on_curve]
+        good[good] = on_curve
+    at = np.flatnonzero(good)
     bad = np.zeros(np_, bool)
-    for i, (m, pk, sig) in enumerate(items):
-        if len(pk) != 32 or len(sig) != 64:
-            bad[i] = True
-            continue
-        if use_precomp:
-            A = _expand_pubkey(bytes(pk))
-            if A is None:  # pubkey fails ZIP-215 decompression
-                bad[i] = True
-                continue
-            a_arr[:, :, i] = A
-        msgs[: len(m), i] = np.frombuffer(m, np.uint8)
-        lens[i] = len(m)
-        pks[:, i] = np.frombuffer(pk, np.uint8)
-        rs[:, i] = np.frombuffer(sig[:32], np.uint8)
-        ss[:, i] = np.frombuffer(sig[32:], np.uint8)
+    bad[:n] = ~good
+
+    # filled row-major, (lane, byte), where a lane's bytes are one run
+    # of the joined buffer; then one transpose a field to the kernel's
+    # batch-last layout
+    lens = np.zeros(np_, np.int32)
+    lens[at] = m_lens[at]
+    m_rows = np.zeros((np_, cap), np.uint8)
+    m_rows[np.arange(cap, dtype=np.int32) < lens[:, None]] = np.frombuffer(
+        _join(ms, good), np.uint8
+    )
+    msgs = np.ascontiguousarray(m_rows.T)
+    sig_rows = np.frombuffer(_join(sig_t, good), np.uint8).reshape(-1, 64)
+    pks, rs, ss = (
+        _batch_last(rows, at, np_)
+        for rows in (pk_rows, sig_rows[:, :32], sig_rows[:, 32:])
+    )
 
     # backend_key[0] reports the ladder the kernel ACTUALLY uses at
     # this dispatch's per-device width (pallas engages by default only

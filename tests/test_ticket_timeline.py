@@ -224,7 +224,10 @@ def test_second_ticket_sees_the_lanes_queued_ahead(
     assert waits == {a._batch.ticket_id: 0, b._batch.ticket_id: 6}
 
 
-def test_pack_and_enqueue_say_what_last_dispatch_says(ring, stubbed_kernel):
+@pytest.mark.parametrize("refused", [0, 2])
+def test_pack_and_enqueue_say_what_last_dispatch_says(
+    ring, stubbed_kernel, refused
+):
     sk = Ed25519PrivKey.generate()
     pk = sk.pub_key().key_bytes
     items = []
@@ -232,9 +235,15 @@ def test_pack_and_enqueue_say_what_last_dispatch_says(ring, stubbed_kernel):
         msg = b"pack-enqueue-%d" % i
         items.append((msg, pk, sk.sign(msg)))
     items[3] = (items[3][0] + b"!", pk, items[3][2])
+    want = [True, True, True, False, True]
+    if refused:
+        # refused before the device: a 31-byte key, a 63-byte signature
+        items[1] = (items[1][0], pk[:31], items[1][2])
+        items[4] = (items[4][0], pk, items[4][2][:63])
+        want[1] = want[4] = False
     assert current_ticket() == (None, None)
     got = ops_ed.verify_batch_async(items).wait().result()
-    assert list(got) == [True, True, True, False, True]
+    assert list(got) == want
     with ticket_scope(42, "some.row"):
         assert current_ticket() == (42, "some.row")
         ops_ed.verify_batch_async(items).result()
@@ -250,7 +259,7 @@ def test_pack_and_enqueue_say_what_last_dispatch_says(ring, stubbed_kernel):
     ):
         assert pack["args"] == {
             "ticket": ticket, "sigs": len(items), "lanes": last["lanes"],
-            "cap": last["cap"], "mode": last["mode"],
+            "cap": last["cap"], "mode": last["mode"], "bad": refused,
         }
         lanes = last["lanes"]
         # msgs + lens + A (precomp) + pks, rs, ss
