@@ -433,7 +433,9 @@ class VerifyScheduler:
                     ticket=ticket.id, lanes=n_ed,
                 )
                 cal.observe_device(n_ed, wall)
-                verdicts = handle.result()
+                # ops.ed25519.fetch learns the ticket from the thread
+                with ticket_scope(ticket.id, _TID_WATCHER):
+                    verdicts = handle.result()
             except Exception as e:
                 _log.error(
                     "device resolve failed; per-item host fallback",

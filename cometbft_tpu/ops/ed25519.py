@@ -466,21 +466,27 @@ LAST_DISPATCH: dict = {}
 
 
 def _sharded_fn(mode: str):
-    """(n_devices, fn): lane-sharded verify over all local devices, or
-    (1, None) on a single device. ``mode``:
+    """(fn, shardings of its arguments): the lane-sharded verify over
+    all local devices, or (None, None) on a single device. ``mode``:
     "plain" | "precomp" | "precomp_tuple"."""
     n = device.backend().count
     if n <= 1:
-        return 1, None
+        return None, None
     # backend key: the sharded program traces through _straus too, so
     # a mid-process backend flip must map to a fresh shard_map program
     key = (n, mode, _ladder_backend_key())
     if key not in _SHARDED_FNS:
         from ..parallel.mesh import make_mesh
-        from ..parallel.sharded_verify import make_sharded_core
+        from ..parallel.sharded_verify import (
+            core_shardings,
+            make_sharded_core,
+        )
 
-        _SHARDED_FNS[key] = make_sharded_core(make_mesh(n), mode)
-    return n, _SHARDED_FNS[key]
+        mesh = make_mesh(n)
+        _SHARDED_FNS[key] = (
+            make_sharded_core(mesh, mode), core_shardings(mesh, mode)
+        )
+    return _SHARDED_FNS[key]
 
 
 class AsyncVerdicts:
@@ -491,10 +497,11 @@ class AsyncVerdicts:
     flat cost — the production pipelining seam (bench config
     "pipeline")."""
 
-    def __init__(self, res, bad, n):
+    def __init__(self, res, bad, n, devices=1):
         self._res = res
         self._bad = bad
         self._n = n
+        self._devices = devices
 
     def wait(self) -> "AsyncVerdicts":
         """Block until the device computation is READY, without
@@ -508,7 +515,16 @@ class AsyncVerdicts:
         return self
 
     def result(self) -> np.ndarray:
-        out = np.array(self._res)[: self._n]
+        """The verdicts on the host. The device-to-host read (from
+        every device that holds a shard of them) is the span
+        ``ops.ed25519.fetch``, for the ticket the calling thread works
+        for, as ``verify_batch_async``'s spans are."""
+        ticket, tid = current_ticket()
+        with global_tracer().annotated_span(
+            "ops.ed25519.fetch", tid=tid or "ops.ed25519", ticket=ticket,
+            devices=self._devices, lanes=len(self._bad),
+        ):
+            out = np.array(self._res)[: self._n]
         out[self._bad[: self._n]] = False
         return out
 
@@ -522,7 +538,8 @@ def verify_batch_async(items) -> AsyncVerdicts:
     works for (trace.ticket_scope; None when called directly):
     ``ops.ed25519.pack`` (the bulk fill of the padded arrays; its
     ``bad`` counts the lanes refused before the device) and
-    ``ops.ed25519.enqueue``."""
+    ``ops.ed25519.enqueue`` (placement of the host arrays, its child
+    span ``ops.ed25519.put``, and the jitted call)."""
     n = len(items)
     if n == 0:
         return AsyncVerdicts(np.zeros(0, bool), np.zeros(0, bool), 0)
@@ -532,18 +549,27 @@ def verify_batch_async(items) -> AsyncVerdicts:
     with tr.annotated_span(
         "ops.ed25519.pack", tid=tid, ticket=ticket, sigs=n
     ) as sp:
-        fn, arrays, tuple_a, put, bad = _pack(items)
+        fn, arrays, tuple_a, shardings, bad = _pack(items)
         d = LAST_DISPATCH
         sp.set(
             lanes=d["lanes"], cap=d["cap"], mode=d["mode"],
             bad=int(np.count_nonzero(bad)),
+            devices=d["n_devices"],
+            lanes_per_device=d["lanes"] // d["n_devices"],
         )
+    nbytes = sum(a.nbytes for a in arrays)
     with tr.annotated_span(
         "ops.ed25519.enqueue", tid=tid, ticket=ticket,
-        lanes=d["lanes"], bytes=sum(a.nbytes for a in arrays),
+        lanes=d["lanes"], bytes=nbytes,
     ):
-        res = _enqueue(fn, arrays, tuple_a, put)
-    return AsyncVerdicts(res, bad, n)
+        with tr.annotated_span(
+            "ops.ed25519.put", tid=tid, ticket=ticket,
+            devices=d["n_devices"], bytes=nbytes,
+        ):
+            args = _put(arrays, tuple_a, shardings)
+        # XLA dispatch is async: the call returns the device future
+        res = fn(*args)
+    return AsyncVerdicts(res, bad, n, d["n_devices"])
 
 
 # one 32-byte key as one array element: distinct keys by np.unique
@@ -567,8 +593,9 @@ def _pack(items):
     """Bucket and kernel choice, and the bulk fill of the padded host
     arrays: the lanes' bytes are joined once a field and scattered by
     array operations, with no Python step a lane. Returns (fn, host
-    arrays in argument order, whether A goes as a pytree, put, bad
-    lanes); LAST_DISPATCH says the shape.
+    arrays in argument order, whether A goes as a pytree, the sharded
+    program's argument shardings or None on one device, bad lanes);
+    LAST_DISPATCH says the shape.
 
     A lane is ``bad`` (refused before the device, all zero in every
     array) when its key is not 32 bytes, its signature not 64, or, in
@@ -578,8 +605,8 @@ def _pack(items):
     m_lens = np.fromiter(map(len, ms), np.int32, n)
     cap = bucket_cap(int(m_lens.max()))  # over ALL items, bad ones too
     np_ = _pad_n(n)
-    n_dev, probe = _sharded_fn("precomp")
-    if probe is not None and np_ % n_dev:
+    n_dev = device.backend().count
+    if np_ % n_dev:
         np_ += n_dev - (np_ % n_dev)
 
     # kernel choice by PER-DEVICE lane width (see PRECOMP_MAX_LANES):
@@ -594,9 +621,7 @@ def _pack(items):
         if tuple_a
         else ("precomp" if use_precomp else "plain")
     )
-    sharded = None
-    if probe is not None:
-        _, sharded = _sharded_fn(mode)
+    sharded, shardings = _sharded_fn(mode)
 
     good = (np.fromiter(map(len, pk_t), np.int32, n) == 32) & (
         np.fromiter(map(len, sig_t), np.int32, n) == 64
@@ -663,10 +688,6 @@ def _pack(items):
         # the Pallas INTERPRETER ran the ladder (CPU platform only)
         interpret=eff_pallas and interpret_mode(),
     )
-    # The sharded program takes the HOST arrays: its in_shardings
-    # (parallel/sharded_verify) send each device its own lanes, where
-    # jnp.asarray would first land every array whole on device 0.
-    put = (lambda a: a) if sharded is not None else jnp.asarray
     if tuple_a:
         fn = sharded or verify_core_precomp_tuple_jit
     elif use_precomp:
@@ -678,23 +699,26 @@ def _pack(items):
         if use_precomp
         else (msgs, lens, pks, rs, ss)
     )
-    return fn, arrays, tuple_a, put, bad
+    return fn, arrays, tuple_a, shardings, bad
 
 
-def _enqueue(fn, arrays, tuple_a: bool, put):
-    """``put`` of the host arrays and the jitted call, until it
-    returns (XLA dispatch is async: the device future)."""
+def _put(arrays, tuple_a: bool, shardings):
+    """The host arrays placed for the program, in argument order. On
+    one device each is a ``jnp.asarray``; the sharded program's go by
+    its own argument shardings (parallel/sharded_verify
+    .core_shardings), each device its own lanes, where ``jnp.asarray``
+    would first land every array whole on device 0."""
     if tuple_a:
         # pytree A: 80 separate (N,) arrays, preserving tuple-of-limbs
         # fusion across the jit boundary (lever #6)
         msgs, lens, a_arr, pks, rs, ss = arrays
-        args = (
-            put(msgs), put(lens), a_tree_from_stacked(a_arr, put),
-            put(pks), put(rs), put(ss),
+        arrays = (
+            msgs, lens, a_tree_from_stacked(a_arr, lambda a: a),
+            pks, rs, ss,
         )
-    else:
-        args = tuple(put(a) for a in arrays)
-    return fn(*args)
+    if shardings is None:
+        return jax.tree.map(jnp.asarray, arrays)
+    return jax.device_put(arrays, shardings)
 
 
 def verify_batch(items) -> np.ndarray:

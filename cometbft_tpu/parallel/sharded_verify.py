@@ -32,6 +32,55 @@ from ..ops import ed25519 as ed
 from .mesh import DATA_AXIS
 
 
+def _core_specs(mode):
+    """(per-device kernel, PartitionSpecs of its arguments) of a
+    kernel form: every argument is split along its lane axis."""
+    spec_lanes = P(None, DATA_AXIS)     # (bytes, N)
+    spec_limbs = P(None, None, DATA_AXIS)  # (4, 20, N)
+    spec_vec = P(DATA_AXIS)             # (N,)
+    if mode == "precomp":
+        return ed._verify_core_precomp, (
+            spec_lanes,  # msgs
+            spec_vec,    # lens
+            spec_limbs,  # precomputed A
+            spec_lanes,  # pks
+            spec_lanes,  # rs
+            spec_lanes,  # ss
+        )
+    if mode == "precomp_tuple":
+        # pytree A: 4 components x NLIMBS separate (N,) leaves, each
+        # lane-sharded — the spec mirrors the pytree structure
+        from ..ops import fe25519 as fe
+
+        a_specs = tuple(
+            tuple(spec_vec for _ in range(fe.NLIMBS))
+            for _ in range(4)
+        )
+        return ed._verify_core_precomp_tuple, (
+            spec_lanes,  # msgs
+            spec_vec,    # lens
+            a_specs,     # A as tuple-of-limbs pytree
+            spec_lanes,  # pks
+            spec_lanes,  # rs
+            spec_lanes,  # ss
+        )
+    return ed._verify_core, (
+        spec_lanes,  # msgs
+        spec_vec,    # lens
+        spec_lanes,  # pks
+        spec_lanes,  # rs
+        spec_lanes,  # ss
+    )
+
+
+def core_shardings(mesh, mode="precomp"):
+    """The NamedShardings of ``make_sharded_core``'s arguments, as a
+    pytree of the arguments' own structure: what
+    ``jax.device_put(host_arrays, ...)`` takes to send each device its
+    own lanes (ops/ed25519's ``ops.ed25519.put`` stage)."""
+    return _on_mesh(mesh, _core_specs(mode)[1])
+
+
 def make_sharded_core(mesh, mode="precomp"):
     """Lane-sharded verify kernel: per-device ZIP-215 verdicts, no
     cross-device communication (the tally/quorum reduction lives in
@@ -48,51 +97,12 @@ def make_sharded_core(mesh, mode="precomp"):
     whenever more than one local device is visible, so every
     VerifyCommit* caller scales over the mesh transparently.
     """
-    spec_lanes = P(None, DATA_AXIS)     # (bytes, N)
-    spec_limbs = P(None, None, DATA_AXIS)  # (4, 20, N)
-    spec_vec = P(DATA_AXIS)             # (N,)
-    if mode == "precomp":
-        inner = ed._verify_core_precomp
-        in_specs = (
-            spec_lanes,  # msgs
-            spec_vec,    # lens
-            spec_limbs,  # precomputed A
-            spec_lanes,  # pks
-            spec_lanes,  # rs
-            spec_lanes,  # ss
-        )
-    elif mode == "precomp_tuple":
-        inner = ed._verify_core_precomp_tuple
-        # pytree A: 4 components x NLIMBS separate (N,) leaves, each
-        # lane-sharded — the spec mirrors the pytree structure
-        from ..ops import fe25519 as fe
-
-        a_specs = tuple(
-            tuple(spec_vec for _ in range(fe.NLIMBS))
-            for _ in range(4)
-        )
-        in_specs = (
-            spec_lanes,  # msgs
-            spec_vec,    # lens
-            a_specs,     # A as tuple-of-limbs pytree
-            spec_lanes,  # pks
-            spec_lanes,  # rs
-            spec_lanes,  # ss
-        )
-    else:
-        inner = ed._verify_core
-        in_specs = (
-            spec_lanes,  # msgs
-            spec_vec,    # lens
-            spec_lanes,  # pks
-            spec_lanes,  # rs
-            spec_lanes,  # ss
-        )
+    inner, in_specs = _core_specs(mode)
     fn = shard_map(
         inner,
         mesh=mesh,
         in_specs=in_specs,
-        out_specs=spec_vec,
+        out_specs=P(DATA_AXIS),
         check_vma=False,
     )
     return jax.jit(fn, in_shardings=_on_mesh(mesh, in_specs))

@@ -72,8 +72,11 @@ __all__ = [
     "write_jsonl",
 ]
 
-# process-wide tracer for cross-node planes (the verify path)
-_GLOBAL = Tracer(name="process", size=8192)
+# process-wide tracer for cross-node planes (the verify path). A
+# device-routed verify ticket leaves 11 spans; the ring has to hold a
+# whole measured window of them (the benchmark's readers give nothing
+# once it has dropped one): 16,384 slots are ~1,450 tickets
+_GLOBAL = Tracer(name="process", size=16384)
 
 
 def global_tracer() -> Tracer:

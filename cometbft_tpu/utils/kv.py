@@ -118,7 +118,10 @@ class SqliteKV(KV):
                 yield bytes(k), bytes(v)
 
     def close(self):
-        self._conn.close()
+        # under the lock: closing the connection in the middle of
+        # another thread's write_batch is a segfault inside sqlite
+        with self._lock:
+            self._conn.close()
 
 
 def open_kv(backend: str, path: Optional[str] = None) -> KV:

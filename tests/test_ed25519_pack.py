@@ -3,7 +3,7 @@
 The reference below is the loop ``_pack`` ran before its fill became a
 handful of array operations, kept here as the test's own code: one
 Python step a lane, four ``np.frombuffer`` calls and five column
-writes. ``_pack`` must hand ``_enqueue`` the same arrays, value for
+writes. ``_pack`` must hand ``_put`` the same arrays, value for
 value, in shape, dtype and C-contiguity, for every input, bad lanes
 included: the verify program's HLO and its argument shapes then stay
 what they were. No kernel runs here.
@@ -17,6 +17,7 @@ import pytest
 from cometbft_tpu.crypto import ref_ed25519
 from cometbft_tpu.ops import ed25519 as ops_ed
 from cometbft_tpu.ops import fe25519 as fe
+from cometbft_tpu.utils import device
 
 PAD_MIN = 8
 CAPS = (47, 175, 431, 943)
@@ -185,10 +186,12 @@ def shapes(monkeypatch):
             monkeypatch.setenv("GRAFT_PRECOMP_TUPLE", "1")
         else:
             monkeypatch.delenv("GRAFT_PRECOMP_TUPLE", raising=False)
-        program = None if n_dev == 1 else object()
+        program = (None, None) if n_dev == 1 else (object(), object())
         monkeypatch.setattr(
-            ops_ed, "_sharded_fn", lambda mode: (n_dev, program)
+            ops_ed.device, "backend",
+            lambda: device.Backend("cpu", "cpu", n_dev),
         )
+        monkeypatch.setattr(ops_ed, "_sharded_fn", lambda mode: program)
         return program
 
     return _set
@@ -209,7 +212,8 @@ def test_pack_hands_over_the_loops_arrays(case, mode, shapes):
     shapes(mode)
     items = CASES[case]()
     want, want_bad, lanes, cap = _loop_pack(items, mode)
-    fn, arrays, tuple_a, _put, bad = ops_ed._pack(items)
+    fn, arrays, tuple_a, shardings, bad = ops_ed._pack(items)
+    assert shardings is None
     _same(arrays, want)
     _same([bad], [want_bad])
     for a, b in zip(arrays, want):  # a refused lane is zero everywhere
@@ -231,15 +235,15 @@ def test_pack_hands_over_the_loops_arrays(case, mode, shapes):
 @pytest.mark.parametrize("mode", MODES)
 def test_pack_rounds_the_lanes_up_to_the_devices(mode, shapes):
     """Three devices: 8 lanes become 9, the program is the sharded
-    one and takes the host arrays as they are."""
-    program = shapes(mode, n_dev=3)
+    one and the arrays go by its shardings."""
+    program, program_shardings = shapes(mode, n_dev=3)
     items = _with(_items([10, 20, 30, 40, 50]), 2, pk=_off_curve())
     want, want_bad, lanes, cap = _loop_pack(items, mode, n_dev=3)
     assert lanes == 9
-    fn, arrays, _tuple_a, put, bad = ops_ed._pack(items)
+    fn, arrays, _tuple_a, shardings, bad = ops_ed._pack(items)
     _same(arrays, want)
     _same([bad], [want_bad])
-    assert fn is program and put(arrays[0]) is arrays[0]
+    assert fn is program and shardings is program_shardings
     last = ops_ed.LAST_DISPATCH
     assert (last["sharded"], last["n_devices"], last["lanes"]) == (
         True, 3, 9

@@ -1,10 +1,12 @@
 """One ticket, one timeline (docs/TRACE.md): a verify ticket leaves one
 span a stage on the process tracer, every one carrying its id, in
 order, inside the ticket's root span; and ops/ed25519's two stage spans
-say what LAST_DISPATCH says.
+say what LAST_DISPATCH says, with the placement (``ops.ed25519.put``)
+and the verdict read (``ops.ed25519.fetch``) as child spans of one name
+on one device and on a mesh.
 
 The kernel PROGRAM is stubbed (the jitted callables, one level beneath
-``verify_batch_async``), so ``_pack`` and ``_enqueue`` run for real on
+``verify_batch_async``), so ``_pack`` and ``_put`` run for real on
 the CPU backend at the smallest bucket without the minutes of kernel
 compile; the kernel's math has its own lane (test_ed25519_verify.py).
 """
@@ -23,6 +25,7 @@ from cometbft_tpu.node.inprocess import make_genesis
 from cometbft_tpu.ops import ed25519 as ops_ed
 from cometbft_tpu.trace import current_ticket, global_tracer, ticket_scope
 from cometbft_tpu.types.validation import verify_commits_coalesced_async
+from cometbft_tpu.utils import device
 from cometbft_tpu.utils.chaingen import make_chain
 
 BUILD = "validation.coalesce.build"
@@ -30,6 +33,8 @@ QUEUE_WAIT = "crypto.sched.queue_wait"
 ROUTE = "crypto.sched.route"
 PACK = "ops.ed25519.pack"
 ENQUEUE = "ops.ed25519.enqueue"
+PUT = "ops.ed25519.put"
+FETCH = "ops.ed25519.fetch"
 DEVICE_WAIT = "crypto.sched.device_wait"
 RESOLVE = "crypto.sched.resolve"
 FOLD = "validation.coalesce.fold"
@@ -41,6 +46,8 @@ STAGES = {
         BUILD, QUEUE_WAIT, ROUTE, PACK, ENQUEUE, DEVICE_WAIT, RESOLVE, FOLD,
     ],
 }
+# child span -> the stage it lies inside (device-routed tickets only)
+CHILDREN = {PUT: ENQUEUE, FETCH: RESOLVE}
 
 
 def _stub_program(*arrays):
@@ -60,17 +67,40 @@ def _stub_program(*arrays):
     return out
 
 
+def _devices(monkeypatch, n):
+    """``n`` local devices, as ops/ed25519 and the mesh route see
+    them (conftest gives JAX 8 virtual ones to place arrays on)."""
+    monkeypatch.setattr(
+        device, "backend", lambda: device.Backend("cpu", "cpu", n)
+    )
+
+
 @pytest.fixture
 def stubbed_kernel(monkeypatch):
     """The three kernel programs replaced; one device, so the plain
     (unsharded) path of verify_batch_async runs whole."""
-    monkeypatch.setattr(ops_ed, "_sharded_fn", lambda mode: (1, None))
+    _devices(monkeypatch, 1)
     for name in (
         "verify_core_jit",
         "verify_core_precomp_jit",
         "verify_core_precomp_tuple_jit",
     ):
         monkeypatch.setattr(ops_ed, name, _stub_program)
+
+
+@pytest.fixture
+def stubbed_mesh(monkeypatch):
+    """Four devices and the sharded program replaced: the arrays are
+    placed by the real program's shardings, shard by shard."""
+    from cometbft_tpu.parallel.mesh import make_mesh
+    from cometbft_tpu.parallel.sharded_verify import core_shardings
+
+    _devices(monkeypatch, 4)
+    mesh = make_mesh(4)
+    monkeypatch.setattr(
+        ops_ed, "_sharded_fn",
+        lambda mode: (_stub_program, core_shardings(mesh, mode)),
+    )
 
 
 @pytest.fixture
@@ -153,7 +183,10 @@ def test_ticket_leaves_every_stage_in_order(
     by_name = {e["name"]: e for e in spans}
     want = STAGES[route]
     # one span a stage and the root, nothing per signature or commit
-    assert sorted(e["name"] for e in spans) == sorted(want + [ROOT])
+    children = list(CHILDREN) if route == "device" else []
+    assert sorted(e["name"] for e in spans) == sorted(
+        want + children + [ROOT]
+    )
     end = lambda e: e["ts_ns"] + e["dur_ns"]  # noqa: E731
     chain_of = [by_name[n] for n in want]
     # the queue wait starts at the submit stamp, inside build (which
@@ -204,7 +237,8 @@ def test_span_count_does_not_grow_with_the_ticket(
         handle = verify_commits_coalesced_async(*_jobs(chain, heights))
         assert handle.result() == [None] * len(heights)
         counts.append(len(_ticket_spans(ring, handle._batch.ticket_id)))
-    assert counts[0] == counts[1] == len(STAGES[route]) + 1
+    children = len(CHILDREN) if route == "device" else 0
+    assert counts[0] == counts[1] == len(STAGES[route]) + children + 1
 
 
 def test_second_ticket_sees_the_lanes_queued_ahead(
@@ -222,6 +256,38 @@ def test_second_ticket_sees_the_lanes_queued_ahead(
         for e in ring.snapshot() if e["name"] == QUEUE_WAIT
     }
     assert waits == {a._batch.ticket_id: 0, b._batch.ticket_id: 6}
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_put_and_fetch_are_children_with_one_name_on_both_paths(
+    devices, chain, ring, fresh_scheduler, monkeypatch, request
+):
+    """``ops.ed25519.put`` inside ``enqueue`` and ``ops.ed25519.fetch``
+    inside ``resolve``, under the ticket's id, on one device (the
+    ``jnp.asarray`` puts) and on a mesh (the arrays placed by the
+    sharded program's shardings, the verdicts read from every
+    device)."""
+    request.getfixturevalue("stubbed_kernel" if devices == 1 else "stubbed_mesh")
+    _route(monkeypatch, "device")
+    chain_id, jobs = _jobs(chain, range(1, 5), bad_height=3)
+    handle = verify_commits_coalesced_async(chain_id, jobs)
+    assert [e is None for e in handle.result()] == [True, True, False, True]
+    last = dict(ops_ed.LAST_DISPATCH)
+    assert last["sharded"] is (devices > 1)
+    assert last["n_devices"] == devices and last["lanes"] % devices == 0
+    ticket = handle._batch.ticket_id
+    by_name = {e["name"]: e for e in _ticket_spans(ring, ticket)}
+    end = lambda e: e["ts_ns"] + e["dur_ns"]  # noqa: E731
+    for child, parent in CHILDREN.items():
+        c, p = by_name[child], by_name[parent]
+        assert p["ts_ns"] <= c["ts_ns"] and end(c) <= end(p), child
+        assert c["tid"] == p["tid"]
+        assert c["args"]["ticket"] == ticket
+        assert c["args"]["devices"] == devices
+    assert by_name[PUT]["args"]["bytes"] == by_name[ENQUEUE]["args"]["bytes"]
+    assert by_name[FETCH]["args"]["lanes"] == last["lanes"]
+    assert by_name[PACK]["args"]["devices"] == devices
+    assert by_name[PACK]["args"]["lanes_per_device"] == last["lanes"] // devices
 
 
 @pytest.mark.parametrize("refused", [0, 2])
@@ -252,16 +318,18 @@ def test_pack_and_enqueue_say_what_last_dispatch_says(
     last = ops_ed.LAST_DISPATCH
     assert last["lanes"] == ops_ed.PAD_MIN  # the smallest bucket
     ev = [e for e in ring.snapshot() if e["name"].startswith("ops.ed25519.")]
-    assert [e["name"] for e in ev] == [PACK, ENQUEUE, PACK, ENQUEUE]
+    # a span is recorded at its end: the child before its parent
+    assert [e["name"] for e in ev] == [PACK, PUT, ENQUEUE, FETCH] * 2
     for pack, enqueue, ticket, tid in (
-        (ev[0], ev[1], None, "ops.ed25519"),
-        (ev[2], ev[3], 42, "some.row"),
+        (ev[0], ev[2], None, "ops.ed25519"),
+        (ev[4], ev[6], 42, "some.row"),
     ):
-        assert pack["args"] == {
-            "ticket": ticket, "sigs": len(items), "lanes": last["lanes"],
-            "cap": last["cap"], "mode": last["mode"], "bad": refused,
-        }
         lanes = last["lanes"]
+        assert pack["args"] == {
+            "ticket": ticket, "sigs": len(items), "lanes": lanes,
+            "cap": last["cap"], "mode": last["mode"], "bad": refused,
+            "devices": 1, "lanes_per_device": lanes,
+        }
         # msgs + lens + A (precomp) + pks, rs, ss
         want_bytes = last["cap"] * lanes + 4 * lanes + 3 * 32 * lanes
         if last["precomp"]:
