@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import threading
 from collections import OrderedDict
+from itertools import compress
 
 DEFAULT_CACHE_SIZE = 10_000
 
@@ -49,6 +50,37 @@ class SignatureCache:
             self._od.move_to_end(k)
             while len(self._od) > self.size:
                 self._od.popitem(last=False)
+
+    def contains_many(self, keys: list) -> list:
+        """``contains`` for a batch of ``key()`` tuples under ONE lock
+        acquisition: the list of verdicts, with the hit/miss counts
+        and the LRU order left exactly as per-key calls in the
+        batch's order leave them (a query changes no membership, so
+        every answer can be taken before any hit is touched)."""
+        with self._lock:
+            od = self._od
+            hits = list(map(od.__contains__, keys))
+            n_hits = hits.count(True)
+            if n_hits:
+                for k in compress(keys, hits):
+                    od.move_to_end(k)
+            self.hits += n_hits
+            self.misses += len(hits) - n_hits
+        return hits
+
+    def add_many(self, keys: list) -> None:
+        """``add`` for a batch of ``key()`` tuples under ONE lock
+        acquisition, same final membership, LRU order and size bound
+        as per-key calls in the batch's order: an LRU holds the
+        ``size`` most recently touched keys in order of their last
+        touch, whenever the evictions happen."""
+        with self._lock:
+            od = self._od
+            for k in keys:
+                od[k] = None
+                od.move_to_end(k)
+            for _ in range(len(od) - self.size):
+                od.popitem(last=False)
 
     def __len__(self) -> int:
         return len(self._od)

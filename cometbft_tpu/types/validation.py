@@ -17,7 +17,10 @@ the tally), identically to the reference's semantics.
 
 from __future__ import annotations
 
+from bisect import bisect_right
 from fractions import Fraction
+from itertools import accumulate, compress, count, islice
+from operator import not_
 from typing import Optional
 
 from ..crypto import scheduler as crypto_sched
@@ -27,7 +30,12 @@ from ..crypto.scheduler import (  # re-exported: consumers pass these
     PRIORITY_LIVE,
 )
 from ..trace import global_tracer
-from .block import BLOCK_ID_FLAG_COMMIT, BlockID, Commit
+from .block import (
+    BLOCK_ID_FLAG_ABSENT,
+    BLOCK_ID_FLAG_COMMIT,
+    BlockID,
+    Commit,
+)
 from .canonical import (
     PRECOMMIT_TYPE,
     finish_vote_sign_bytes,
@@ -122,17 +130,17 @@ def _run_batch_async(
     async dispatch, host-routed batches ride the slot-bounded chunk
     pipeline — either way the caller's decode/apply work proceeds
     while lanes verify (docs/PERF.md "Unified verify scheduler")."""
-    to_verify = []
-    lanes = []
-    skip = [False] * len(items)
+    # lanes: what the scheduler gets; to_verify: the item of each, or
+    # None where every item goes, in order; keys: the cache's of each
+    lanes, to_verify, keys = items, None, None
     if cache is not None:
-        for i, (pk, sb, sig) in enumerate(items):
-            if cache.contains(sb, sig, pk.key_bytes):
-                skip[i] = True
-    for i, item in enumerate(items):
-        if not skip[i]:
-            lanes.append(item)
-            to_verify.append(i)
+        key = cache.key
+        keys = [key(sb, sig, pk.key_bytes) for pk, sb, sig in items]
+        hits = cache.contains_many(keys)
+        if True in hits:
+            to_verify = _falses(hits)
+            lanes = [items[i] for i in to_verify]
+            keys = [keys[i] for i in to_verify]
     pending = (
         crypto_sched.scheduler().submit(
             lanes,
@@ -142,7 +150,7 @@ def _run_batch_async(
         if lanes
         else None
     )
-    return _BatchHandle(items, to_verify, pending, cache)
+    return _BatchHandle(items, to_verify, keys, pending, cache)
 
 
 class _BatchHandle:
@@ -150,11 +158,12 @@ class _BatchHandle:
     dispatch, fills verdicts over the cache-skipped lanes, and feeds
     verified signatures back into the cache."""
 
-    __slots__ = ("_items", "_to_verify", "_pending", "_cache")
+    __slots__ = ("_items", "_to_verify", "_keys", "_pending", "_cache")
 
-    def __init__(self, items, to_verify, pending, cache) -> None:
+    def __init__(self, items, to_verify, keys, pending, cache) -> None:
         self._items = items
         self._to_verify = to_verify
+        self._keys = keys
         self._pending = pending
         self._cache = cache
 
@@ -170,20 +179,35 @@ class _BatchHandle:
             return None
         return self._pending.result()[1]
 
+    def refused(self, verdicts) -> list:
+        """The items the ticket refused, ascending, from its verdicts;
+        the accepted lanes fed to the cache in lane order."""
+        if verdicts is None:
+            return []
+        bad = _falses(verdicts)
+        if self._cache is not None:
+            keys = self._keys
+            self._cache.add_many(
+                list(compress(keys, verdicts)) if bad else keys
+            )
+        to_verify = self._to_verify
+        return bad if to_verify is None else [to_verify[b] for b in bad]
+
     def fill(self, verdicts):
         """Per-item verdicts from the ticket's, cache fed."""
-        items, cache = self._items, self._cache
-        oks = [True] * len(items)
-        if verdicts is not None:
-            for i, ok in zip(self._to_verify, verdicts):
-                oks[i] = ok
-                if ok and cache is not None:
-                    pk, sb, sig = items[i]
-                    cache.add(sb, sig, pk.key_bytes)
+        oks = [True] * len(self._items)
+        for i in self.refused(verdicts):
+            oks[i] = False
         return oks
 
     def result(self):
         return self.fill(self.wait())
+
+
+def _falses(flags) -> list:
+    """Positions of the false entries of a vector (the refused lanes
+    of a ticket's verdicts, the misses of a cache query), ascending."""
+    return list(compress(count(), map(not_, flags)))
 
 
 def _run_batch(
@@ -393,7 +417,7 @@ def verify_commits_coalesced_async(
     with global_tracer().annotated_span(
         "validation.coalesce.build", tid=_TID_CALLER, jobs=len(jobs)
     ) as sp:
-        items, job_lanes, errors = _coalesce_lanes(chain_id, jobs, light)
+        items, plans, errors = _coalesce_lanes(chain_id, jobs, light)
         batch_handle = _run_batch_async(
             items, cache, priority=priority, label="coalesced"
         )
@@ -401,59 +425,103 @@ def verify_commits_coalesced_async(
             ticket=getattr(batch_handle, "ticket_id", None),
             lanes=len(items),
         )
-    return _CoalescedHandle(batch_handle, jobs, job_lanes, errors)
+    return _CoalescedHandle(batch_handle, jobs, plans, errors)
 
 
 def _coalesce_lanes(chain_id: str, jobs, light: bool):
-    """One lane batch for every job's signatures: (items, per-job
-    [(lane_idx, val_idx)], per-job structural error or None)."""
+    """One lane batch for every job's signatures, planned a COMMIT at
+    a time from the columns of its validator set: (items, per-job
+    plan, per-job structural error or None). A plan is (first lane,
+    validator index of every lane the job reads, tallied power of
+    those that voted for the block); a job that failed reads none."""
     items = []         # global lane batch
-    job_lanes = []     # per job: list of (lane_idx, val_idx)
+    plans = []
     errors: list = [None] * len(jobs)
     for j, (vals, block_id, height, commit) in enumerate(jobs):
-        lanes = []
+        first = len(items)
         try:
             _basic_checks(vals, commit, height, block_id)
-            total = vals.total_voting_power()
-            tallied_known = 0
-            for i, cs in enumerate(commit.signatures):
-                want = cs.for_block() if light else not cs.is_absent()
-                if not want:
-                    continue
-                val = vals.get_by_index(i)
-                if val.address != cs.validator_address:
-                    raise CommitVerifyError(
-                        f"commit sig {i} address mismatch"
-                    )
-                lanes.append((len(items), i))
-                items.append(
-                    (
-                        val.pub_key,
-                        _commit_sign_bytes(chain_id, commit, cs),
-                        cs.signature,
-                    )
-                )
-                if light and cs.for_block():
-                    tallied_known += val.voting_power
-                    if tallied_known * 3 > total * 2:
-                        break
+            want, tallied = _plan_commit(chain_id, vals, commit, light, items)
         except CommitVerifyError as e:
             errors[j] = e
-            lanes = []
-        job_lanes.append(lanes)
-    return items, job_lanes, errors
+            want, tallied = (), 0
+        plans.append((first, want, tallied))
+    return items, plans, errors
+
+
+def _plan_commit(
+    chain_id: str, vals: ValidatorSet, commit: Commit, light: bool,
+    items: list,
+):
+    """The lanes ONE commit's verification reads, appended to
+    ``items``: what the loops of verify_commit (``light=False``: every
+    non-absent vote) and _collect_light_lanes (the for-block votes up
+    to the one with which the tally passes 2/3) read, in their order,
+    from one pass over the commit's flags and slices of the set's
+    columns. Returns (validator index of each lane, tallied power of
+    the for-block ones). Raises the address mismatch of the first lane
+    that has one, after the lanes before it (the loops had appended
+    them)."""
+    cols = vals.columns()
+    sigs = commit.signatures
+    for_block = [cs.block_id_flag == BLOCK_ID_FLAG_COMMIT for cs in sigs]
+    if light:
+        # the tally after each for-block vote: read up to the first
+        # with which it passes 2/3, every one where none does
+        tally = list(accumulate(compress(cols.powers, for_block)))
+        cut = bisect_right(tally, vals.total_voting_power() * 2 // 3) + 1
+        tallied = tally[min(cut, len(tally)) - 1] if tally else 0
+        reads = for_block
+    else:
+        tallied = sum(compress(cols.powers, for_block))
+        cut = len(sigs)
+        reads = [cs.block_id_flag != BLOCK_ID_FLAG_ABSENT for cs in sigs]
+
+    def read(column) -> list:
+        return list(islice(compress(column, reads), cut))
+
+    want = read(range(len(sigs)))
+    votes = read(sigs)
+    addresses = read(cols.addresses)
+    mismatch = None
+    if [cs.validator_address for cs in votes] != addresses:
+        at = next(
+            k for k, cs in enumerate(votes)
+            if cs.validator_address != addresses[k]
+        )
+        mismatch = CommitVerifyError(f"commit sig {want[at]} address mismatch")
+        votes = votes[:at]
+    # sign bytes once a distinct timestamp (and flag class, where the
+    # nil votes are read too)
+    stamps = [cs.timestamp_ns for cs in votes]
+    if not light:
+        stamps = list(zip(read(for_block), stamps))
+    sign_bytes = {
+        stamp: _commit_sign_bytes(chain_id, commit, cs)
+        for stamp, cs in dict(zip(stamps, votes)).items()
+    }
+    items.extend(
+        zip(
+            read(cols.pub_keys),
+            map(sign_bytes.__getitem__, stamps),
+            [cs.signature for cs in votes],
+        )
+    )
+    if mismatch is not None:
+        raise mismatch
+    return want, tallied
 
 
 class _CoalescedHandle:
     """``result()`` blocks for the lane verdicts and folds them back
     into per-job errors (tally + 2/3 check per commit)."""
 
-    __slots__ = ("_batch", "_jobs", "_job_lanes", "_errors")
+    __slots__ = ("_batch", "_jobs", "_plans", "_errors")
 
-    def __init__(self, batch, jobs, job_lanes, errors) -> None:
+    def __init__(self, batch, jobs, plans, errors) -> None:
         self._batch = batch
         self._jobs = jobs
-        self._job_lanes = job_lanes
+        self._plans = plans
         self._errors = errors
 
     def result(self):
@@ -461,7 +529,7 @@ class _CoalescedHandle:
         if not isinstance(batch, _BatchHandle):
             # a stand-in for the batch route (tests, the benchmark's
             # control): no ticket, so no stage to record
-            return self._fold(batch.result())
+            return self._fold(_falses(batch.result()))
         verdicts = batch.wait()
         # the ticket's last stage: verdicts in hand to errors returned
         # (cache feed + tally fold), on the caller's/executor's thread
@@ -470,32 +538,30 @@ class _CoalescedHandle:
             ticket=batch.ticket_id, jobs=len(self._jobs),
             lanes=len(batch._items),
         ):
-            return self._fold(batch.fill(verdicts))
+            return self._fold(batch.refused(verdicts))
 
-    def _fold(self, oks):
-        errors = self._errors
-        for j, (vals, block_id, height, commit) in enumerate(
-            self._jobs
-        ):
-            if errors[j] is not None:
-                continue
-            tallied = 0
-            bad = None
-            for lane, i in self._job_lanes[j]:
-                if not oks[lane]:
-                    bad = ErrInvalidSignature(
-                        f"invalid signature for validator {i} "
-                        f"at height {height}"
-                    )
-                    break
-                if commit.signatures[i].for_block():
-                    tallied += vals.get_by_index(i).voting_power
-            if bad is not None:
-                errors[j] = bad
-            elif not tallied * 3 > vals.total_voting_power() * 2:
-                errors[j] = ErrNotEnoughVotingPower(
-                    f"height {height}: tallied {tallied} <= 2/3"
+    def _fold(self, refused):
+        """``refused``: the lanes of the batch with no valid
+        signature, ascending. A job's first one names its error; a job
+        with none passed every lane it reads, so its tally is the
+        plan's."""
+        errors, jobs, plans = self._errors, self._jobs, self._plans
+        firsts = [first for first, _, _ in plans]
+        for lane in refused:
+            j = bisect_right(firsts, lane) - 1
+            if errors[j] is None:
+                first, want, _ = plans[j]
+                errors[j] = ErrInvalidSignature(
+                    f"invalid signature for validator {want[lane - first]} "
+                    f"at height {jobs[j][2]}"
                 )
+        for j, (vals, _, height, _) in enumerate(jobs):
+            if errors[j] is None:
+                tallied = plans[j][2]
+                if not tallied * 3 > vals.total_voting_power() * 2:
+                    errors[j] = ErrNotEnoughVotingPower(
+                        f"height {height}: tallied {tallied} <= 2/3"
+                    )
         return errors
 
 

@@ -10,7 +10,7 @@ centering and scaling to bound priority spread
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
 from ..crypto import merkle
 from ..crypto.keys import PubKey
@@ -55,6 +55,17 @@ class Validator:
         return 0
 
 
+class ValidatorColumns(NamedTuple):
+    """A validator set read by columns, in set order: what commit
+    verification reads of every validator, once a set and not once a
+    signature (types/validation.py plans a commit by selections of
+    these)."""
+
+    addresses: List[bytes]
+    pub_keys: List[PubKey]
+    powers: List[int]
+
+
 class ValidatorSet:
     def __init__(self, validators: Sequence[Validator]):
         vals = [v.copy() for v in validators]
@@ -91,6 +102,22 @@ class ValidatorSet:
             self._total_power = tp
         return tp
 
+    def columns(self) -> ValidatorColumns:
+        # memoized like total_voting_power(): a set lives for
+        # thousands of heights and every commit of every one of them
+        # is verified against these. Addresses, keys and powers only
+        # change through update_with_change_set, which drops the memo.
+        cols = getattr(self, "_columns", None)
+        if cols is None:
+            vals = self.validators
+            cols = ValidatorColumns(
+                [v.address for v in vals],
+                [v.pub_key for v in vals],
+                [v.voting_power for v in vals],
+            )
+            self._columns = cols
+        return cols
+
     def has_address(self, addr: bytes) -> bool:
         return addr in self._by_address
 
@@ -124,6 +151,9 @@ class ValidatorSet:
         vs._by_address = dict(self._by_address)
         vs._hash = getattr(self, "_hash", None)
         vs._total_power = getattr(self, "_total_power", None)
+        # immutable by convention and equal for the copy (priorities
+        # are not in them): shared, like the hash
+        vs._columns = getattr(self, "_columns", None)
         vs.proposer = (
             None
             if self.proposer is None
@@ -249,9 +279,10 @@ class ValidatorSet:
         new_vals.sort(key=lambda v: (-v.voting_power, v.address))
         self.validators = new_vals
         self._by_address = {v.address: i for i, v in enumerate(new_vals)}
-        # membership/power changed: drop both memos
+        # membership/power changed: drop the memos
         self._hash = None
         self._total_power = None
+        self._columns = None
         self._shift_by_avg_proposer_priority()
         self.proposer = self._compute_max_priority_validator()
 

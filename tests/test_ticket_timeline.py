@@ -363,3 +363,35 @@ def test_stand_in_batch_route_still_folds(chain, ring, monkeypatch):
     seam = [e for e in ring.snapshot() if e["name"].startswith("validation.")]
     assert [e["name"] for e in seam] == [BUILD]
     assert seam[0]["args"]["ticket"] is None
+
+
+@pytest.mark.parametrize("cached", [False, True], ids=["no-cache", "cache"])
+def test_seam_spans_keep_their_names_rows_and_args(
+    cached, chain, ring, fresh_scheduler
+):
+    """The seam plans and folds by columns since PR 31; what its two
+    spans say did not move (22 readers sit on them): the names, the
+    row, and exactly ``ticket``, ``jobs``, ``lanes``: there is no
+    per-signature fallback to count. ``lanes`` is every lane of the
+    batch, cache hits included."""
+    from cometbft_tpu.types.signature_cache import SignatureCache
+
+    crypto_batch.set_default_backend("cpu")
+    cache = SignatureCache() if cached else None
+    chain_id, jobs = _jobs(chain, range(1, 5), bad_height=2)
+    tickets = []
+    for window in (jobs[:3], jobs[1:]):  # the second hits the cache
+        handle = verify_commits_coalesced_async(chain_id, window, cache=cache)
+        errors = handle.result()
+        assert [e is None for e in errors] == [
+            j[2] != 2 for j in window
+        ]
+        tickets.append(handle._batch.ticket_id)
+    seam = [e for e in ring.snapshot() if e["name"].startswith("validation.")]
+    assert [e["name"] for e in seam] == [BUILD, FOLD] * 2
+    for e, ticket in zip(seam, [tickets[0]] * 2 + [tickets[1]] * 2):
+        assert e["tid"] == "validation" and e["ph"] == "X"
+        assert e["args"] == {"ticket": ticket, "jobs": 3, "lanes": 9}
+    if cached:
+        # heights 2 (its two good lanes) and 3 were fed by the first
+        assert (cache.hits, cache.misses) == (5, 9 + 4)
