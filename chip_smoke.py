@@ -113,8 +113,8 @@ def cache_entries(cache_dir: str) -> int:
 
 def build_chain(seed: int, n_vals: int, n_blocks: int, home: str):
     """(genesis, source NodeParts): a signed chain in a sqlite store
-    under ``home`` (the corpus of bench.py:_corpus, from a seed and a
-    seed-derived genesis time instead of the clock)."""
+    under ``home``, from a seed and a seed-derived genesis time
+    instead of the clock."""
     import numpy as np
 
     import cometbft_tpu.types as T
@@ -279,7 +279,7 @@ def phase_catchup(
         b = unpinned_bucket(n)
         say(
             f"  {n} -> {d['lanes']} | {b} "
-            f"{'precomp' if b <= ed._precomp_max_lanes() else 'plain'}"
+            f"{'precomp' if b <= ed.PRECOMP_MAX_LANES else 'plain'}"
         )
     say(
         f"catchup: calibration flat_s={cal.flat_s!r} lane_s={cal.lane_s!r} "
@@ -405,21 +405,25 @@ def compare_verdicts(name, items, bad, got, ref_all: bool) -> list:
 
 def phase_verdicts(src, chain_id: str, seed: int, n_commits: int) -> None:
     from cometbft_tpu.crypto import batch as crypto_batch
+    from cometbft_tpu.crypto import scheduler as crypto_sched
     from cometbft_tpu.crypto.keys import Ed25519PubKey
     from cometbft_tpu.ops import ed25519 as ed
 
-    # one commit through the backend with the device forced
+    # one commit through the scheduler, the path the node takes, with
+    # the device forced
     commit, bad = corrupt(commit_lanes(src, chain_id, [2]), seed, 1)
     crypto_batch.set_default_backend("tpu")
     floor = crypto_batch._MIN_TPU_BATCH
     crypto_batch.set_min_tpu_batch(1)
     try:
-        v = crypto_batch.create_batch_verifier()
-        for msg, pk, sig in commit:
-            v.add(Ed25519PubKey(pk), msg, sig)
-        _, got = v.verify()
+        ticket = crypto_sched.scheduler().submit(
+            [(Ed25519PubKey(pk), msg, sig) for msg, pk, sig in commit],
+            label="chip-smoke",
+        )
+        _, got = ticket.result(timeout=1800.0)
     finally:
         crypto_batch.set_min_tpu_batch(floor)
+    need(ticket.backend == "tpu", ticket.backend)
     need(crypto_batch.LAST_ROUTE["path"] == "device", crypto_batch.LAST_ROUTE)
     check_dispatch(ed.LAST_DISPATCH, "xla", 1)
     compare_verdicts("commit", commit, bad, got, ref_all=True)

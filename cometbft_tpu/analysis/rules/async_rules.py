@@ -842,9 +842,8 @@ def unbounded_delete_in_hot_plane(ctx: FileContext) -> List[Finding]:
 
 
 # Verify-consumer planes that must dispatch signature batches through
-# the unified scheduler (crypto/scheduler.py) rather than building
-# their own BatchVerifier / reaching the parallel-verify pool
-# directly: a bypass verifies OUTSIDE the priority classes, so a
+# the unified scheduler (crypto/scheduler.py) rather than verifying
+# a batch themselves / reaching the parallel-verify pool directly: a bypass verifies OUTSIDE the priority classes, so a
 # catch-up storm it spawns can starve the live round the scheduler
 # exists to protect (ASY121). The sanctioned seams are crypto/ itself
 # and types/validation (the choke point every plane submits through).
@@ -856,22 +855,16 @@ _ASY121_PREFIXES = (
     "cometbft_tpu/evidence/",
 )
 
-# direct-construction spellings of the batch-verifier backends plus
-# the factory; any of these in a hot plane is an unscheduled verify
-_ASY121_CTORS = {
-    "CpuBatchVerifier",
-    "CpuParallelBatchVerifier",
-    "TpuBatchVerifier",
-    "MeshBatchVerifier",
-    "create_batch_verifier",
-}
+# the serial reference verifier (crypto/batch.py: tests compare
+# against it); constructed in a hot plane it is an unscheduled verify
+_ASY121_CTORS = {"CpuBatchVerifier"}
 
 
 @rule(
     "ASY121",
     "verify-bypass-scheduler",
     "a hot-plane module (consensus/blocksync/light/statesync/"
-    "evidence) constructing a BatchVerifier or reaching the "
+    "evidence) constructing a CpuBatchVerifier or reaching the "
     "parallel-verify pool directly: signature work dispatched outside "
     "the unified scheduler's priority classes can starve the live "
     "round — submit through crypto/scheduler.py (the types/validation "

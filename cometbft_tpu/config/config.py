@@ -291,30 +291,27 @@ class InstrumentationConfig:
 
 @dataclass
 class CryptoConfig:
-    """TPU-native addition: signature-verification backend knobs.
+    """TPU-native addition: the signature-verification backend knob.
 
-    batch_backend names an entry in the crypto/batch.py backend
-    registry: "tpu" (device lanes, host-routed batches ride the
-    parallel plane), "cpu" (serial host baseline), "cpu-parallel"
-    (multi-core host plane, crypto/parallel_verify — the production
-    host policy when no device is reachable), "mesh" (multi-chip:
-    lanes shard over every local device via the shard_map/
-    PartitionSpec program, crypto/mesh_backend; DEGRADABLE — with
-    fewer than two devices it verifies on the cpu-parallel host
-    plane, so selecting it on a throttled no-mesh box is safe).
-    Empty (the default) inherits the process-wide default
-    (crypto/batch.set_default_backend — "tpu" unless the embedder
+    batch_backend is one of crypto/batch.BACKENDS, the name the one
+    routing decision (crypto/batch.decide, asked by the verify
+    scheduler crypto/scheduler.py once a ticket) goes by: "tpu"
+    (device lanes where the measured crossover says the device wins,
+    host chunks on the parallel plane otherwise), "cpu" (host chunks
+    inline on the dispatcher thread: the serial baseline),
+    "cpu-parallel" (host chunks on the multi-core plane,
+    crypto/parallel_verify), "mesh" (multi-chip: every eligible
+    dispatch's lanes shard over every local device through the
+    shard_map program of parallel/sharded_verify, no calibration
+    gate; DEGRADABLE: with fewer than two devices it verifies on the
+    cpu-parallel host plane, so a no-mesh box may select it). Empty
+    (the default) inherits the process-wide default
+    (crypto/batch.set_default_backend, "tpu" unless the embedder
     changed it); a non-empty value is applied at node build
-    (node/inprocess.build_node). The unified verify scheduler
-    (crypto/scheduler.py) routes every consumer's batches by this
-    backend. The parallel plane's own knobs are env-based:
-    GRAFT_VERIFY_WORKERS / _TIER / _CHUNK_TARGET_MS / _MIN_PARALLEL
-    (docs/PERF.md host plane)."""
+    (node/inprocess.build_node). The parallel plane's knobs are env:
+    GRAFT_VERIFY_WORKERS / _TIER / _CHUNK_TARGET_MS / _MIN_PARALLEL."""
 
     batch_backend: str = ""  # "" (inherit) | tpu | cpu | cpu-parallel | mesh
-    min_batch_for_tpu: int = 2
-    coalesce_window_ms: float = 2.0
-    max_lanes: int = 131072
 
 
 @dataclass

@@ -96,7 +96,7 @@ class PeerVoteCursor:
     The old shape rescanned every vote set the peer could need on
     EVERY gossip tick — O(validators) per peer per tick, O(V^2)
     across the committee even at steady state (flagged by ASY117,
-    slope measured by bench.py's scaling leg). The cursor reads each
+    slope measured by analysis/scaling.py's probe). The cursor reads each
     source log once (``vote_log[read:]``), stages what the peer has
     not acked into ``pending``, and retransmits only from there:
     a tick costs O(new votes + unacked), which is O(0) at steady

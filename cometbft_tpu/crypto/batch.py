@@ -1,43 +1,39 @@
-"""Batch signature verification dispatch: the framework's hottest seam.
+"""Routing policy of signature verification: where a batch's ed25519
+lanes verify, on the device or on the host.
 
-Mirrors the reference's injectable ``crypto.BatchVerifier``
-(crypto/crypto.go + crypto/batch/batch.go:10): callers accumulate
-(pubkey, msg, sig) triples and call ``verify()``. Two backends:
+Every consumer submits its ``(pubkey, msg, sig)`` lanes to the verify
+scheduler (crypto/scheduler.py), which splits them by curve and asks
+``decide()`` here, once a ticket, where the ed25519 lanes go. This
+module is that policy and nothing else:
 
-- ``CpuBatchVerifier`` — sequential ZIP-215 on host (correctness
-  baseline + small-batch latency path, like the reference's per-vote
-  single verify).
-- ``TpuBatchVerifier`` — one XLA dispatch over signature lanes
-  (ops/ed25519). Returns per-signature verdicts, so unlike the
-  reference's random-linear-combination batch there is no second
-  fall-back pass on failure.
+- the floor (``_MIN_TPU_BATCH``, ``set_min_tpu_batch``) under which
+  the device is never considered, and whose value 1 FORCES it;
+- the measured host-vs-device crossover (``calibration``, an EWMA the
+  scheduler's readiness watcher and host chunks feed);
+- the backend NAME the operator chose (``set_default_backend``, the
+  ``[crypto] batch_backend`` knob): ``tpu`` | ``cpu`` |
+  ``cpu-parallel`` | ``mesh``;
+- ``decide()``, the whole decision, and ``LAST_ROUTE``, what it last
+  chose (tests, the chip smoke and the driver's dryrun read it).
 
-- ``CpuParallelBatchVerifier`` — the multi-core host plane
-  (crypto/parallel_verify): verification lanes fan out in calibrated
-  chunks over a persistent worker pool, verdicts merge in input
-  order. Bit-identical to CpuBatchVerifier; it IS the host path worth
-  benchmarking against the device.
+Unlike the reference's random-linear-combination batch
+(crypto/batch/batch.go:10) the kernel returns per-signature verdicts,
+so there is no second fall-back pass on failure; and where the
+reference abandons batching when key types are mixed
+(types/validation.go shouldBatchVerify), the scheduler verifies the
+non-ed25519 lanes on the host and re-interleaves the verdicts.
 
-Backends live in a registry (``register_backend``) so config knobs,
-the bench ablation and tests select by name; the TPU verifier's
-host-routed lanes also ride the parallel plane, so every coalesced
-caller (types/validation windows, blocksync replay, light client,
-consensus vote sets) gets multi-core host verification for free.
-
-Mixed-curve sets (north-star config #5): ed25519 items go to the TPU
-lanes, anything else verifies on host; verdicts are re-interleaved.
-The reference instead abandons batching entirely when key types are
-mixed (types/validation.go shouldBatchVerify).
+``CpuBatchVerifier`` is the plain serial reference (one ``pk.verify``
+a lane, in order) that tests and the chip smoke compare verdicts
+against; nothing in the node constructs it.
 """
 
 from __future__ import annotations
 
 import threading
-import time
-from typing import List, Optional, Sequence, Tuple
+from typing import List, Tuple
 
-import numpy as np
-
+from ..utils import device
 from .keys import Ed25519PubKey, PubKey
 
 # Floor below which the device is never considered. The REAL cutoff is
@@ -47,7 +43,8 @@ from .keys import Ed25519PubKey, PubKey
 # #3: the r2 value routed 150-sig commits to a 98ms dispatch that
 # costs 12ms on host).
 # Setting it to <= 1 (set_min_tpu_batch(1)) FORCES the device path,
-# bypassing calibration — tests and the driver dryrun rely on that.
+# bypassing calibration — tests, the chip smoke and the driver dryrun
+# rely on that.
 _MIN_TPU_BATCH = 64
 
 
@@ -143,107 +140,19 @@ def _jax_backend_is_cpu() -> bool:
     routing (set_min_tpu_batch(1) — the dryrun/tests) is unaffected:
     the virtual-mesh validation deliberately runs the kernel on CPU.
     A backend that cannot start raises (utils/device)."""
-    from ..utils import device
-
     return device.on_cpu()
 
-# Last routing decision (observability: bench configs + tests report
-# which path the calibrated dispatch actually chose).
+
+# Last routing decision (observability: tests, the chip smoke and the
+# driver's dryrun report which path decide() actually chose).
 LAST_ROUTE = {"path": None, "n": 0, "crossover": None}
 
 
-class ResolvedVerdicts:
-    """Already-computed verdicts behind the async-handle interface."""
-
-    def __init__(self, all_ok: bool, oks: List[bool]) -> None:
-        self._res = (all_ok, oks)
-
-    def result(self) -> Tuple[bool, List[bool]]:
-        return self._res
-
-
-class _PendingVerdicts:
-    """In-flight device dispatch: host lanes already resolved in
-    ``oks``; ``result()`` fills the ed25519 lanes from the device
-    handle. Plain fields (not a closure) so the handle object holds
-    exactly what it needs.
-
-    The device wall for the calibration EWMA is observed by a
-    watcher thread blocking on device readiness (see verify_async),
-    NOT at result() time: a caller that overlaps long host work
-    before resolving would otherwise inflate the observed wall and
-    poison flat_s (the replay pipeline resolves a window's handle
-    ~1 s of apply-work after dispatch)."""
-
-    __slots__ = ("_handle", "_ed_idx", "_oks")
-
-    def __init__(self, handle, ed_idx, oks) -> None:
-        self._handle = handle
-        self._ed_idx = ed_idx
-        self._oks = oks
-
-    def result(self) -> Tuple[bool, List[bool]]:
-        oks = self._oks
-        for i, v in zip(self._ed_idx, self._handle.result()):
-            oks[i] = bool(v)
-        return all(oks) and bool(oks), oks
-
-
-class _PendingHostVerdicts:
-    """Host-routed async batch: ed25519 lanes in flight on the
-    parallel plane, other lanes already resolved in ``oks``. The
-    pool-completion wall (recorded by the handle's done callback, NOT
-    at result() time) feeds the host-cost EWMA, so a caller that
-    overlaps long host work before resolving cannot inflate the
-    observed host cost — the mirror of the device watcher's concern
-    (_PendingVerdicts below)."""
-
-    __slots__ = ("_handle", "_ed_idx", "_oks")
-
-    def __init__(self, handle, ed_idx, oks) -> None:
-        self._handle = handle
-        self._ed_idx = ed_idx
-        self._oks = oks
-
-    def result(self) -> Tuple[bool, List[bool]]:
-        oks = self._oks
-        for i, v in zip(self._ed_idx, self._handle.result()):
-            oks[i] = v
-        wall = self._handle.wall()
-        if wall:
-            calibration.observe_host(len(self._ed_idx), wall)
-        return all(oks) and bool(oks), oks
-
-
-class BatchVerifier:
-    """Accumulate signatures, verify all at once.
-
-    add() order is preserved; verify() returns (all_ok, per_item_ok).
-    verify_async() enqueues the work and returns a handle whose
-    ``result()`` blocks for the verdicts — on the TPU backend the XLA
-    dispatch is genuinely asynchronous, so callers can overlap host
-    work (block decode/apply) with device verification (the blocksync
-    window pipeline; docs/PERF.md "overlapped replay dispatch").
-    """
-
-    def add(self, pk: PubKey, msg: bytes, sig: bytes) -> None:
-        raise NotImplementedError
-
-    def verify(self) -> Tuple[bool, List[bool]]:
-        raise NotImplementedError
-
-    def verify_async(self):
-        """Default: compute now, hand back a resolved handle (host
-        backends have no async dispatch to overlap)."""
-        return ResolvedVerdicts(*self.verify())
-
-    def __len__(self) -> int:
-        raise NotImplementedError
-
-
-class CpuBatchVerifier(BatchVerifier):
-    """Sequential host verification — the correctness baseline and the
-    serial leg of the bench ablation (docs/PERF.md host plane)."""
+class CpuBatchVerifier:
+    """Sequential host verification: accumulate lanes with ``add()``,
+    ``verify()`` returns ``(all_ok, per_item_ok)`` in ``add()`` order.
+    The correctness baseline the scheduler's verdicts are tested
+    against."""
 
     def __init__(self) -> None:
         self.items: List[Tuple[PubKey, bytes, bytes]] = []
@@ -259,249 +168,71 @@ class CpuBatchVerifier(BatchVerifier):
         return len(self.items)
 
 
-class _PendingParallelVerdicts:
-    """In-flight parallel-plane batch behind the async-handle
-    interface (``result()`` blocks for the pool and merges)."""
+# Names mirror the config knob (config.CryptoConfig.batch_backend).
+BACKENDS = ("tpu", "cpu", "cpu-parallel", "mesh")
 
-    __slots__ = ("_handle",)
-
-    def __init__(self, handle) -> None:
-        self._handle = handle
-
-    def result(self) -> Tuple[bool, List[bool]]:
-        oks = self._handle.result()
-        return all(oks) and bool(oks), oks
+_default_backend = "tpu"
+_lock = threading.Lock()
 
 
-class CpuParallelBatchVerifier(BatchVerifier):
-    """Multi-core host plane: fans lanes over the persistent worker
-    pool (crypto/parallel_verify.engine()); verdicts are bit-identical
-    to CpuBatchVerifier and order-stable. verify_async() genuinely
-    enqueues — the blocksync window pipeline overlaps window K's host
-    apply with window K+1's verification even with no device."""
-
-    def __init__(self) -> None:
-        self.items: List[Tuple[PubKey, bytes, bytes]] = []
-
-    def add(self, pk: PubKey, msg: bytes, sig: bytes) -> None:
-        self.items.append((pk, msg, sig))
-
-    def verify(self) -> Tuple[bool, List[bool]]:
-        from .parallel_verify import engine
-
-        oks = engine().verify(self.items)
-        return all(oks) and bool(oks), oks
-
-    def verify_async(self):
-        from .parallel_verify import engine
-
-        return _PendingParallelVerdicts(
-            engine().verify_async(self.items)
-        )
-
-    def __len__(self) -> int:
-        return len(self.items)
+def default_backend() -> str:
+    """Name of the backend ``decide()`` routes by."""
+    with _lock:
+        return _default_backend
 
 
-class TpuBatchVerifier(BatchVerifier):
-    """Routes ed25519 lanes to the TPU kernel, everything else to host."""
+def set_default_backend(name: str) -> None:
+    """One of ``BACKENDS`` (process-wide; mirrors the config knob)."""
+    global _default_backend
+    assert name in BACKENDS, (name, BACKENDS)
+    with _lock:
+        _default_backend = name
 
-    def __init__(self) -> None:
-        self.items: List[Tuple[PubKey, bytes, bytes]] = []
 
-    def add(self, pk: PubKey, msg: bytes, sig: bytes) -> None:
-        self.items.append((pk, msg, sig))
-
-    def __len__(self) -> int:
-        return len(self.items)
-
-    def _route(self):
-        """Split items by curve and take the calibrated routing
-        decision (shared by verify / verify_async)."""
-        ed_idx, ed_items, other_idx = [], [], []
-        for i, (pk, msg, sig) in enumerate(self.items):
-            if isinstance(pk, Ed25519PubKey):
-                ed_idx.append(i)
-                ed_items.append((msg, pk.key_bytes, sig))
-            else:
-                other_idx.append(i)
-        n_ed = len(ed_items)
-        forced = _MIN_TPU_BATCH <= 1
+def decide(n_ed: int) -> Tuple[str, str, bool]:
+    """The routing decision for ``n_ed`` ed25519 lanes of one ticket:
+    ``(path, backend, degraded)`` with path ``device`` | ``host``,
+    backend the configured name (what ``ticket.backend`` becomes) or
+    ``mesh-degraded`` where ``mesh`` is configured and fewer than two
+    devices exist, and ``degraded`` true in that case alone."""
+    backend = default_backend()
+    degraded = False
+    forced = _MIN_TPU_BATCH <= 1
+    cal = calibration
+    use_device = False
+    if backend == "tpu":
         # calibration first: the backend probe imports jax and
         # initializes the platform, so it must only run when the
         # device route is otherwise about to be taken
         use_device = n_ed >= _MIN_TPU_BATCH and (
             forced
             or (
-                (
-                    calibration.device_wins(n_ed)
-                    or calibration.should_explore()
-                )
+                (cal.device_wins(n_ed) or cal.should_explore())
                 and not _jax_backend_is_cpu()
             )
         )
         if use_device and not forced:
-            calibration.note_device_used()
-        LAST_ROUTE.update(
-            path="device" if use_device else "host",
-            n=n_ed,
-            crossover=None if forced else calibration.crossover(),
-        )
-        return ed_idx, ed_items, other_idx, use_device
-
-    def _host_lanes(self, oks, ed_idx, other_idx, ed_on_host: bool):
-        """Host-routed lanes ride the multi-core plane: ed25519 lanes
-        fan out over the persistent pool (crypto/parallel_verify); the
-        rare non-ed lanes verify inline. observe_host feeds the
-        PARALLEL wall — routing must compare the device against the
-        host path's real (multi-core) cost, not one core's."""
-        if ed_on_host and ed_idx:
-            from .parallel_verify import engine
-
-            t0 = time.perf_counter()
-            verdicts = engine().verify(
-                [self.items[i] for i in ed_idx]
-            )
-            wall = time.perf_counter() - t0
-            for i, v in zip(ed_idx, verdicts):
-                oks[i] = v
-            calibration.observe_host(len(ed_idx), wall)
-        for i in other_idx:
-            pk, msg, sig = self.items[i]
-            oks[i] = pk.verify(msg, sig)
-
-    def verify(self) -> Tuple[bool, List[bool]]:
-        ed_idx, ed_items, other_idx, use_device = self._route()
-        oks = [False] * len(self.items)
-        if use_device:
-            from ..ops import ed25519 as _ed
-
-            t0 = time.perf_counter()
-            verdicts = _ed.verify_batch(ed_items)
-            calibration.observe_device(
-                len(ed_items), time.perf_counter() - t0
-            )
-            for i, v in zip(ed_idx, verdicts):
-                oks[i] = bool(v)
-        self._host_lanes(oks, ed_idx, other_idx, not use_device)
-        return all(oks) and bool(oks), oks
-
-    def verify_async(self):
-        """Enqueue the device dispatch WITHOUT blocking on verdicts.
-        Host-routed lanes (small batches, non-ed25519 curves) are
-        verified eagerly — there is nothing to overlap for them.
-
-        A daemon watcher thread blocks on device READINESS and feeds
-        the true dispatch wall into the calibration EWMA. Without
-        this, the async seam — the one verify_commit_light actually
-        takes (types/validation.py) — never corrects the optimistic
-        flat-cost seed and small commits route to a ~120 ms dispatch
-        forever (BENCH_r05 first run: commit150 auto=device at 10x
-        the host wall). Observing at result() time instead would
-        over-state walls for callers that overlap host work (the
-        replay pipeline) and poison the estimate the other way."""
-        ed_idx, ed_items, other_idx, use_device = self._route()
-        oks = [False] * len(self.items)
-        if not use_device:
-            # host route: enqueue ed lanes on the parallel plane and
-            # hand back a PENDING handle — the caller's host work
-            # (window decode/apply) overlaps pool verification even
-            # with no device in the picture
-            for i in other_idx:
-                pk, msg, sig = self.items[i]
-                oks[i] = pk.verify(msg, sig)
-            if not ed_idx:
-                return ResolvedVerdicts(all(oks) and bool(oks), oks)
-            from .parallel_verify import engine
-
-            return _PendingHostVerdicts(
-                engine().verify_async(
-                    [self.items[i] for i in ed_idx]
-                ),
-                ed_idx,
-                oks,
-            )
-        from ..ops import ed25519 as _ed
-
-        t0 = time.perf_counter()
-        handle = _ed.verify_batch_async(ed_items)
-        n_ed = len(ed_items)
-
-        def _observe_ready():
-            try:
-                handle.wait()
-            except Exception:
-                return
-            calibration.observe_device(
-                n_ed, time.perf_counter() - t0
-            )
-
-        threading.Thread(target=_observe_ready, daemon=True).start()
-        self._host_lanes(oks, ed_idx, other_idx, False)
-        return _PendingVerdicts(handle, ed_idx, oks)
-
-
-_default_backend = "tpu"
-_lock = threading.Lock()
-
-
-def _mesh_factory():
-    """Lazy factory for the multi-chip mesh backend — the import
-    touches jax device enumeration, which must not happen just
-    because the registry dict was built."""
-    from .mesh_backend import MeshBatchVerifier
-
-    return MeshBatchVerifier()
-
-
-# Backend registry: every coalesced caller goes through
-# create_batch_verifier(), so registering a backend here hands it to
-# all of them (types/validation windows, blocksync replay, light
-# client, consensus vote sets) at once. Names mirror the config knob
-# (config.CryptoConfig.batch_backend).
-_BACKENDS = {
-    "tpu": TpuBatchVerifier,
-    "cpu": CpuBatchVerifier,
-    "cpu-parallel": CpuParallelBatchVerifier,
-    "mesh": _mesh_factory,
-}
-
-
-def register_backend(name: str, factory) -> None:
-    """Add/replace a named verifier backend (factory: () -> BatchVerifier)."""
-    with _lock:
-        _BACKENDS[name] = factory
-
-
-def backends() -> Tuple[str, ...]:
-    return tuple(_BACKENDS)
-
-
-def default_backend() -> str:
-    """Name of the backend create_batch_verifier() would return — the
-    verify scheduler (crypto/scheduler.py) routes by it."""
-    with _lock:
-        return _default_backend
-
-
-def set_default_backend(name: str) -> None:
-    """Any registered backend name — 'tpu', 'cpu', 'cpu-parallel', ...
-    (process-wide; mirrors config knobs)."""
-    global _default_backend
-    assert name in _BACKENDS, (name, tuple(_BACKENDS))
-    with _lock:
-        _default_backend = name
-
-
-def create_batch_verifier(
-    pks: Optional[Sequence[PubKey]] = None,
-) -> BatchVerifier:
-    """Factory mirroring crypto/batch.CreateBatchVerifier: returns the
-    configured backend (TPU by default)."""
-    return _BACKENDS[_default_backend]()
+            cal.note_device_used()
+    elif backend == "mesh":
+        # explicit operator choice: shard whenever a mesh exists
+        # (no calibration gate — the mesh IS the configured
+        # plane); honor the batch floor so tiny commits stay on
+        # host, and degrade to host chunks with no mesh
+        if device.backend().count > 1:
+            use_device = n_ed > 0 and (forced or n_ed >= _MIN_TPU_BATCH)
+        else:
+            backend = "mesh-degraded"
+            degraded = True
+    path = "device" if use_device else "host"
+    LAST_ROUTE.update(
+        path=path,
+        n=n_ed,
+        crossover=None if forced else cal.crossover(),
+    )
+    return path, backend, degraded
 
 
 def supports_batch_verification(pk: PubKey) -> bool:
-    """Mirrors crypto/batch.SupportsBatchVerifier — but note the TPU
-    verifier also absorbs mixed sets by splitting (see module doc)."""
+    """Mirrors crypto/batch.SupportsBatchVerifier — but note the
+    scheduler also absorbs mixed sets by splitting (see module doc)."""
     return isinstance(pk, Ed25519PubKey)

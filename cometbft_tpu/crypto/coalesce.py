@@ -7,8 +7,8 @@ CPU whose single verify costs ~60us. A TPU dispatch has fixed latency,
 so the win only appears when a round's vote WAVE (one vote per
 validator, arriving in a burst) is verified as one lane batch. This
 queue is that seam: requests arriving within ``window_s`` (or until
-``max_pending``) are verified in ONE batch dispatch through the
-injectable crypto/batch backend, each submitter getting its own
+``max_pending``) are verified as ONE live-class ticket of the verify
+scheduler (crypto/scheduler.py), each submitter getting its own
 future. Verified signatures land in the shared SignatureCache
 (reference types/signature_cache.go) so the consensus state machine's
 inline re-verify is a cache hit, preserving its single-writer design.
@@ -23,19 +23,10 @@ import asyncio
 from typing import List, Optional, Tuple
 
 from . import scheduler as crypto_sched
-from .scheduler import PRIORITY_LIVE
+from .scheduler import PRIORITY_LIVE, _host_verify_one
 from ..utils.log import get_logger
 
 _log = get_logger("coalesce")
-
-
-def _host_verify_one(pk, sign_bytes: bytes, sig: bytes) -> bool:
-    """Per-item host verification (OpenSSL/ref path via PubKey.verify);
-    the dispatch-failure fallback. Never raises."""
-    try:
-        return bool(pk.verify(sign_bytes, sig))
-    except Exception:
-        return False
 
 # window long enough to collect a gossip burst, short enough to add no
 # visible latency to a round (consensus timeouts are 100ms+)
@@ -152,7 +143,7 @@ class CoalescingVerifier:
 
             def _host_verify_all():
                 return [
-                    _host_verify_one(pk, sb, sig)
+                    _host_verify_one((pk, sb, sig))
                     for pk, sb, sig, _fut in items
                 ]
 

@@ -10,7 +10,8 @@ Mirrors the reference's ``crypto.PubKey/PrivKey`` interfaces
 - single-signature verification uses ZIP-215 semantics to match batch
   verification exactly (reference uses curve25519-voi ZIP-215 for both).
 
-The TPU batch path lives in :mod:`cometbft_tpu.crypto.batch`.
+The batch path is :mod:`cometbft_tpu.crypto.scheduler` (routing policy:
+:mod:`cometbft_tpu.crypto.batch`; kernel: :mod:`cometbft_tpu.ops.ed25519`).
 """
 
 from __future__ import annotations
@@ -71,7 +72,7 @@ class PubKey:
 # Constructed-OpenSSL-object cache: validator keys repeat massively
 # (a 10k-block replay has ~150 distinct keys for ~1.5M verifies), and
 # Ed25519PublicKey.from_public_bytes costs ~1.5x the hash of the vote
-# itself (profile_replay r5). Only VALID constructions are cached;
+# itself (round-5 replay profile). Only VALID constructions are cached;
 # invalid keys re-raise (and fall through to the liberal check) every
 # time, which is the rare path.
 _EVP_CACHE: dict = {}

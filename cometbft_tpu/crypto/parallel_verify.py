@@ -120,7 +120,7 @@ class PendingLanes:
     dispatch→completion wall recorded by the LAST chunk's done
     callback — immune to how long the caller overlaps host work
     before resolving (the same poisoning concern as the device
-    calibration watcher, crypto/batch.py)."""
+    calibration watcher, crypto/scheduler.py)."""
 
     __slots__ = (
         "_futures", "_engine", "_n", "_t0", "_done_t", "_left", "_lock",
@@ -429,9 +429,9 @@ _ENGINE_LOCK = threading.Lock()
 
 
 def engine() -> ParallelVerifyEngine:
-    """The shared engine every host verification seam rides (the
-    cpu-parallel batch backend and the TPU backend's host-routed
-    lanes). Created lazily on first use."""
+    """The shared engine every host-routed ticket of the verify
+    scheduler rides (the cpu-parallel backend, and the tpu and mesh
+    backends' host-routed lanes). Created lazily on first use."""
     global _ENGINE
     with _ENGINE_LOCK:
         if _ENGINE is None:

@@ -1,13 +1,17 @@
 """Unified verify scheduler: ONE dispatch queue for every signature
 verification consumer (docs/PERF.md "Unified verify scheduler").
 
-Before this seam each consumer reached the crypto engine through its
-own path — types/validation built a per-call BatchVerifier, the
-consensus vote coalescer and the light serving plane each window-
-batched on their own, blocksync pipelined through the same unordered
-pool — so a live round's precommit wave could queue behind a
-500-block catch-up window sharing the host pool. The scheduler is the
-single choke point those seams now submit to:
+Every consumer (types/validation's commit seams, the consensus vote
+coalescer crypto/coalesce, the light serving plane, blocksync,
+statesync, evidence) submits its ``(pubkey, msg, sig)`` lanes here
+and gets a ticket; nothing else reaches the kernel or the host pool,
+so a live round's precommit wave never queues behind a 500-block
+catch-up window. A ticket's life: queue (by class) -> ``_plan`` (lane
+split by curve + the one routing decision, ``crypto/batch.decide``)
+-> ``ops/ed25519.verify_batch_async`` (pack, put, the jitted program,
+one device or lane-sharded over a mesh) and a watcher thread, or
+calibrated host chunks on the parallel plane -> verdicts merged back
+in submission order.
 
 - **Priority classes**: live round (0) > light session (1) >
   catch-up/evidence (2). Dispatch granularity is one calibrated chunk
@@ -19,21 +23,22 @@ single choke point those seams now submit to:
   is served ahead of higher classes once every ``promote_every``
   picks — catch-up keeps a bounded 1/promote_every share of dispatch
   slots under ANY sustained live load (tests/test_verify_scheduler).
-- **Per-backend lanes + calibrated routing**: the routing decision is
-  the exact decision crypto/batch.TpuBatchVerifier._route takes —
-  same _MIN_TPU_BATCH floor, same measured host-vs-device crossover
-  EWMA (crypto/batch.calibration), same explore/recovery schedule —
-  so migrating a consumer onto the scheduler cannot change WHERE its
-  lanes verify, only when. Device dispatches ride the async XLA seam
-  with the same readiness-watcher calibration feed; the ``mesh``
-  backend (crypto/mesh_backend) shards lanes over every local device
-  and degrades to host chunks when no mesh materializes.
+- **Per-backend lanes + calibrated routing**: WHERE a ticket's
+  ed25519 lanes verify is decided in one place, crypto/batch.decide:
+  the configured backend name, the batch floor, the measured
+  host-vs-device crossover EWMA (crypto/batch.calibration) with its
+  explore/recovery schedule. The scheduler only decides WHEN. Device
+  dispatches ride the async XLA seam; a readiness watcher feeds the
+  calibration with the true dispatch wall and resolves the ticket.
+  The ``mesh`` backend shards lanes over every local device and
+  degrades to host chunks when no mesh materializes or the dispatch
+  fails (``ticket.backend`` ``mesh-degraded``, the ``degraded``
+  counter).
 
-Verdicts are serial-equivalent BY CONSTRUCTION: every lane runs the
-same ``pk.verify``/kernel math the direct backends run, merged back
-in submission order (differential-tested in
-tests/test_verify_scheduler.py and gated in-bench by the
-``verify-sched`` leg).
+Verdicts are serial-equivalent BY CONSTRUCTION: every lane runs
+``pk.verify`` or the kernel's same math, merged back in submission
+order (differential-tested against crypto/batch.CpuBatchVerifier in
+tests/test_verify_scheduler.py).
 """
 
 from __future__ import annotations
@@ -89,9 +94,8 @@ def _clamp_priority(priority) -> int:
 
 class VerifyTicket:
     """One submitted batch: ``result()`` blocks for the merged
-    verdicts, returning ``(all_ok, oks)`` exactly like the
-    BatchVerifier async handles (crypto/batch.ResolvedVerdicts), so
-    the validation seam plumbs it through unchanged."""
+    verdicts and returns ``(all_ok, oks)``, ``oks`` in submission
+    order (what crypto/batch.CpuBatchVerifier.verify returns)."""
 
     __slots__ = (
         "id", "items", "priority", "label", "t_submit", "t_submit_ns",
@@ -184,7 +188,7 @@ class VerifyScheduler:
         priority = _clamp_priority(priority)
         ticket = VerifyTicket(list(items), priority, label)
         if not ticket.items:
-            # empty batch resolves to (False, []) like BatchVerifier
+            # empty batch resolves to (False, []) like CpuBatchVerifier
             ticket.t_done = ticket.t_submit
             ticket._event.set()
             return ticket
@@ -296,10 +300,9 @@ class VerifyScheduler:
     # --- routing -------------------------------------------------------
 
     def _route(self, ticket: VerifyTicket) -> None:
-        """First pop: split lanes by curve, take the calibrated
-        backend-routing decision (the same decision
-        crypto/batch.TpuBatchVerifier._route takes), dispatch the
-        device part async, queue the host part as calibrated chunks."""
+        """First pop: split lanes by curve, take the routing decision
+        (crypto/batch.decide), dispatch the device part async, queue
+        the host part as calibrated chunks."""
         # the span ends before the device dispatch begins:
         # ops.ed25519.pack / .enqueue are stages of their own
         with global_tracer().annotated_span(
@@ -309,9 +312,6 @@ class VerifyScheduler:
             plan = self._plan(ticket)
             sp.set(path=plan[0])
         path, ed_idx, ed_items = plan
-        if path == "custom":
-            self._finish(ticket, len(ticket.items))
-            return
         backend = ticket.backend
         if path == "device" and ed_idx:
             if self._dispatch_device(ticket, ed_idx, ed_items, backend):
@@ -322,9 +322,9 @@ class VerifyScheduler:
         self._queue_host_chunks(ticket, ed_idx)
 
     def _plan(self, ticket: VerifyTicket) -> tuple:
-        """The routing decision itself: (path, ed_idx, ed_items) with
-        path ``device`` | ``host``, or ``custom`` where a registered
-        backend has already resolved every lane."""
+        """Split the lanes by curve and ask the routing decision for
+        the ed25519 ones: (path, ed_idx, ed_items) with path
+        ``device`` | ``host``. Other curves verify inline, here."""
         items = ticket.items
         ed_idx: List[int] = []
         ed_items = []
@@ -335,68 +335,25 @@ class VerifyScheduler:
                 ed_items.append((msg, pk.key_bytes, sig))
             else:
                 other_idx.append(i)
-        backend = crypto_batch.default_backend()
-        ticket.backend = backend
-        if backend not in ("tpu", "cpu", "cpu-parallel", "mesh"):
-            # custom registered backend (register_backend): preserve
-            # its semantics verbatim — build it and resolve on the
-            # dispatcher thread (priority ordering still applied at
-            # pick time; preemption granularity is the whole ticket)
-            verifier = crypto_batch.create_batch_verifier()
-            for pk, msg, sig in items:
-                verifier.add(pk, msg, sig)
-            _, oks = verifier.verify()
-            ticket.oks[:] = oks
-            ticket._routed = True
-            return "custom", ed_idx, ed_items
-        n_ed = len(ed_items)
-        forced = crypto_batch._MIN_TPU_BATCH <= 1
-        cal = crypto_batch.calibration
-        use_device = False
-        if backend == "tpu":
-            use_device = n_ed >= crypto_batch._MIN_TPU_BATCH and (
-                forced
-                or (
-                    (cal.device_wins(n_ed) or cal.should_explore())
-                    and not crypto_batch._jax_backend_is_cpu()
-                )
-            )
-            if use_device and not forced:
-                cal.note_device_used()
-        elif backend == "mesh":
-            # explicit operator choice: shard whenever a mesh exists
-            # (no calibration gate — the mesh IS the configured
-            # plane); honor the batch floor so tiny commits stay on
-            # host, and degrade to host chunks with no mesh
-            from .mesh_backend import mesh_devices
-
-            if mesh_devices() > 1:
-                use_device = n_ed > 0 and (
-                    forced or n_ed >= crypto_batch._MIN_TPU_BATCH
-                )
-            else:
-                ticket.backend = "mesh-degraded"
-                self.degraded += 1
-        crypto_batch.LAST_ROUTE.update(
-            path="device" if use_device else "host",
-            n=n_ed,
-            crossover=None if forced else cal.crossover(),
-        )
-        # non-ed lanes: verified inline at route time (rare curves,
-        # exactly TpuBatchVerifier._host_lanes' treatment)
+        path, ticket.backend, degraded = crypto_batch.decide(len(ed_items))
+        if degraded:
+            self.degraded += 1
+        # non-ed lanes: verified inline at route time (rare curves)
         for i in other_idx:
             pk, msg, sig = items[i]
             ticket.oks[i] = pk.verify(msg, sig)
         ticket._routed = True
-        return ("device" if use_device else "host"), ed_idx, ed_items
+        return path, ed_idx, ed_items
 
     def _dispatch_device(
         self, ticket: VerifyTicket, ed_idx, ed_items, backend: str
     ) -> bool:
         """Async device dispatch for the ed25519 lanes; a daemon
         watcher feeds the calibration EWMA from true readiness
-        (``wait()``, as crypto/batch.verify_async does) and resolves
-        the ticket. Returns False when the dispatch itself fails."""
+        (``wait()``, not ``result()``: a caller that overlaps host work
+        before resolving must not inflate the observed wall) and
+        resolves the ticket. Returns False when the dispatch itself
+        fails."""
         try:
             from ..ops import ed25519 as _ed
 
@@ -535,8 +492,8 @@ class VerifyScheduler:
             eng._observe_chunk(n, wall)
             if ticket.backend == "tpu":
                 # host-vs-device routing EWMA: fed only on the backend
-                # whose routing consults it (TpuBatchVerifier parity —
-                # the cpu backends never calibrated)
+                # whose routing consults it (the cpu backends never
+                # calibrate)
                 crypto_batch.calibration.observe_host(n, wall)
         self._unit_done(ticket, n)
 
@@ -566,8 +523,8 @@ class VerifyScheduler:
     def _finish(self, ticket: VerifyTicket, lanes: int, sp=None) -> None:
         tr = global_tracer()
         if sp is None:
-            # host-routed (or custom-backend) ticket: the verdicts were
-            # written back chunk by chunk, what is left is this
+            # host-routed ticket: the verdicts were written back chunk
+            # by chunk, what is left is this
             sp = tr.annotated_span(
                 "crypto.sched.resolve", tid=_TID_HOST,
                 ticket=ticket.id, lanes=len(ticket.items),

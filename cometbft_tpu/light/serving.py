@@ -23,10 +23,10 @@ seams fix that:
 - **CoalescedCommitVerifier** — the cross-client batcher: concurrent
   sessions' skipping-verification hops (verify_non_adjacent's
   trusting + light checks) funnel into ONE lane batch through
-  types/validation.verify_commit_jobs_coalesced — i.e. the existing
-  crypto/batch + crypto/parallel_verify engine — with
+  types/validation.verify_commit_jobs_coalesced — i.e. one
+  light-class ticket of the verify scheduler — with
   serial-equivalent verdicts (same error types, same early-break
-  collection; asserted in tests and in-bench). Window-batched with
+  collection; asserted in tests). Window-batched with
   leader election: the first submitting thread collects followers for
   ``window_s`` then dispatches for everyone.
 

@@ -14,7 +14,7 @@ reference and the no-numpy fallback.
 This is the apply-leg counterpart of state/native_finalize.py: where
 the native pass removes the per-item HASH/ENCODE overhead of the
 finalize path, this model removes the per-item STATE-APPLY overhead,
-so ``bench.py finalize`` can show an end-to-end blocks/s ceiling for
+so a finalize measurement can show an end-to-end blocks/s ceiling for
 the whole height loop rather than a crypto-only one (docs/PERF.md
 "Native finalize lane"). The kvstore keeps its dict semantics as the
 universal fake app; vecbank is the throughput app.

@@ -18,12 +18,10 @@ verdict row per (node, span, metric). Consumers:
 - ``python -m cometbft_tpu.trace summarize --budget [FILE]`` — prints
   the verdict table, exits 2 on any violation;
 - chaos runs (chaos/net.run_schedule budget_file=...) — a violation
-  dumps the traces and fails the run's exit code;
-- ``bench.py --trace`` — verdicts embedded per config in the result
-  JSON, the regression gate future perf PRs diff against.
+  dumps the traces and fails the run's exit code.
 
 Budgets gate *recorded seeds on this box*: numbers carry the ±30%
-run-to-run variance headroom the bench memos document, so a pass is
+run-to-run variance headroom docs/PERF.md documents, so a pass is
 reproducible and a failure means a real regression, not noise.
 """
 

@@ -8,14 +8,12 @@ semicolon-joined ``module:func`` chain outermost-first — exactly the
 
 Why not cProfile: its tracing hook attaches per-thread (the calling
 thread here would just be sleeping) and its overhead on a GIL-bound
-2-vCPU box distorts the very tails we are attributing. Sampling at
-the default ~50 Hz costs well under 1% (the bench ingest leg asserts
-<3% headroom, bench.py); each sample walks every thread's frames
-once, bounded depth, no allocation beyond the counter dict.
+2-vCPU box distorts the very tails we are attributing. Each sample
+at the default ~50 Hz walks every thread's frames once, bounded
+depth, no allocation beyond the counter dict.
 
-Used by: ``bench.py`` (attached automatically, folded profile embedded
-in the result JSON), chaos runs (profile.folded written beside the
-trace dumps on violation), and the pprof-style debug server.
+Used by: chaos runs (profile.folded written beside the trace dumps
+on violation) and the pprof-style debug server.
 """
 
 from __future__ import annotations

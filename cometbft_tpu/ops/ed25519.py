@@ -56,12 +56,6 @@ def bucket_cap(max_len: int) -> int:
     raise ValueError(f"message too long for verify kernel: {max_len}")
 
 
-# straus-ladder window-loop unroll factor (bench-tunable; see _straus)
-import os as _os
-
-LADDER_UNROLL = int(_os.environ.get("GRAFT_LADDER_UNROLL", "1"))
-
-
 _B_TABLE = None
 
 
@@ -170,12 +164,7 @@ def _straus(ds, dh, A, shape):
         return curve.add_affine_cached(q, addend_b, need_t=False)
 
     # T-less carry: the loop output feeds add_projective (no T input).
-    # Unrolling trades HLO size for scheduling freedom across window
-    # iterations (the kernel is issue-bound, not multiply-bound —
-    # docs/PERF.md); the default is measured on v5e via bench.py.
-    return lax.fori_loop(
-        0, 64, body, ident[:3] + (None,), unroll=LADDER_UNROLL
-    )
+    return lax.fori_loop(0, 64, body, ident[:3] + (None,))
 
 
 def _stack_pt(p):
@@ -302,9 +291,7 @@ def _ladder_backend_key() -> tuple:
     the pallas sublane blocking. The jit wrappers below are cached PER
     KEY, so flipping GRAFT_PALLAS / GRAFT_COMPACT_FIELD /
     GRAFT_PALLAS_SUBLANES mid-process retraces instead of silently
-    reusing a stale trace (VERDICT r4 weak #6 — the bench no longer
-    needs a subprocess per backend for correctness, only for compile-
-    hang isolation)."""
+    reusing a stale trace (VERDICT r4 weak #6)."""
     from .pallas_ladder import block_sublanes, min_lanes, pallas_enabled
 
     # pallas_enabled(None) here = "may pallas engage at SOME width";
@@ -355,9 +342,7 @@ def precomp_tuple_enabled() -> bool:
 def a_tree_from_stacked(a_arr, put=jnp.asarray):
     """Host-side: stacked (4, NLIMBS, N) numpy A -> the pytree of 80
     separate (N,) arrays the tuple kernel takes, each through ``put``
-    (onto the device, or left on the host for a sharded program). The
-    ONE builder production and bench share, so the A/B leg measures
-    the exact input form production dispatches."""
+    (onto the device, or left on the host for a sharded program)."""
     return tuple(
         tuple(
             put(np.ascontiguousarray(a_arr[k, j]))
@@ -365,13 +350,6 @@ def a_tree_from_stacked(a_arr, put=jnp.asarray):
         )
         for k in range(4)
     )
-
-
-def _precomp_max_lanes() -> int:
-    """Width cutoff for the precomp kernel; env-overridable so the
-    bench can force precomp at bulk widths for the lever-#6 A/B."""
-    v = os.environ.get("GRAFT_PRECOMP_MAX_LANES")
-    return int(v) if v else PRECOMP_MAX_LANES
 
 
 @functools.lru_cache(maxsize=None)
@@ -494,8 +472,7 @@ class AsyncVerdicts:
     the program is enqueued and this handle holds the device future).
     ``result()`` blocks and returns the bool verdicts. Overlapping
     several dispatches before resolving amortizes the per-dispatch
-    flat cost — the production pipelining seam (bench config
-    "pipeline")."""
+    flat cost — the production pipelining seam."""
 
     def __init__(self, res, bad, n, devices=1):
         self._res = res
@@ -506,8 +483,7 @@ class AsyncVerdicts:
     def wait(self) -> "AsyncVerdicts":
         """Block until the device computation is READY, without
         fetching the verdicts to host (thread-safe; used by the
-        routing calibration's readiness watchers in crypto/batch and
-        crypto/scheduler). ``block_until_ready`` blocks on an attached
+        routing calibration's readiness watcher in crypto/scheduler). ``block_until_ready`` blocks on an attached
         chip: chip_smoke.py times it against the fetch that follows."""
         bur = getattr(self._res, "block_until_ready", None)
         if bur is not None:
@@ -614,7 +590,7 @@ def _pack(items):
     # decompression dominates there — plain above it, where depth
     # amortizes and the stacked A input costs more than it saves
     # (unless the tuple-form A opt-in is on, docs/PERF.md lever #6)
-    use_precomp = (np_ // n_dev) <= _precomp_max_lanes()
+    use_precomp = (np_ // n_dev) <= PRECOMP_MAX_LANES
     tuple_a = use_precomp and precomp_tuple_enabled()
     mode = (
         "precomp_tuple"

@@ -91,11 +91,11 @@ def make_sharded_core(mesh, mode="precomp"):
     #6) — same width rule as single-device dispatch
     (ops/ed25519.PRECOMP_MAX_LANES).
 
-    This is the PRODUCTION seam: ``ops/ed25519.verify_batch`` (behind
-    crypto/batch.TpuBatchVerifier — the reference's injectable
-    BatchVerifier, types/validation.go:261-270) routes through this
-    whenever more than one local device is visible, so every
-    VerifyCommit* caller scales over the mesh transparently.
+    This is the PRODUCTION seam: ``ops/ed25519.verify_batch_async``
+    (the verify scheduler's device dispatch, crypto/scheduler.py)
+    routes through this whenever more than one local device is
+    visible, so every VerifyCommit* caller scales over the mesh
+    transparently.
     """
     inner, in_specs = _core_specs(mode)
     fn = shard_map(

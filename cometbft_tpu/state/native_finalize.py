@@ -259,7 +259,7 @@ def finalize_pass(
 ) -> FinalizeArtifacts:
     """The one pass per block. ``resp`` is the app's
     ResponseFinalizeBlock; ``portable=True`` forces the Python twin
-    (differential tests and the parity leg of ``bench.py finalize``).
+    (the differential tests).
 
     The flatten itself (attr_kvi over every event) happens exactly
     once, HERE, regardless of backend — the artifacts carry the

@@ -127,10 +127,6 @@ class RetentionPlane:
         self.snapshots_taken = 0
         self.reconciles = 0
         self.last_prune_s = 0.0
-        # OS thread ident of the last reconcile pass — bench.py's
-        # lifecycle leg asserts it differs from the event-loop thread
-        # (prune work must never run on the consensus path)
-        self.last_thread_ident = None
 
     # --- enablement ---------------------------------------------------
 
@@ -217,7 +213,6 @@ class RetentionPlane:
         with self._reconcile_lock:
             import time as _time
 
-            self.last_thread_ident = threading.get_ident()
             t0 = _time.monotonic()
             out = {
                 "snapshot": 0,

@@ -29,7 +29,7 @@ class SignatureCache:
         needed — the reference hashes only to bound Go map key size),
         and cheap on the miss-then-add path because Python caches each
         bytes object's hash, so the second keying of the SAME objects
-        costs almost nothing (profile_replay r5: sha256 keying was
+        costs almost nothing (round-5 replay profile: sha256 keying was
         ~3% of replay host wall with a 0% hit rate on linear sync)."""
         return (sign_bytes, sig, pubkey)
 

@@ -2,14 +2,14 @@
 
 Parity with reference types/validation.go: VerifyCommit (:30),
 VerifyCommitLight (:65), VerifyCommitLightTrusting (:148), the
-``*AllSignatures`` and ``*WithCache`` variants, with an injectable batch
-verifier (reference :270). Consumers: blocksync replay, adaptive
+``*AllSignatures`` and ``*WithCache`` variants. Consumers: blocksync replay, adaptive
 ingest, light-client bisection, evidence checks (SURVEY.md §2.3).
 
 TPU-first departure: the reference dispatches between a sequential path
 and a random-linear-combination CPU batch; here every multi-signature
-verification builds one lane batch for the TPU kernel
-(crypto/batch.TpuBatchVerifier), which returns per-lane verdicts — the
+verification builds one lane batch and submits it as one ticket of the
+verify scheduler (crypto/scheduler.py; crypto/batch.decide routes it to
+the TPU kernel or the host plane), which returns per-lane verdicts — the
 "light" early-exit at +2/3 is pointless on SIMD lanes, so light mode
 just restricts *which* signatures are checked (the ones counted toward
 the tally), identically to the reference's semantics.

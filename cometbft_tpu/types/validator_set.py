@@ -91,7 +91,7 @@ class ValidatorSet:
     def total_voting_power(self) -> int:
         # memoized like hash(): every add_vote compares accumulated
         # power against the total, so an unmemoized sum here is O(V)
-        # per vote = O(V^2) per height (the bench.py scaling leg
+        # per vote = O(V^2) per height (analysis/scaling.py's probe
         # measures the slope). Powers only change through
         # update_with_change_set, which drops the memo.
         tp = getattr(self, "_total_power", None)

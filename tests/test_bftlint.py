@@ -654,8 +654,8 @@ FIXTURES = [
         """,
     ),
     (
-        "ASY121",  # verify-bypass-scheduler: a hot plane building a
-        # BatchVerifier / touching the parallel-verify pool directly
+        "ASY121",  # verify-bypass-scheduler: a hot plane building the
+        # serial verifier / touching the parallel-verify pool directly
         # verifies outside the scheduler's priority classes
         """
         from cometbft_tpu.crypto.batch import CpuBatchVerifier
@@ -665,8 +665,8 @@ FIXTURES = [
             for pk, msg, sig in jobs:
                 v.add(pk, msg, sig)
             return v.verify()
-        def factory_verify(jobs):
-            return batch.create_batch_verifier()
+        def module_verify(jobs):
+            return batch.CpuBatchVerifier()
         def pool_verify(items):
             return parallel_verify.engine().verify(items)
         """,

@@ -204,7 +204,6 @@ def test_only_the_helper_names_the_cache_directory():
 def test_an_unstartable_backend_is_an_error_not_the_cpu(monkeypatch):
     import jax
 
-    from cometbft_tpu.crypto import mesh_backend
     from cometbft_tpu.ops import fe25519, pallas_ladder
     from cometbft_tpu.utils import device
 
@@ -215,6 +214,8 @@ def test_an_unstartable_backend_is_an_error_not_the_cpu(monkeypatch):
     monkeypatch.delenv("GRAFT_COMPACT_FIELD", raising=False)
     monkeypatch.delenv("GRAFT_PALLAS", raising=False)
     monkeypatch.setattr(fe25519, "_COMPACT", None)
+    # the mesh branch of the routing decision reads the device count
+    monkeypatch.setattr(crypto_batch, "_default_backend", "mesh")
     device.backend.cache_clear()
     try:
         for probe in (
@@ -222,7 +223,7 @@ def test_an_unstartable_backend_is_an_error_not_the_cpu(monkeypatch):
             pallas_ladder.pallas_enabled,
             pallas_ladder.interpret_mode,
             fe25519.compact_mode,
-            mesh_backend.mesh_devices,
+            lambda: crypto_batch.decide(4800),
             lambda: ed._sharded_fn("plain"),
         ):
             with pytest.raises(RuntimeError, match="Unable to initialize"):

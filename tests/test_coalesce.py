@@ -167,29 +167,31 @@ def test_150_validator_vote_wave_two_dispatches():
     run(main())
 
 
-def test_dispatch_failure_falls_back_to_host_verification(monkeypatch):
+@pytest.mark.parametrize("failure", ["submit", "device-dispatch"])
+def test_dispatch_failure_falls_back_to_host_verification(
+    monkeypatch, failure
+):
     """ADVICE r2 (low): a transient backend/device error must not mark
     a whole wave invalid — the reactor already announced has_vote, so
     the dropped votes would never be re-gossiped. Per-item host
-    verification resolves the lanes instead."""
+    verification resolves the lanes instead: the coalescer's own when
+    the scheduler refuses the ticket, the scheduler's host chunks
+    when the device dispatch fails."""
     from cometbft_tpu.crypto import batch as crypto_batch
+    from cometbft_tpu.crypto import scheduler as crypto_sched
+    from cometbft_tpu.ops import ed25519 as ops_ed
 
-    class ExplodingVerifier(crypto_batch.BatchVerifier):
-        def __init__(self):
-            self.items = []
-
-        def add(self, pk, msg, sig):
-            self.items.append((pk, msg, sig))
-
-        def __len__(self):
-            return len(self.items)
-
-        def verify(self):
+    sched = crypto_sched.VerifyScheduler()
+    monkeypatch.setattr(crypto_sched, "scheduler", lambda: sched)
+    if failure == "submit":
+        sched.close()  # submit() raises: "verify scheduler closed"
+    else:
+        def boom(ed_items):
             raise RuntimeError("device went away")
 
-    monkeypatch.setattr(
-        crypto_batch, "create_batch_verifier", lambda: ExplodingVerifier()
-    )
+        monkeypatch.setattr(ops_ed, "verify_batch_async", boom)
+        monkeypatch.setattr(crypto_batch, "_default_backend", "tpu")
+        monkeypatch.setattr(crypto_batch, "_MIN_TPU_BATCH", 1)
 
     async def main():
         v = CoalescingVerifier(window_s=0.005)
@@ -204,4 +206,9 @@ def test_dispatch_failure_falls_back_to_host_verification(monkeypatch):
         got = await asyncio.gather(*futs)
         assert got == [i != 3 for i in range(6)]
 
-    run(main())
+    try:
+        run(main())
+        if failure == "device-dispatch":
+            assert sched.stats()["degraded"] == 1
+    finally:
+        sched.close()

@@ -181,7 +181,6 @@ def shapes(monkeypatch):
         monkeypatch.setattr(
             ops_ed, "PRECOMP_MAX_LANES", 0 if mode == "plain" else 4096
         )
-        monkeypatch.delenv("GRAFT_PRECOMP_MAX_LANES", raising=False)
         if mode == "precomp_tuple":
             monkeypatch.setenv("GRAFT_PRECOMP_TUPLE", "1")
         else:

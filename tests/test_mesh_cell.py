@@ -131,7 +131,6 @@ def test_pad_min_pin_gives_the_mesh_one_program(
     config = lookup.load_cell(lookup.load_spec(), CELL)["config_data"]
     assert config["pins"] == {"cometbft_tpu.ops.ed25519.PAD_MIN": 65_536}
     monkeypatch.setattr(ops_ed, "PAD_MIN", pad_min)
-    monkeypatch.delenv("GRAFT_PRECOMP_MAX_LANES", raising=False)
     monkeypatch.delenv("GRAFT_PRECOMP_TUPLE", raising=False)
     monkeypatch.setattr(
         device, "backend", lambda: device.Backend("cpu", "cpu", 4)

@@ -19,6 +19,7 @@ from cometbft_tpu.crypto import batch as crypto_batch
 from cometbft_tpu.crypto import keys as crypto_keys
 from cometbft_tpu.crypto import native_verify
 from cometbft_tpu.crypto import parallel_verify as pv
+from cometbft_tpu.crypto import scheduler as crypto_sched
 from cometbft_tpu.crypto.keys import Ed25519PrivKey, Secp256k1PrivKey
 from cometbft_tpu.crypto.parallel_verify import ParallelVerifyEngine
 
@@ -142,20 +143,17 @@ def test_forged_and_edge_lanes_land_on_exact_indices():
             assert eng.verify(items) == want, tier
         finally:
             eng.close()
-    # and through the registered backend
-    old = crypto_batch._default_backend
+    # and as a scheduler ticket on the cpu-parallel backend
+    old = crypto_batch.default_backend()
     crypto_batch.set_default_backend("cpu-parallel")
+    sched = crypto_sched.VerifyScheduler()
     try:
-        v = crypto_batch.create_batch_verifier()
-        for it in items:
-            v.add(*it)
-        all_ok, oks = v.verify()
-        assert not all_ok and oks == want
-        v2 = crypto_batch.create_batch_verifier()
-        for it in items:
-            v2.add(*it)
-        assert v2.verify_async().result() == (False, want)
+        ticket = sched.submit(items, label="vectors")
+        assert ticket.result(timeout=60) == (False, want)
+        assert ticket.backend == "cpu-parallel"
+        assert sched.stats()["host_chunks"] >= 1
     finally:
+        sched.close()
         crypto_batch.set_default_backend(old)
 
 
@@ -227,43 +225,36 @@ def test_serial_degrade_when_single_worker():
 
 
 def test_tpu_backend_host_lanes_ride_the_parallel_plane(monkeypatch):
-    """Host-routed batches on the DEFAULT (tpu) backend must go
-    through the shared engine — every coalesced caller gets the
-    multi-core plane for free — and verify_async must hand back a
-    genuinely pending handle, not an eagerly-resolved one."""
-    calls = []
-    real_engine = pv.engine()
-
-    class Recorder:
-        def verify(self, items):
-            calls.append(("verify", len(items)))
-            return real_engine.verify(items)
-
-        def verify_async(self, items):
-            calls.append(("verify_async", len(items)))
-            return real_engine.verify_async(items)
-
-    monkeypatch.setattr(pv, "engine", lambda: Recorder())
-    old = crypto_batch._default_backend
+    """Host-routed tickets on the DEFAULT (tpu) backend must go
+    through the shared engine's pool — every coalesced caller gets the
+    multi-core plane for free — in calibrated chunks, and feed the
+    host side of the routing calibration."""
+    eng = ParallelVerifyEngine(workers=2, tier="thread", min_parallel=1)
+    monkeypatch.setattr(pv, "engine", lambda: eng)
+    monkeypatch.setattr(
+        crypto_batch, "calibration", crypto_batch._Calibration()
+    )
+    host_s0 = crypto_batch.calibration.host_s
+    old = crypto_batch.default_backend()
     old_min = crypto_batch._MIN_TPU_BATCH
     crypto_batch.set_default_backend("tpu")
     crypto_batch.set_min_tpu_batch(1 << 30)  # force host routing
+    sched = crypto_sched.VerifyScheduler()
     try:
         items = _random_items(80, n_keys=4)
-        v = crypto_batch.create_batch_verifier()
-        for it in items:
-            v.add(*it)
-        ok, oks = v.verify()
-        assert ok and all(oks)
-        v2 = crypto_batch.create_batch_verifier()
-        for it in items:
-            v2.add(*it)
-        handle = v2.verify_async()
-        assert isinstance(handle, crypto_batch._PendingHostVerdicts)
-        assert handle.result() == (True, [True] * 80)
-        assert ("verify", 80) in calls
-        assert ("verify_async", 80) in calls
+        ticket = sched.submit(items, label="host-lanes")
+        assert ticket.result(timeout=60) == (True, [True] * 80)
+        assert ticket.backend == "tpu"
+        assert crypto_batch.LAST_ROUTE["path"] == "host"
+        st = sched.stats()
+        assert st["device_dispatches"] == 0 and st["degraded"] == 0
+        # host chunks ran on the pool, every one of them
+        assert st["host_chunks"] >= 1
+        assert eng.chunks_dispatched == st["host_chunks"]
+        assert crypto_batch.calibration.host_s != host_s0
     finally:
+        sched.close()
+        eng.close()
         crypto_batch.set_min_tpu_batch(old_min)
         crypto_batch.set_default_backend(old)
 

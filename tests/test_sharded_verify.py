@@ -1,7 +1,7 @@
 """Production multi-chip verify path (VERDICT r1 missing #1).
 
 On the virtual 8-device CPU mesh (conftest), the PRODUCTION seam —
-crypto/batch.TpuBatchVerifier -> ops/ed25519.verify_batch — must
+crypto/scheduler.py -> ops/ed25519.verify_batch_async — must
 lane-shard over all local devices via shard_map and return verdicts
 identical to the single-device/host path. The driver's
 dryrun_multichip exercises the same code path.

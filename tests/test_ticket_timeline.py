@@ -18,7 +18,6 @@ import numpy as np
 import pytest
 
 from cometbft_tpu.crypto import batch as crypto_batch
-from cometbft_tpu.crypto import mesh_backend as mesh_mod
 from cometbft_tpu.crypto import scheduler as sched_mod
 from cometbft_tpu.crypto.keys import Ed25519PrivKey, Ed25519PubKey
 from cometbft_tpu.node.inprocess import make_genesis
@@ -151,13 +150,17 @@ def _jobs(chain, heights, bad_height=None):
     return gen.chain_id, jobs
 
 
-def _route(monkeypatch, route):
+def _route(route):
     if route == "host":
         crypto_batch.set_default_backend("cpu")
     else:
-        crypto_batch.set_default_backend("mesh")
+        # forced past the floor: the mesh route where the kernel
+        # fixture gave more than one device, the tpu route on one
+        # (the decision and ops/ed25519 read the same device count)
         crypto_batch.set_min_tpu_batch(1)
-        monkeypatch.setattr(mesh_mod, "mesh_devices", lambda: 8)
+        crypto_batch.set_default_backend(
+            "mesh" if device.backend().count > 1 else "tpu"
+        )
 
 
 def _ticket_spans(ring, ticket):
@@ -171,7 +174,7 @@ def _ticket_spans(ring, ticket):
 def test_ticket_leaves_every_stage_in_order(
     route, chain, ring, fresh_scheduler, stubbed_kernel, monkeypatch
 ):
-    _route(monkeypatch, route)
+    _route(route)
     chain_id, jobs = _jobs(chain, range(1, 5), bad_height=2)
     handle = verify_commits_coalesced_async(chain_id, jobs)
     errors = handle.result()
@@ -231,7 +234,7 @@ def test_ticket_leaves_every_stage_in_order(
 def test_span_count_does_not_grow_with_the_ticket(
     route, chain, ring, fresh_scheduler, stubbed_kernel, monkeypatch
 ):
-    _route(monkeypatch, route)
+    _route(route)
     counts = []
     for heights in (range(1, 2), range(1, 6)):
         handle = verify_commits_coalesced_async(*_jobs(chain, heights))
@@ -268,7 +271,7 @@ def test_put_and_fetch_are_children_with_one_name_on_both_paths(
     sharded program's shardings, the verdicts read from every
     device)."""
     request.getfixturevalue("stubbed_kernel" if devices == 1 else "stubbed_mesh")
-    _route(monkeypatch, "device")
+    _route("device")
     chain_id, jobs = _jobs(chain, range(1, 5), bad_height=3)
     handle = verify_commits_coalesced_async(chain_id, jobs)
     assert [e is None for e in handle.result()] == [True, True, False, True]

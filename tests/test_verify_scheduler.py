@@ -14,12 +14,10 @@ import time
 import pytest
 
 from cometbft_tpu.crypto import batch as crypto_batch
-from cometbft_tpu.crypto import mesh_backend as mesh_mod
 from cometbft_tpu.crypto import parallel_verify as pv
 from cometbft_tpu.crypto import scheduler as sched_mod
 from cometbft_tpu.crypto.batch import CpuBatchVerifier
 from cometbft_tpu.crypto.keys import Ed25519PrivKey, Secp256k1PrivKey
-from cometbft_tpu.crypto.mesh_backend import LAST_MESH, MeshBatchVerifier
 from cometbft_tpu.crypto.scheduler import (
     PRIORITY_CATCHUP,
     PRIORITY_LIGHT,
@@ -27,6 +25,7 @@ from cometbft_tpu.crypto.scheduler import (
     VerifyScheduler,
     VerifyTicket,
 )
+from cometbft_tpu.utils import device
 
 # key generation dominates test wall time: a small reusable pool is
 # plenty (verdicts depend on (msg, sig), not key uniqueness)
@@ -80,9 +79,17 @@ def restore_routing():
     crypto_batch.set_min_tpu_batch(old_floor)
 
 
+def set_device_count(monkeypatch, n):
+    """The routing decision reads the device count from
+    utils/device.backend(): give it ``n`` devices."""
+    monkeypatch.setattr(
+        device, "backend", lambda: device.Backend("cpu", "cpu", n)
+    )
+
+
 class FakeDeviceHandle:
     """Stands in for ops.ed25519.AsyncVerdicts: verdicts computed by
-    the same per-key host math the backends fall back to."""
+    the same per-key host math the scheduler falls back to."""
 
     def __init__(self, ed_items):
         from cometbft_tpu.crypto.keys import Ed25519PubKey
@@ -114,7 +121,7 @@ def test_serial_equivalence_differential(sched, cpu_backend):
 
 
 def test_empty_submit_matches_batch_verifier(sched, cpu_backend):
-    # BatchVerifier.verify() on zero lanes is (False, []); an empty
+    # CpuBatchVerifier.verify() on zero lanes is (False, []); an empty
     # ticket must resolve immediately with the same shape
     t = sched.submit([], priority=PRIORITY_LIGHT)
     assert t.done()
@@ -143,31 +150,6 @@ def test_priority_clamped(sched, cpu_backend):
     t3 = sched.submit(items, priority=None)
     assert t3.priority == PRIORITY_CATCHUP
     t3.result(timeout=30)
-
-
-def test_custom_backend_passthrough(sched, restore_routing):
-    """An operator-registered backend keeps its semantics verbatim:
-    the scheduler builds it and resolves the whole ticket through it."""
-    built = []
-
-    class Recording(CpuBatchVerifier):
-        def __init__(self):
-            super().__init__()
-            built.append(self)
-
-    crypto_batch.register_backend("unit-test-backend", Recording)
-    try:
-        crypto_batch.set_default_backend("unit-test-backend")
-        items = make_items(6, bad={2})
-        want = serial_verdicts(items)
-        t = sched.submit(items, priority=PRIORITY_LIVE)
-        assert t.result(timeout=30) == want
-        assert t.backend == "unit-test-backend"
-        assert len(built) == 1 and len(built[0]) == 6
-    finally:
-        crypto_batch.set_default_backend("cpu")
-        with crypto_batch._lock:
-            crypto_batch._BACKENDS.pop("unit-test-backend", None)
 
 
 # --- priority ordering / starvation guard --------------------------------
@@ -265,9 +247,7 @@ def test_mesh_route_dispatches_device(sched, restore_routing, monkeypatch):
 
     crypto_batch.set_default_backend("mesh")
     crypto_batch.set_min_tpu_batch(1)  # force past the batch floor
-    monkeypatch.setattr(
-        mesh_mod, "mesh_devices", lambda: 8
-    )
+    set_device_count(monkeypatch, 8)
     dispatched = []
 
     def fake_async(ed_items):
@@ -290,9 +270,7 @@ def test_mesh_degrades_without_mesh(sched, restore_routing, monkeypatch):
 
     crypto_batch.set_default_backend("mesh")
     crypto_batch.set_min_tpu_batch(1)
-    monkeypatch.setattr(
-        mesh_mod, "mesh_devices", lambda: 1
-    )
+    set_device_count(monkeypatch, 1)
 
     def boom(ed_items):  # pragma: no cover - must never be reached
         raise AssertionError("degraded route must not touch the device")
@@ -316,9 +294,7 @@ def test_mesh_degrades_on_dispatch_failure(
 
     crypto_batch.set_default_backend("mesh")
     crypto_batch.set_min_tpu_batch(1)
-    monkeypatch.setattr(
-        mesh_mod, "mesh_devices", lambda: 8
-    )
+    set_device_count(monkeypatch, 8)
 
     def boom(ed_items):
         raise RuntimeError("no XLA for you")
@@ -332,69 +308,34 @@ def test_mesh_degrades_on_dispatch_failure(
     assert sched.degraded == 1
 
 
-def test_mesh_backend_verifier_host_parity(restore_routing):
-    """MeshBatchVerifier below the floor / without a mesh verifies on
-    the host plane with CpuBatchVerifier-identical verdicts."""
+def test_mesh_below_the_floor_stays_on_the_host(
+    sched, restore_routing, monkeypatch
+):
+    """A mesh exists, but the ticket is under the batch floor: it
+    verifies on the host plane with CpuBatchVerifier's verdicts, not
+    degraded, and the device is never touched."""
+    import cometbft_tpu.ops.ed25519 as ops_ed
+
+    crypto_batch.set_default_backend("mesh")
+    crypto_batch.set_min_tpu_batch(64)
+    set_device_count(monkeypatch, 8)
+
+    def boom(ed_items):  # pragma: no cover - must never be reached
+        raise AssertionError("a ticket under the floor went to the device")
+
+    monkeypatch.setattr(ops_ed, "verify_batch_async", boom)
     items = make_items(8, bad={2}, mixed=True)
     want = serial_verdicts(items)
-    v = MeshBatchVerifier()
-    for pk, msg, sig in items:
-        v.add(pk, msg, sig)
-    assert v.verify() == want
-    assert LAST_MESH["path"] in ("host", "host-degraded")
-
-
-def test_mesh_backend_registered(restore_routing):
-    assert "mesh" in crypto_batch.backends()
-    crypto_batch.set_default_backend("mesh")
-    assert isinstance(
-        crypto_batch.create_batch_verifier(), MeshBatchVerifier
+    t = sched.submit(items, priority=PRIORITY_LIVE, label="small")
+    assert t.result(timeout=30) == want
+    assert t.backend == "mesh"
+    assert crypto_batch.LAST_ROUTE["path"] == "host"
+    assert crypto_batch.LAST_ROUTE["n"] == sum(
+        1 for pk, _, _ in items if pk.type_ == "ed25519"
     )
-
-
-def test_mesh_backend_sharded_path(restore_routing, monkeypatch):
-    import cometbft_tpu.ops.ed25519 as ops_ed
-
-    crypto_batch.set_min_tpu_batch(1)
-    monkeypatch.setattr(
-        mesh_mod, "mesh_devices", lambda: 8
-    )
-    monkeypatch.setattr(
-        ops_ed,
-        "verify_batch",
-        lambda ed_items: FakeDeviceHandle(ed_items).verdicts,
-    )
-    items = make_items(16, bad={9}, mixed=True)
-    want = serial_verdicts(items)
-    v = MeshBatchVerifier()
-    for pk, msg, sig in items:
-        v.add(pk, msg, sig)
-    assert v.verify() == want
-    assert LAST_MESH["path"] == "mesh"
-    assert LAST_MESH["devices"] == 8
-
-
-def test_mesh_backend_degrades_on_kernel_error(
-    restore_routing, monkeypatch
-):
-    import cometbft_tpu.ops.ed25519 as ops_ed
-
-    crypto_batch.set_min_tpu_batch(1)
-    monkeypatch.setattr(
-        mesh_mod, "mesh_devices", lambda: 8
-    )
-
-    def boom(ed_items):
-        raise RuntimeError("mesh fell over")
-
-    monkeypatch.setattr(ops_ed, "verify_batch", boom)
-    items = make_items(8, bad={3})
-    want = serial_verdicts(items)
-    v = MeshBatchVerifier()
-    for pk, msg, sig in items:
-        v.add(pk, msg, sig)
-    assert v.verify() == want  # bit-identical host degrade, no wedge
-    assert LAST_MESH["path"] == "host-degraded"
+    st = sched.stats()
+    assert st["degraded"] == 0 and st["device_dispatches"] == 0
+    assert st["host_chunks"] >= 1
 
 
 # --- observability -------------------------------------------------------
