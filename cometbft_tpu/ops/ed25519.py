@@ -513,7 +513,9 @@ def verify_batch_async(items) -> AsyncVerdicts:
     one timeline"), carrying the verify ticket the calling thread
     works for (trace.ticket_scope; None when called directly):
     ``ops.ed25519.pack`` (the bulk fill of the padded arrays; its
-    ``bad`` counts the lanes refused before the device) and
+    ``bad`` counts the lanes refused before the device; in the precomp
+    forms ``keys`` counts the dispatch's distinct keys and ``expanded``
+    those ``_expand_pubkey`` did not find in ``_A_CACHE``) and
     ``ops.ed25519.enqueue`` (placement of the host arrays, its child
     span ``ops.ed25519.put``, and the jitted call)."""
     n = len(items)
@@ -533,6 +535,9 @@ def verify_batch_async(items) -> AsyncVerdicts:
             devices=d["n_devices"],
             lanes_per_device=d["lanes"] // d["n_devices"],
         )
+        if d["precomp"]:
+            # the host-expanded-key LRU's work for this dispatch
+            sp.set(keys=d["keys"], expanded=d["expanded"])
     nbytes = sum(a.nbytes for a in arrays)
     with tr.annotated_span(
         "ops.ed25519.enqueue", tid=tid, ticket=ticket,
@@ -612,8 +617,11 @@ def _pack(items):
         )
         table = np.zeros((4, fe.NLIMBS, len(uniq)), np.int32)
         key_ok = np.zeros(len(uniq), bool)
+        expanded = 0  # keys the LRU did not hold
         for j, key in enumerate(uniq):
-            A = _expand_pubkey(key.tobytes())
+            key = key.tobytes()
+            expanded += key not in _A_CACHE
+            A = _expand_pubkey(key)
             if A is not None:  # else: fails ZIP-215 decompression
                 table[:, :, j] = A
                 key_ok[j] = True
@@ -664,6 +672,8 @@ def _pack(items):
         # the Pallas INTERPRETER ran the ladder (CPU platform only)
         interpret=eff_pallas and interpret_mode(),
     )
+    if use_precomp:
+        LAST_DISPATCH.update(keys=len(uniq), expanded=expanded)
     if tuple_a:
         fn = sharded or verify_core_precomp_tuple_jit
     elif use_precomp:

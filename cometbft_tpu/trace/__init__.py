@@ -72,11 +72,16 @@ __all__ = [
     "write_jsonl",
 ]
 
-# process-wide tracer for cross-node planes (the verify path). A
-# device-routed verify ticket leaves 11 spans; the ring has to hold a
-# whole measured window of them (the benchmark's readers give nothing
-# once it has dropped one): 16,384 slots are ~1,450 tickets
-_GLOBAL = Tracer(name="process", size=16384)
+# process-wide tracer for cross-node planes (the verify path). The
+# ring has to hold a whole measured window of a ticket's spans (the
+# benchmark's readers give nothing once it has dropped one). A
+# device-routed ticket leaves 11 spans, a host-routed one of 150 lanes
+# 19 (13 crypto.verify_chunk). The window that makes most tickets is
+# the benchmark's val150.commit-live, one ticket a commit: 25 commits/s
+# x 40 s + 64 of warm-up + 12 of the traced slice = 1,076 tickets;
+# x 19 spans x 1.25 of room = 25,555 slots; the next power of two.
+# (qa175.verify-only: ~800 tickets of 11, and twice that fit.)
+_GLOBAL = Tracer(name="process", size=32768)
 
 
 def global_tracer() -> Tracer:

@@ -237,23 +237,54 @@ def verify_commit(
     (including nil votes), and >2/3 of power must have signed block_id.
     (reference types/validation.go:30; used by blocksync + ingest).
     ``priority`` is the verify-scheduler class (PRIORITY_LIVE for the
-    consensus hot path; default catch-up)."""
-    _basic_checks(vals, commit, height, block_id)
-    items = []
-    tally_idx = []
-    for i, cs in enumerate(commit.signatures):  # bftlint: disable=ASY117 — verifying an O(V) commit payload is O(V) by construction; once per commit received, curve math batch-verified via the lane cache
-        if cs.is_absent():
-            continue
-        val = vals.get_by_index(i)
-        if val.address != cs.validator_address:
-            raise CommitVerifyError(
-                f"commit sig {i} address mismatch with validator set"
+    consensus hot path; default catch-up).
+
+    One ticket of its own, so its two stages on the caller's thread
+    are spans as the coalesced seam's are (docs/TRACE.md "One ticket,
+    one timeline"): ``validation.commit.build`` (entry to ``submit()``
+    returned) and ``validation.commit.fold`` (verdicts in hand to
+    return or raise)."""
+    tr = global_tracer()
+    with tr.annotated_span(
+        "validation.commit.build", tid=_TID_CALLER
+    ) as sp:
+        _basic_checks(vals, commit, height, block_id)
+        items = []
+        tally_idx = []
+        for i, cs in enumerate(commit.signatures):  # bftlint: disable=ASY117 — verifying an O(V) commit payload is O(V) by construction; once per commit received, curve math batch-verified via the lane cache
+            if cs.is_absent():
+                continue
+            val = vals.get_by_index(i)
+            if val.address != cs.validator_address:
+                raise CommitVerifyError(
+                    f"commit sig {i} address mismatch with validator set"
+                )
+            items.append(
+                (val.pub_key, _commit_sign_bytes(chain_id, commit, cs), cs.signature)
             )
-        items.append(
-            (val.pub_key, _commit_sign_bytes(chain_id, commit, cs), cs.signature)
+            tally_idx.append(i)
+        handle = _run_batch_async(
+            items, cache, priority=priority, label="commit"
         )
-        tally_idx.append(i)
-    oks = _run_batch(items, cache, priority=priority, label="commit")
+        ticket = getattr(handle, "ticket_id", None)
+        sp.set(ticket=ticket, lanes=len(items))
+    if not isinstance(handle, _BatchHandle):
+        # a stand-in for the batch route (tests, the benchmark's
+        # control): no ticket, so no stage to record
+        return _fold_commit(handle.result(), tally_idx, vals, commit)
+    verdicts = handle.wait()
+    with tr.annotated_span(
+        "validation.commit.fold", tid=_TID_CALLER, ticket=ticket,
+        lanes=len(items),
+    ):
+        _fold_commit(handle.fill(verdicts), tally_idx, vals, commit)
+
+
+def _fold_commit(
+    oks: list, tally_idx: list, vals: ValidatorSet, commit: Commit
+) -> None:
+    """verify_commit's verdict and tally fold: the first refused lane
+    named, then the for-block power against 2/3."""
     tallied = 0
     for (i, ok) in zip(tally_idx, oks):
         if not ok:

@@ -323,16 +323,21 @@ def test_pack_and_enqueue_say_what_last_dispatch_says(
     ev = [e for e in ring.snapshot() if e["name"].startswith("ops.ed25519.")]
     # a span is recorded at its end: the child before its parent
     assert [e["name"] for e in ev] == [PACK, PUT, ENQUEUE, FETCH] * 2
-    for pack, enqueue, ticket, tid in (
-        (ev[0], ev[2], None, "ops.ed25519"),
-        (ev[4], ev[6], 42, "some.row"),
+    for pack, enqueue, ticket, tid, expanded in (
+        (ev[0], ev[2], None, "ops.ed25519", 1),
+        (ev[4], ev[6], 42, "some.row", 0),
     ):
         lanes = last["lanes"]
-        assert pack["args"] == {
+        want_args = {
             "ticket": ticket, "sigs": len(items), "lanes": lanes,
             "cap": last["cap"], "mode": last["mode"], "bad": refused,
             "devices": 1, "lanes_per_device": lanes,
         }
+        if last["precomp"]:
+            # one distinct 32-byte key, new to the expanded-key LRU
+            # in the first dispatch and found there in the second
+            want_args.update(keys=1, expanded=expanded)
+        assert pack["args"] == want_args
         # msgs + lens + A (precomp) + pks, rs, ss
         want_bytes = last["cap"] * lanes + 4 * lanes + 3 * 32 * lanes
         if last["precomp"]:
