@@ -3,10 +3,12 @@ verification consumer (docs/PERF.md "Unified verify scheduler").
 
 Every consumer (types/validation's commit seams, the consensus vote
 coalescer crypto/coalesce, the light serving plane, blocksync,
-statesync, evidence) submits its ``(pubkey, msg, sig)`` lanes here
-and gets a ticket; nothing else reaches the kernel or the host pool,
-so a live round's precommit wave never queues behind a 500-block
-catch-up window. A ticket's life: queue (by class) -> ``_plan`` (lane
+statesync, evidence) submits its lanes here (``(pubkey, msg, sig)``
+tuples, or a crypto/lanes.LaneBatch: the ed25519 lanes of a window by
+columns, which go through to the device arrays with no Python step a
+lane) and gets a ticket; nothing else reaches the kernel or the host
+pool, so a live round's precommit wave never queues behind a
+500-block catch-up window. A ticket's life: queue (by class) -> ``_plan`` (lane
 split by curve + the one routing decision, ``crypto/batch.decide``)
 -> ``ops/ed25519.verify_batch_async`` (pack, put, the jitted program,
 one device or lane-sharded over a mesh) and a watcher thread, or
@@ -49,10 +51,13 @@ import time
 from collections import deque
 from typing import List, Optional, Sequence, Tuple
 
+import numpy as np
+
 from ..trace import global_tracer, ticket_scope
 from ..utils.log import get_logger
 from . import batch as crypto_batch
 from .keys import Ed25519PubKey
+from .lanes import LaneBatch
 
 _log = get_logger("crypto.sched")
 
@@ -95,17 +100,20 @@ def _clamp_priority(priority) -> int:
 class VerifyTicket:
     """One submitted batch: ``result()`` blocks for the merged
     verdicts and returns ``(all_ok, oks)``, ``oks`` in submission
-    order (what crypto/batch.CpuBatchVerifier.verify returns)."""
+    order (what crypto/batch.CpuBatchVerifier.verify returns): a
+    list of bool for a ticket of tuples, a bool array for a columnar
+    one (``items`` a LaneBatch)."""
 
     __slots__ = (
-        "id", "items", "priority", "label", "t_submit", "t_submit_ns",
-        "t_done", "oks", "backend", "depth_ahead", "_chunks",
-        "_units_left", "_event", "_routed",
+        "id", "items", "columnar", "priority", "label", "t_submit",
+        "t_submit_ns", "t_done", "oks", "backend", "depth_ahead",
+        "_chunks", "_units_left", "_event", "_routed",
     )
 
     def __init__(self, items, priority: int, label: str) -> None:
         self.id = next(_TICKET_IDS)
         self.items = items
+        self.columnar = isinstance(items, LaneBatch)
         self.priority = priority
         self.label = label
         self.t_submit = time.perf_counter()
@@ -114,7 +122,9 @@ class VerifyTicket:
         self.t_submit_ns = time.monotonic_ns()
         self.depth_ahead = 0  # lanes queued ahead of it at submit
         self.t_done: Optional[float] = None
-        self.oks: List[bool] = [False] * len(items)
+        # a bool array while the ticket is worked on (every write-back
+        # is one assignment); _finish makes a tuple ticket's a list
+        self.oks = np.zeros(len(items), bool)
         self.backend: Optional[str] = None
         self._chunks: deque = deque()
         self._units_left = 0
@@ -129,7 +139,7 @@ class VerifyTicket:
                 f"within {timeout}s"
             )
         oks = self.oks
-        return all(oks) and bool(oks), oks
+        return len(oks) > 0 and bool(np.all(oks)), oks
 
     def done(self) -> bool:
         return self._event.is_set()
@@ -174,6 +184,7 @@ class VerifyScheduler:
         self.host_chunks = 0
         self.degraded = 0
         self.tickets = 0
+        self.columnar_tickets = 0
 
     # --- submission ----------------------------------------------------
 
@@ -183,12 +194,20 @@ class VerifyScheduler:
         priority: int = PRIORITY_CATCHUP,
         label: str = "",
     ) -> VerifyTicket:
-        """Queue (pubkey, msg, sig) lanes for verification under a
-        priority class; returns immediately with a VerifyTicket."""
+        """Queue lanes for verification under a priority class;
+        returns immediately with a VerifyTicket. ``items``: a sequence
+        of (pubkey, msg, sig), any curve, copied; or a
+        crypto/lanes.LaneBatch (ed25519 lanes by columns, none
+        refused, as types/validation builds them), kept as it is:
+        its ticket's verdicts are a bool array."""
         priority = _clamp_priority(priority)
-        ticket = VerifyTicket(list(items), priority, label)
-        if not ticket.items:
+        ticket = VerifyTicket(
+            items if isinstance(items, LaneBatch) else list(items),
+            priority, label,
+        )
+        if not len(ticket.items):
             # empty batch resolves to (False, []) like CpuBatchVerifier
+            ticket.oks = []
             ticket.t_done = ticket.t_submit
             ticket._event.set()
             return ticket
@@ -196,6 +215,7 @@ class VerifyScheduler:
             if self._closed:
                 raise RuntimeError("verify scheduler closed")
             self.tickets += 1
+            self.columnar_tickets += ticket.columnar
             n = len(ticket.items)
             self.enqueued_lanes += n
             self.enqueued_by_class[priority] += n
@@ -308,12 +328,13 @@ class VerifyScheduler:
         with global_tracer().annotated_span(
             "crypto.sched.route", tid=_TID_DISPATCHER,
             ticket=ticket.id, lanes=len(ticket.items),
+            form="columns" if ticket.columnar else "tuples",
         ) as sp:
             plan = self._plan(ticket)
             sp.set(path=plan[0])
         path, ed_idx, ed_items = plan
         backend = ticket.backend
-        if path == "device" and ed_idx:
+        if path == "device" and len(ed_idx):
             if self._dispatch_device(ticket, ed_idx, ed_items, backend):
                 return
             # device dispatch failed: re-route the lanes to host
@@ -324,8 +345,20 @@ class VerifyScheduler:
     def _plan(self, ticket: VerifyTicket) -> tuple:
         """Split the lanes by curve and ask the routing decision for
         the ed25519 ones: (path, ed_idx, ed_items) with path
-        ``device`` | ``host``. Other curves verify inline, here."""
+        ``device`` | ``host``, ``ed_idx`` their positions in the
+        ticket and ``ed_items`` what ops/ed25519.verify_batch_async
+        takes. A columnar ticket (``items`` a LaneBatch) is ed25519 by
+        construction: every lane, the batch itself, no pass over the
+        lanes. A ticket of ``(pubkey, msg, sig)`` tuples is split a
+        lane at a time into ``(msg, key_bytes, sig)`` tuples; its
+        other curves verify inline, here."""
         items = ticket.items
+        if ticket.columnar:
+            path, ticket.backend, degraded = crypto_batch.decide(len(items))
+            if degraded:
+                self.degraded += 1
+            ticket._routed = True
+            return path, range(len(items)), items
         ed_idx: List[int] = []
         ed_items = []
         other_idx: List[int] = []
@@ -402,8 +435,7 @@ class VerifyScheduler:
                 verdicts = [
                     _host_verify_one(ticket.items[i]) for i in ed_idx
                 ]
-            for i, v in zip(ed_idx, verdicts):
-                ticket.oks[i] = bool(v)
+            ticket.oks[_at(ed_idx)] = verdicts
             self._unit_done(ticket, n_ed, sp)
 
         threading.Thread(
@@ -416,7 +448,7 @@ class VerifyScheduler:
         serial work each — the preemption granularity) and requeue the
         ticket at the FRONT of its class so its chunks drain before
         later same-class arrivals."""
-        if not ed_idx:
+        if not len(ed_idx):
             if ticket._units_left == 0:
                 self._finish(ticket, 0)
             return
@@ -485,8 +517,7 @@ class VerifyScheduler:
         fut.add_done_callback(_done)
 
     def _chunk_resolved(self, ticket, idx_chunk, oks, wall, eng) -> None:
-        for i, ok in zip(idx_chunk, oks):
-            ticket.oks[i] = bool(ok)
+        ticket.oks[_at(idx_chunk)] = oks
         n = len(idx_chunk)
         if wall:
             eng._observe_chunk(n, wall)
@@ -529,6 +560,8 @@ class VerifyScheduler:
                 "crypto.sched.resolve", tid=_TID_HOST,
                 ticket=ticket.id, lanes=len(ticket.items),
             )
+        if not ticket.columnar and isinstance(ticket.oks, np.ndarray):
+            ticket.oks = ticket.oks.tolist()
         ticket.t_done = time.perf_counter()
         with self._cv:
             n = len(ticket.items)
@@ -583,6 +616,7 @@ class VerifyScheduler:
         with self._cv:
             return {
                 "tickets": self.tickets,
+                "columnar_tickets": self.columnar_tickets,
                 "lanes": self.enqueued_lanes,
                 "by_class": {
                     name: self.enqueued_by_class[cls]
@@ -614,6 +648,12 @@ class VerifyScheduler:
         t = self._thread
         if t is not None:
             t.join(timeout=5.0)
+
+
+def _at(idx):
+    """Index of a ticket's verdicts for the lanes ``idx``: a run of
+    them (a columnar ticket's, or a chunk of one) as a slice."""
+    return slice(idx.start, idx.stop) if isinstance(idx, range) else idx
 
 
 def _host_verify_one(item) -> bool:

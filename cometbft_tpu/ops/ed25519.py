@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..crypto.lanes import LaneBatch
 from ..trace import current_ticket, global_tracer
 from ..utils import device
 from . import curve25519 as curve
@@ -424,6 +425,14 @@ PAD_MIN = 128
 #   defeat the tuple-of-limbs fusion: 550ms vs 363ms @131072 lanes).
 PRECOMP_MAX_LANES = 4096
 
+# Lanes transposed at a time when _pack turns (lane, byte) rows into
+# the kernel's batch-last layout: the width of the one-chip cells'
+# dispatch, which is therefore ONE block; a wider batch (the mesh's
+# 65,536 lanes) goes in few large blocks, one array operation each.
+# Wider, and the rows fall out of the cache between two reads of a
+# column; narrower, and the operations' GIL hand-backs outweigh it.
+PACK_BLOCK = 16_384
+
 
 def _pad_n(n: int) -> int:
     """Pad batch to limit recompilation: powers of two >= PAD_MIN."""
@@ -509,10 +518,17 @@ def verify_batch_async(items) -> AsyncVerdicts:
     """Enqueue one verify dispatch WITHOUT blocking on the verdicts
     (see AsyncVerdicts). Same prep/dispatch as verify_batch.
 
+    ``items``: a crypto/lanes.LaneBatch (the lanes by columns, as the
+    verify seam hands them through the scheduler) or a sequence of
+    ``(msg, key_bytes, sig)`` tuples, turned into one HERE, inside the
+    ``pack`` span: below this boundary a batch has one form.
+
     Two stage spans on the process tracer (docs/TRACE.md "One ticket,
     one timeline"), carrying the verify ticket the calling thread
     works for (trace.ticket_scope; None when called directly):
     ``ops.ed25519.pack`` (the bulk fill of the padded arrays; its
+    ``form`` says how the lanes arrived, ``columns`` | ``tuples``, its
+    ``blocks`` in how many lane blocks they were transposed, its
     ``bad`` counts the lanes refused before the device; in the precomp
     forms ``keys`` counts the dispatch's distinct keys and ``expanded``
     those ``_expand_pubkey`` did not find in ``_A_CACHE``) and
@@ -524,16 +540,21 @@ def verify_batch_async(items) -> AsyncVerdicts:
     tr = global_tracer()
     ticket, tid = current_ticket()
     tid = tid or "ops.ed25519"
+    columnar = isinstance(items, LaneBatch)
     with tr.annotated_span(
-        "ops.ed25519.pack", tid=tid, ticket=ticket, sigs=n
+        "ops.ed25519.pack", tid=tid, ticket=ticket, sigs=n,
+        form="columns" if columnar else "tuples",
     ) as sp:
-        fn, arrays, tuple_a, shardings, bad = _pack(items)
+        fn, arrays, tuple_a, shardings, bad = _pack(
+            items if columnar else LaneBatch.from_items(items)
+        )
         d = LAST_DISPATCH
         sp.set(
             lanes=d["lanes"], cap=d["cap"], mode=d["mode"],
             bad=int(np.count_nonzero(bad)),
             devices=d["n_devices"],
             lanes_per_device=d["lanes"] // d["n_devices"],
+            blocks=-(-n // PACK_BLOCK),
         )
         if d["precomp"]:
             # the host-expanded-key LRU's work for this dispatch
@@ -557,32 +578,40 @@ def verify_batch_async(items) -> AsyncVerdicts:
 _KEY32 = np.dtype((np.void, 32))
 
 
-def _join(field, good) -> bytes:
-    """The good lanes' bytes of one field, end to end."""
-    return b"".join(itertools.compress(field, good.tolist()))
+def _batch_last(rows, refused, lanes: int):
+    """(n, width) rows, one a lane -> (width, lanes) C-contiguous,
+    the ``refused`` lanes and those past n zero; transposed a block of
+    PACK_BLOCK lanes at a time (a wider block's rows fall out of the
+    cache between two reads of a column)."""
+    n = len(rows)
+    out = np.zeros((rows.shape[1], lanes), np.uint8)
+    for lo in range(0, n, PACK_BLOCK):
+        hi = min(lo + PACK_BLOCK, n)
+        out[:, lo:hi] = rows[lo:hi].T
+    if len(refused):
+        out[:, refused] = 0
+    return out
 
 
-def _batch_last(rows, at, lanes: int):
-    """(len(at), width) rows of the lanes ``at`` -> (width, lanes)
-    C-contiguous, every other lane zero."""
-    out = np.zeros((lanes, rows.shape[1]), np.uint8)
-    out[at] = rows
-    return np.ascontiguousarray(out.T)
-
-
-def _pack(items):
+def _pack(batch: LaneBatch):
     """Bucket and kernel choice, and the bulk fill of the padded host
-    arrays: the lanes' bytes are joined once a field and scattered by
-    array operations, with no Python step a lane. Returns (fn, host
+    arrays from a crypto/lanes.LaneBatch, the lanes by columns (the
+    tuple form is turned into one at verify_batch_async): the
+    messages are measured, padded to the bucket and joined (three
+    C-level passes: they are ragged), keys and signatures come as
+    rows, and every field goes to the kernel's batch-last layout
+    PACK_BLOCK lanes at a time, with no Python step a lane and no
+    transpose wider than a block. Returns (fn, host
     arrays in argument order, whether A goes as a pytree, the sharded
     program's argument shardings or None on one device, bad lanes);
     LAST_DISPATCH says the shape.
 
     A lane is ``bad`` (refused before the device, all zero in every
-    array) when its key is not 32 bytes, its signature not 64, or, in
-    the precomp forms, its key fails ZIP-215 decompression."""
-    n = len(items)
-    ms, pk_t, sig_t = zip(*items)
+    array) when its key is not 32 bytes, its signature not 64 (the
+    batch's ``bad``), or, in the precomp forms, its key fails ZIP-215
+    decompression."""
+    n = len(batch)
+    ms = batch.msgs
     m_lens = np.fromiter(map(len, ms), np.int32, n)
     cap = bucket_cap(int(m_lens.max()))  # over ALL items, bad ones too
     np_ = _pad_n(n)
@@ -604,16 +633,15 @@ def _pack(items):
     )
     sharded, shardings = _sharded_fn(mode)
 
-    good = (np.fromiter(map(len, pk_t), np.int32, n) == 32) & (
-        np.fromiter(map(len, sig_t), np.int32, n) == 64
-    )
-    pk_rows = np.frombuffer(_join(pk_t, good), np.uint8).reshape(-1, 32)
+    refused = batch.bad
     a_arr = None
     if use_precomp:
         # each DISTINCT key is expanded once a dispatch (a validator
         # set has a few hundred of them for thousands of lanes)
+        good = np.ones(n, bool)
+        good[refused] = False
         uniq, inverse = np.unique(
-            pk_rows.view(_KEY32).ravel(), return_inverse=True
+            batch.keys[good].view(_KEY32).ravel(), return_inverse=True
         )
         table = np.zeros((4, fe.NLIMBS, len(uniq)), np.int32)
         key_ok = np.zeros(len(uniq), bool)
@@ -627,27 +655,30 @@ def _pack(items):
                 key_ok[j] = True
         a_arr = np.zeros((4, fe.NLIMBS, np_), np.int32)
         a_arr[:, :, np.flatnonzero(good)] = table[:, :, inverse]
-        on_curve = key_ok[inverse]
-        pk_rows = pk_rows[on_curve]
-        good[good] = on_curve
-    at = np.flatnonzero(good)
+        good[good] = key_ok[inverse]
+        refused = np.flatnonzero(~good)
     bad = np.zeros(np_, bool)
-    bad[:n] = ~good
+    bad[refused] = True
 
-    # filled row-major, (lane, byte), where a lane's bytes are one run
-    # of the joined buffer; then one transpose a field to the kernel's
-    # batch-last layout
+    # the messages padded to cap and joined (one C-level pass that
+    # keeps the GIL) are their (lane, byte) rows; every field then
+    # goes to the kernel's batch-last layout in few LARGE array
+    # operations: each gives the GIL away and waits for it while
+    # another thread runs Python, so a dozen small ones cost more
+    # than the copying they do (PERF.md, PR 36)
     lens = np.zeros(np_, np.int32)
-    lens[at] = m_lens[at]
-    m_rows = np.zeros((np_, cap), np.uint8)
-    m_rows[np.arange(cap, dtype=np.int32) < lens[:, None]] = np.frombuffer(
-        _join(ms, good), np.uint8
-    )
-    msgs = np.ascontiguousarray(m_rows.T)
-    sig_rows = np.frombuffer(_join(sig_t, good), np.uint8).reshape(-1, 64)
-    pks, rs, ss = (
-        _batch_last(rows, at, np_)
-        for rows in (pk_rows, sig_rows[:, :32], sig_rows[:, 32:])
+    lens[:n] = m_lens
+    lens[refused] = 0
+    m_rows = np.frombuffer(
+        b"".join(
+            map(bytes.ljust, ms, itertools.repeat(cap), itertools.repeat(b"\0"))
+        ),
+        np.uint8,
+    ).reshape(n, cap)
+    sigs = batch.sigs
+    msgs, pks, rs, ss = (
+        _batch_last(rows, refused, np_)
+        for rows in (m_rows, batch.keys, sigs[:, :32], sigs[:, 32:])
     )
 
     # backend_key[0] reports the ladder the kernel ACTUALLY uses at

@@ -12,7 +12,15 @@ import threading
 from collections import OrderedDict
 from itertools import compress
 
+import numpy as np
+
 DEFAULT_CACHE_SIZE = 10_000
+
+
+def _row_bytes(rows) -> list:
+    """Each row of an (n, width) uint8 array as ``bytes``."""
+    rows = np.ascontiguousarray(rows)
+    return rows.view(np.dtype((np.void, rows.shape[1]))).ravel().tolist()
 
 
 class SignatureCache:
@@ -32,6 +40,13 @@ class SignatureCache:
         costs almost nothing (round-5 replay profile: sha256 keying was
         ~3% of replay host wall with a 0% hit rate on linear sync)."""
         return (sign_bytes, sig, pubkey)
+
+    @staticmethod
+    def keys_of_columns(sign_bytes, sig_rows, key_rows) -> list:
+        """``key()`` of every lane of a batch held by columns
+        (crypto/lanes.LaneBatch): the sign bytes one a lane, the
+        signatures and keys as (n, 64) and (n, 32) uint8 rows."""
+        return list(zip(sign_bytes, _row_bytes(sig_rows), _row_bytes(key_rows)))
 
     def contains(self, sign_bytes: bytes, sig: bytes, pubkey: bytes) -> bool:
         k = self.key(sign_bytes, sig, pubkey)

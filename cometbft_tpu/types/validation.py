@@ -19,11 +19,13 @@ from __future__ import annotations
 
 from bisect import bisect_right
 from fractions import Fraction
-from itertools import accumulate, compress, count, islice
-from operator import not_
+from itertools import accumulate, compress, islice
 from typing import Optional
 
+import numpy as np
+
 from ..crypto import scheduler as crypto_sched
+from ..crypto.lanes import LaneBatch
 from ..crypto.scheduler import (  # re-exported: consumers pass these
     PRIORITY_CATCHUP,
     PRIORITY_LIGHT,
@@ -116,30 +118,40 @@ def _run_batch_async(
     priority: Optional[int] = None,
     label: str = "",
 ):
-    """items: list of (pubkey, sign_bytes, sig). Returns a handle whose
-    ``result()`` yields list[bool] — async so callers (the blocksync
-    window pipeline) can overlap host work with the verification in
-    flight.
+    """items: list of (pubkey, sign_bytes, sig), or the same lanes by
+    columns (a crypto/lanes.LaneBatch, as ``_coalesce_lanes`` builds
+    them). Returns a handle whose ``result()`` yields list[bool] — async
+    so callers (the blocksync window pipeline) can overlap host work
+    with the verification in flight.
 
     THE single choke point onto the unified verify scheduler
     (crypto/scheduler.py): cache-unskipped lanes are submitted as one
-    ticket under the caller's priority class — live round > light
-    session > catch-up/evidence (default) — and the scheduler takes
-    the calibrated backend-routing decision from there. The handle is
-    genuinely pending on every backend: device batches ride the XLA
-    async dispatch, host-routed batches ride the slot-bounded chunk
-    pipeline — either way the caller's decode/apply work proceeds
-    while lanes verify (docs/PERF.md "Unified verify scheduler")."""
+    ticket, in the form they came in, under the caller's priority
+    class — live round > light session > catch-up/evidence (default)
+    — and the scheduler takes the calibrated backend-routing decision
+    from there. The handle is genuinely pending on every backend:
+    device batches ride the XLA async dispatch, host-routed batches
+    ride the slot-bounded chunk pipeline — either way the caller's
+    decode/apply work proceeds while lanes verify (docs/PERF.md
+    "Unified verify scheduler")."""
     # lanes: what the scheduler gets; to_verify: the item of each, or
     # None where every item goes, in order; keys: the cache's of each
     lanes, to_verify, keys = items, None, None
     if cache is not None:
-        key = cache.key
-        keys = [key(sb, sig, pk.key_bytes) for pk, sb, sig in items]
+        columnar = isinstance(items, LaneBatch)
+        if columnar:
+            keys = cache.keys_of_columns(items.msgs, items.sigs, items.keys)
+        else:
+            key = cache.key
+            keys = [key(sb, sig, pk.key_bytes) for pk, sb, sig in items]
         hits = cache.contains_many(keys)
         if True in hits:
             to_verify = _falses(hits)
-            lanes = [items[i] for i in to_verify]
+            lanes = (
+                items.take(to_verify)
+                if columnar
+                else [items[i] for i in to_verify]
+            )
             keys = [keys[i] for i in to_verify]
     pending = (
         crypto_sched.scheduler().submit(
@@ -147,7 +159,7 @@ def _run_batch_async(
             priority=PRIORITY_CATCHUP if priority is None else priority,
             label=label,
         )
-        if lanes
+        if len(lanes)
         else None
     )
     return _BatchHandle(items, to_verify, keys, pending, cache)
@@ -206,8 +218,9 @@ class _BatchHandle:
 
 def _falses(flags) -> list:
     """Positions of the false entries of a vector (the refused lanes
-    of a ticket's verdicts, the misses of a cache query), ascending."""
-    return list(compress(count(), map(not_, flags)))
+    of a ticket's verdicts, a list or a bool array; the misses of a
+    cache query), ascending."""
+    return np.flatnonzero(~np.asarray(flags, bool)).tolist()
 
 
 def _run_batch(
@@ -459,40 +472,116 @@ def verify_commits_coalesced_async(
     return _CoalescedHandle(batch_handle, jobs, plans, errors)
 
 
+class _SeamLanes:
+    """The lanes of one batch as ``_plan_commit`` collects them, a
+    commit at a time, by columns: ``len()`` = lanes so far."""
+
+    __slots__ = ("msgs", "commits", "key_parts", "sig_parts")
+
+    def __init__(self) -> None:
+        self.msgs: list = []  # sign bytes, one a lane
+        # a commit: (its set's columns, validators read, their votes)
+        self.commits: list = []
+        # a commit's key rows and its signatures end to end, while
+        # every commit so far could be held by columns; else None
+        self.key_parts: Optional[list] = []
+        self.sig_parts: list = []
+
+    def __len__(self) -> int:
+        return len(self.msgs)
+
+    def add(self, cols, want: list, votes: list, sign_bytes) -> None:
+        """One commit's lanes: the validator index and the vote of
+        each, in lane order, and their sign bytes."""
+        self.msgs.extend(sign_bytes)
+        self.commits.append((cols, want, votes))
+        if self.key_parts is None or not want:
+            return
+        # the commit's signatures checked by their joined length (and
+        # the longest, so that a 63 and a 65 do not pass as two 64s)
+        sigs = [cs.signature for cs in votes]
+        joined = b"".join(sigs)
+        if (
+            cols.key_rows is None
+            or len(joined) != 64 * len(sigs)
+            or max(map(len, sigs)) != 64
+        ):
+            # another curve, an odd key, an odd signature: this
+            # batch's lanes go as tuples (crypto/scheduler splits
+            # them by curve, ops/ed25519 refuses the odd ones)
+            self.key_parts = None
+            return
+        lo, hi = want[0], want[-1] + 1
+        self.key_parts.append(
+            cols.key_rows[lo:hi]
+            if hi - lo == len(want)  # ascending, so one run of the set
+            else cols.key_rows[want]
+        )
+        self.sig_parts.append(joined)
+
+    def batch(self):
+        """What ``_run_batch_async`` is handed: a LaneBatch, the key
+        rows and signatures concatenated once; or the
+        ``(pubkey, sign_bytes, sig)`` tuples where some commit could
+        not be held by columns (and ``[]`` for no lane)."""
+        if not self.msgs:
+            return []
+        if self.key_parts is not None:
+            return LaneBatch(
+                self.msgs,
+                np.concatenate(self.key_parts),
+                np.frombuffer(b"".join(self.sig_parts), np.uint8).reshape(
+                    -1, 64
+                ),
+            )
+        return list(
+            zip(
+                [cols.pub_keys[i] for cols, want, _ in self.commits for i in want],
+                self.msgs,
+                [cs.signature for _, _, votes in self.commits for cs in votes],
+            )
+        )
+
+
 def _coalesce_lanes(chain_id: str, jobs, light: bool):
     """One lane batch for every job's signatures, planned a COMMIT at
     a time from the columns of its validator set: (items, per-job
-    plan, per-job structural error or None). A plan is (first lane,
-    validator index of every lane the job reads, tallied power of
-    those that voted for the block); a job that failed reads none."""
-    items = []         # global lane batch
+    plan, per-job structural error or None). ``items`` is the batch by
+    columns, a crypto/lanes.LaneBatch, or, where a set holds another
+    curve or an odd key or a commit an odd signature, the
+    ``(pubkey, sign_bytes, sig)`` tuples of the same lanes. A plan is
+    (first lane, validator index of every lane the job reads, tallied
+    power of those that voted for the block); a job that failed reads
+    none."""
+    lanes = _SeamLanes()
     plans = []
     errors: list = [None] * len(jobs)
     for j, (vals, block_id, height, commit) in enumerate(jobs):
-        first = len(items)
+        first = len(lanes)
         try:
             _basic_checks(vals, commit, height, block_id)
-            want, tallied = _plan_commit(chain_id, vals, commit, light, items)
+            want, tallied = _plan_commit(chain_id, vals, commit, light, lanes)
         except CommitVerifyError as e:
             errors[j] = e
             want, tallied = (), 0
         plans.append((first, want, tallied))
-    return items, plans, errors
+    return lanes.batch(), plans, errors
 
 
 def _plan_commit(
     chain_id: str, vals: ValidatorSet, commit: Commit, light: bool,
-    items: list,
+    lanes: _SeamLanes,
 ):
-    """The lanes ONE commit's verification reads, appended to
-    ``items``: what the loops of verify_commit (``light=False``: every
-    non-absent vote) and _collect_light_lanes (the for-block votes up
-    to the one with which the tally passes 2/3) read, in their order,
-    from one pass over the commit's flags and slices of the set's
-    columns. Returns (validator index of each lane, tallied power of
-    the for-block ones). Raises the address mismatch of the first lane
-    that has one, after the lanes before it (the loops had appended
-    them)."""
+    """The lanes ONE commit's verification reads, added to ``lanes``
+    by columns (its set's columns, the validator index and the vote of
+    each lane, the sign bytes of each): what the loops of
+    verify_commit (``light=False``: every non-absent vote) and
+    _collect_light_lanes (the for-block votes up to the one with which
+    the tally passes 2/3) read, in their order, from one pass over the
+    commit's flags and slices of the set's columns. Returns (validator
+    index of each lane, tallied power of the for-block ones). Raises
+    the address mismatch of the first lane that has one, after the
+    lanes before it (the loops had appended them)."""
     cols = vals.columns()
     sigs = commit.signatures
     for_block = [cs.block_id_flag == BLOCK_ID_FLAG_COMMIT for cs in sigs]
@@ -531,12 +620,9 @@ def _plan_commit(
         stamp: _commit_sign_bytes(chain_id, commit, cs)
         for stamp, cs in dict(zip(stamps, votes)).items()
     }
-    items.extend(
-        zip(
-            read(cols.pub_keys),
-            map(sign_bytes.__getitem__, stamps),
-            [cs.signature for cs in votes],
-        )
+    lanes.add(
+        cols, want[: len(votes)], votes,
+        map(sign_bytes.__getitem__, stamps),
     )
     if mismatch is not None:
         raise mismatch

@@ -12,8 +12,10 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List, NamedTuple, Optional, Sequence
 
+import numpy as np
+
 from ..crypto import merkle
-from ..crypto.keys import PubKey
+from ..crypto.keys import Ed25519PubKey, PubKey
 from ..utils import proto
 
 PRIORITY_WINDOW_SIZE_FACTOR = 2
@@ -64,6 +66,22 @@ class ValidatorColumns(NamedTuple):
     addresses: List[bytes]
     pub_keys: List[PubKey]
     powers: List[int]
+    # the keys' bytes as one (n_vals, 32) uint8 array (read-only), a
+    # row a validator: the seam takes a commit's key rows as one slice
+    # of it (crypto/lanes.LaneBatch). None unless EVERY key is ed25519
+    # with 32 bytes: such a set's lanes go as tuples
+    key_rows: Optional[np.ndarray]
+
+
+def _key_rows(pub_keys: List[PubKey]) -> Optional[np.ndarray]:
+    if not all(
+        isinstance(pk, Ed25519PubKey) and len(pk.key_bytes) == 32
+        for pk in pub_keys
+    ):
+        return None
+    return np.frombuffer(
+        b"".join(pk.key_bytes for pk in pub_keys), np.uint8
+    ).reshape(-1, 32)
 
 
 class ValidatorSet:
@@ -110,10 +128,12 @@ class ValidatorSet:
         cols = getattr(self, "_columns", None)
         if cols is None:
             vals = self.validators
+            pub_keys = [v.pub_key for v in vals]
             cols = ValidatorColumns(
                 [v.address for v in vals],
-                [v.pub_key for v in vals],
+                pub_keys,
                 [v.voting_power for v in vals],
+                _key_rows(pub_keys),
             )
             self._columns = cols
         return cols

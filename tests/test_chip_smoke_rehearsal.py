@@ -14,6 +14,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import chip_smoke  # noqa: E402
 from cometbft_tpu.crypto import batch as crypto_batch  # noqa: E402
 from cometbft_tpu.crypto import scheduler as crypto_sched  # noqa: E402
+from cometbft_tpu.crypto.lanes import LaneBatch  # noqa: E402
 from cometbft_tpu.ops import ed25519 as ed  # noqa: E402
 
 SEED = 7
@@ -42,6 +43,11 @@ def host_kernel(monkeypatch):
         import jax.numpy as jnp
 
         def fake_async(items):
+            if isinstance(items, LaneBatch):  # a window's lanes by columns
+                items = [
+                    (msg, key.tobytes(), sig.tobytes())
+                    for msg, key, sig in zip(items.msgs, items.keys, items.sigs)
+                ]
             oks = np.array(chip_smoke.host_verdicts(items), bool)
             ed.LAST_DISPATCH.clear()
             ed.LAST_DISPATCH.update(

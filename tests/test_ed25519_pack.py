@@ -4,9 +4,15 @@ The reference below is the loop ``_pack`` ran before its fill became a
 handful of array operations, kept here as the test's own code: one
 Python step a lane, four ``np.frombuffer`` calls and five column
 writes. ``_pack`` must hand ``_put`` the same arrays, value for
-value, in shape, dtype and C-contiguity, for every input, bad lanes
-included: the verify program's HLO and its argument shapes then stay
-what they were. No kernel runs here.
+value, in shape, dtype, C-contiguity and ownership of the buffer, for
+every input, bad lanes included: the verify program's HLO and its
+argument shapes then stay what they were. No kernel runs here.
+
+``_pack`` takes the lanes by columns (crypto/lanes.LaneBatch). Every
+case is packed from both constructions of one: ``from_items``, the
+boundary every caller with tuples crosses, and the columns themselves
+as the verify seam holds them (built below a lane at a time); and in
+one block of lanes as in several small ones (``PACK_BLOCK``).
 """
 
 import functools
@@ -15,6 +21,7 @@ import numpy as np
 import pytest
 
 from cometbft_tpu.crypto import ref_ed25519
+from cometbft_tpu.crypto.lanes import LaneBatch
 from cometbft_tpu.ops import ed25519 as ops_ed
 from cometbft_tpu.ops import fe25519 as fe
 from cometbft_tpu.utils import device
@@ -22,6 +29,10 @@ from cometbft_tpu.utils import device
 PAD_MIN = 8
 CAPS = (47, 175, 431, 943)
 MODES = ("plain", "precomp", "precomp_tuple")
+FORMS = ("tuples", "columns")
+# lanes transposed at a time: the module's own (every case one block)
+# and one that cuts the 37-lane cases into 16 + 16 + 5
+BLOCKS = (ops_ed.PACK_BLOCK, 16)
 
 
 def _loop_pack(items, mode, n_dev=1):
@@ -105,6 +116,28 @@ def _items(lengths, seed=1):
     ]
 
 
+def _columns(items):
+    """The lanes by columns, built a lane at a time: a refused lane's
+    rows zero and its position in ``bad``."""
+    n = len(items)
+    keys = np.zeros((n, 32), np.uint8)
+    sigs = np.zeros((n, 64), np.uint8)
+    bad = []
+    for i, (_m, pk, sig) in enumerate(items):
+        if len(pk) != 32 or len(sig) != 64:
+            bad.append(i)
+            continue
+        keys[i] = np.frombuffer(pk, np.uint8)
+        sigs[i] = np.frombuffer(sig, np.uint8)
+    return LaneBatch(
+        [bytes(m) for m, _, _ in items], keys, sigs, np.array(bad, np.intp)
+    )
+
+
+def _batch(form, items):
+    return LaneBatch.from_items(items) if form == "tuples" else _columns(items)
+
+
 def _ragged():
     return _items(np.random.default_rng(7).integers(0, 171, 37).tolist())
 
@@ -168,6 +201,25 @@ CASES = {
         4, pk=bytearray(_off_curve()),
     ),
     "longest_cap": lambda: _items([943, 1, 432]),
+    # in blocks of 16: refused lanes either side of both block edges
+    # and at the end of the ragged last block, of each kind
+    "bad_lanes_on_block_edges": lambda: _with(
+        _with(
+            _with(
+                _with(_with(_ragged(), 15, pk=b"k" * 31), 16, sig=b"s" * 65),
+                31, pk=_off_curve(),
+            ),
+            32, sig=b"",
+        ),
+        36, pk=_off_curve(),
+    ),
+    "three_whole_blocks": lambda: _with(
+        _items([3, 170, 90] * 16), 47, pk=b""
+    ),
+    "four_blocks_and_a_bucket_of_padding": lambda: _with(
+        _items(np.random.default_rng(9).integers(0, 48, 65).tolist()),
+        64, sig=b"\x01" * 63,
+    ),
 }
 
 
@@ -176,8 +228,9 @@ def shapes(monkeypatch):
     """Small buckets, and the device count and kernel form the case
     asks for (``_pack`` reads all three from its module)."""
 
-    def _set(mode, n_dev=1):
+    def _set(mode, n_dev=1, block=ops_ed.PACK_BLOCK):
         monkeypatch.setattr(ops_ed, "PAD_MIN", PAD_MIN)
+        monkeypatch.setattr(ops_ed, "PACK_BLOCK", block)
         monkeypatch.setattr(
             ops_ed, "PRECOMP_MAX_LANES", 0 if mode == "plain" else 4096
         )
@@ -201,17 +254,19 @@ def _same(got, want):
     for g, w in zip(got, want):
         assert type(g) is np.ndarray
         assert g.shape == w.shape and g.dtype == w.dtype
-        assert g.flags.c_contiguous
+        assert g.flags.c_contiguous and g.flags.owndata
         np.testing.assert_array_equal(g, w)
 
 
+@pytest.mark.parametrize("block", BLOCKS)
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize("case", CASES)
-def test_pack_hands_over_the_loops_arrays(case, mode, shapes):
-    shapes(mode)
+def test_pack_hands_over_the_loops_arrays(case, mode, form, block, shapes):
+    shapes(mode, block=block)
     items = CASES[case]()
     want, want_bad, lanes, cap = _loop_pack(items, mode)
-    fn, arrays, tuple_a, shardings, bad = ops_ed._pack(items)
+    fn, arrays, tuple_a, shardings, bad = ops_ed._pack(_batch(form, items))
     assert shardings is None
     _same(arrays, want)
     _same([bad], [want_bad])
@@ -231,15 +286,17 @@ def test_pack_hands_over_the_loops_arrays(case, mode, shapes):
     assert last["sharded"] is False and last["n_devices"] == 1
 
 
+@pytest.mark.parametrize("block", (ops_ed.PACK_BLOCK, 2))
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("mode", MODES)
-def test_pack_rounds_the_lanes_up_to_the_devices(mode, shapes):
+def test_pack_rounds_the_lanes_up_to_the_devices(mode, form, block, shapes):
     """Three devices: 8 lanes become 9, the program is the sharded
     one and the arrays go by its shardings."""
-    program, program_shardings = shapes(mode, n_dev=3)
+    program, program_shardings = shapes(mode, n_dev=3, block=block)
     items = _with(_items([10, 20, 30, 40, 50]), 2, pk=_off_curve())
     want, want_bad, lanes, cap = _loop_pack(items, mode, n_dev=3)
     assert lanes == 9
-    fn, arrays, _tuple_a, shardings, bad = ops_ed._pack(items)
+    fn, arrays, _tuple_a, shardings, bad = ops_ed._pack(_batch(form, items))
     _same(arrays, want)
     _same([bad], [want_bad])
     assert fn is program and shardings is program_shardings
@@ -258,6 +315,46 @@ def test_pack_expands_a_distinct_key_once(shapes, monkeypatch):
         lambda pk: calls.append(pk) or expand(pk),
     )
     items = _with(_with(_ragged(), 3, pk=_off_curve()), 30, pk=_off_curve())
-    ops_ed._pack(items)
+    ops_ed._pack(LaneBatch.from_items(items))
     assert sorted(calls) == sorted(_keys()[0] + (_off_curve(),))
     assert all(type(pk) is bytes for pk in calls)
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_from_items_gives_the_columns(case):
+    """The boundary's one pass over the tuples: the columns a lane at
+    a time gives, the messages as ``bytes`` (the callers' own
+    objects where they are)."""
+    items = CASES[case]()
+    got, want = LaneBatch.from_items(items), _columns(items)
+    assert len(got) == len(items)
+    for a, (b, _, _) in zip(got.msgs, items):
+        assert type(a) is bytes and a == b
+        assert a is b or type(b) is not bytes
+    for g, w in ((got.keys, want.keys), (got.sigs, want.sigs)):
+        assert g.shape == w.shape and g.dtype == np.uint8
+        assert g.flags.c_contiguous
+        np.testing.assert_array_equal(g, w)
+    assert got.bad.tolist() == want.bad.tolist()
+
+
+def test_verify_batch_async_takes_both_forms(shapes, monkeypatch):
+    """The boundary: tuples become columns inside the ``pack`` span,
+    columns pass; ``len`` of what was handed in counts the verdicts."""
+    shapes("plain")
+    packed = []
+    real = ops_ed._pack
+    monkeypatch.setattr(
+        ops_ed, "_pack", lambda batch: packed.append(batch) or real(batch)
+    )
+    monkeypatch.setattr(ops_ed, "_put", lambda arrays, tuple_a, sh: arrays)
+    monkeypatch.setattr(
+        ops_ed, "verify_core_jit",
+        lambda msgs, lens, *rest: np.ones(lens.shape, bool),
+    )
+    items = _with(_ragged(), 4, pk=b"short")
+    batch = LaneBatch.from_items(items)
+    for handed in (items, batch):
+        oks = ops_ed.verify_batch_async(handed).result()
+        assert oks.tolist() == [i != 4 for i in range(len(items))]
+    assert type(packed[0]) is LaneBatch and packed[1] is batch

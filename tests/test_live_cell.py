@@ -21,6 +21,7 @@ from benchmark.generators import commit_live
 from benchmark.tests import tiny
 from cometbft_tpu.crypto import batch as crypto_batch
 from cometbft_tpu.crypto import scheduler as sched_mod
+from cometbft_tpu.crypto.lanes import LaneBatch
 from cometbft_tpu.ops import ed25519 as ops_ed
 from cometbft_tpu.trace import global_tracer
 from cometbft_tpu.utils import device
@@ -169,7 +170,7 @@ def test_a_150_signature_ticket_meets_one_precomp_program(monkeypatch, one_devic
     for pad_min in (256, 128, 16_384):
         monkeypatch.setattr(ops_ed, "PAD_MIN", pad_min)
         for n in (150, 129, 101):
-            ops_ed._pack(_commit_items(n))
+            ops_ed._pack(LaneBatch.from_items(_commit_items(n)))
             d = ops_ed.LAST_DISPATCH
             shapes[pad_min, n] = (d["lanes"], d["mode"], d["cap"])
             assert d["sharded"] is False and d["backend_key"][0] == "xla"

@@ -16,6 +16,7 @@ from benchmark import lookup, mesh, opcount, record, reference, trace_reduce
 from benchmark.generators import commit_stream
 from cometbft_tpu.crypto import batch as crypto_batch
 from cometbft_tpu.crypto import scheduler as sched_mod
+from cometbft_tpu.crypto.lanes import LaneBatch
 from cometbft_tpu.ops import ed25519 as ops_ed
 from cometbft_tpu.trace import global_tracer
 from cometbft_tpu.utils import device
@@ -142,7 +143,7 @@ def test_pad_min_pin_gives_the_mesh_one_program(
     )
     shapes = []
     for n in (150, 512 * 101):
-        ops_ed._pack(_commit_items(n))
+        ops_ed._pack(LaneBatch.from_items(_commit_items(n)))
         d = ops_ed.LAST_DISPATCH
         assert d["sharded"] is True and d["n_devices"] == 4
         shapes.append((d["lanes"], d["mode"], d["cap"]))

@@ -3,10 +3,14 @@ of its validator set (types/validation._coalesce_lanes / _plan_commit /
 _CoalescedHandle._fold): held here to the plain per-signature statement
 of the rule, which stays in the single-commit verifiers.
 
-  * the lanes: ``_coalesce_lanes`` appends the items (same key objects,
-    same bytes, same order) that ``_collect_light_lanes`` appends a
-    commit at a time, and for ``light=False`` those of the
-    per-signature loop it replaced (kept below, verbatim);
+  * the lanes: ``_coalesce_lanes`` hands on, BY COLUMNS (a
+    crypto/lanes.LaneBatch: the sign bytes one a lane, key and
+    signature rows), the lanes (same keys, same bytes, same order) that
+    ``_collect_light_lanes`` appends a commit at a time, and for
+    ``light=False`` those of the per-signature loop it replaced (kept
+    below, verbatim); and as that loop's own tuples, the set's own key
+    objects, where a set holds another curve or an odd key or a commit
+    a signature that is not 64 bytes;
   * the verdicts: ``verify_commits_coalesced`` names the error type,
     the validator and the text that ``verify_commit_light`` /
     ``verify_commit`` raise for the same commit under the same lane
@@ -27,10 +31,16 @@ comparison see the same verdicts. The real route has its own tests
 import dataclasses
 import random
 
+import numpy as np
 import pytest
 
 from cometbft_tpu import types as T
-from cometbft_tpu.crypto.keys import Ed25519PrivKey
+from cometbft_tpu.crypto.keys import (
+    Ed25519PrivKey,
+    Ed25519PubKey,
+    Secp256k1PrivKey,
+)
+from cometbft_tpu.crypto.lanes import LaneBatch
 from cometbft_tpu.types import validation as V
 from cometbft_tpu.types.signature_cache import SignatureCache
 from cometbft_tpu.types.validator_set import Validator, ValidatorSet
@@ -50,24 +60,30 @@ class _Ticket:
         self.id, self._oks = id_, oks
 
     def result(self, timeout=None):
-        return all(self._oks) and bool(self._oks), self._oks
+        return len(self._oks) > 0 and all(self._oks), self._oks
 
 
 class _StubScheduler:
-    """``submit`` as the seam calls it: records the lanes it is handed
-    and refuses those whose signature is in ``refuse``."""
+    """``submit`` as the seam calls it: records the lanes it is handed,
+    tuples or columns, and refuses those whose signature is in
+    ``refuse``; a columnar ticket's verdicts are a bool array, as the
+    scheduler's are."""
 
     def __init__(self, refuse=()):
         self.refuse = set(refuse)
         self.submitted = []
 
     def submit(self, lanes, priority=None, label=""):
-        assert isinstance(lanes, list)
-        self.submitted.append(list(lanes))
-        return _Ticket(
-            len(self.submitted),
-            [sig not in self.refuse for _, _, sig in lanes],
-        )
+        if isinstance(lanes, LaneBatch):
+            self.submitted.append(lanes)
+            oks = np.array(
+                [row.tobytes() not in self.refuse for row in lanes.sigs]
+            )
+        else:
+            assert isinstance(lanes, list)
+            self.submitted.append(list(lanes))
+            oks = [sig not in self.refuse for _, _, sig in lanes]
+        return _Ticket(len(self.submitted), oks)
 
 
 @pytest.fixture
@@ -281,8 +297,24 @@ def _per_signature_fold(jobs, job_lanes, errors, oks):
     return errors
 
 
-def _same_items(got, want):
+def _same_items(got, want, form=None):
+    """``got``, columns or tuples (``form``: which it has to be),
+    holds the lanes ``want``, the per-signature tuples."""
     assert len(got) == len(want)
+    if form is not None:
+        assert isinstance(got, LaneBatch) == (form == "columns")
+    if isinstance(got, LaneBatch):
+        assert got.msgs == [sb for _, sb, _ in want]
+        assert all(type(sb) is bytes for sb in got.msgs)
+        for rows, width, field in (
+            (got.keys, 32, [pk.key_bytes for pk, _, _ in want]),
+            (got.sigs, 64, [sig for _, _, sig in want]),
+        ):
+            assert type(rows) is np.ndarray and rows.dtype == np.uint8
+            assert rows.shape == (len(want), width)
+            assert rows.tobytes() == b"".join(field)
+        assert len(got.bad) == 0  # none refused, by construction
+        return
     for (pk_a, sb_a, sig_a), (pk_b, sb_b, sig_b) in zip(got, want):
         assert pk_a is pk_b  # the set's own key object
         assert (sb_a, sig_a) == (sb_b, sig_b)
@@ -315,7 +347,7 @@ def test_planned_lanes_are_the_per_signature_lanes(scenario, light):
         ref_items, ref_lanes, ref_errors = _per_signature_lanes(
             CHAIN_ID, _fresh(jobs), light
         )
-        _same_items(items, ref_items)
+        _same_items(items, ref_items, "columns" if ref_items else "tuples")
         for j, (first, want, _) in enumerate(plans):
             got = [(first + k, i) for k, i in enumerate(want)]
             assert got == ref_lanes[j], (seed, j)
@@ -335,6 +367,92 @@ def test_planned_lanes_are_the_per_signature_lanes(scenario, light):
             except V.CommitVerifyError:
                 pass
         _same_items(items, one_by_one)
+
+
+def _odd_jobs(rng, odd):
+    """Plain commits of several sets, one of them with the odd thing:
+    (jobs, signatures the odd commit's verification refuses by form)."""
+    jobs = [_job(rng, "mixed_flags", height=20 + k) for k in range(5)]
+    height = 22
+    # equal powers: light verification reads the first five of seven
+    keys = rng.sample(_KEYS, 7)
+    if odd == "secp256k1_key":
+        keys[3] = Secp256k1PrivKey.generate().pub_key()
+    elif odd == "key_31_bytes":
+        keys[3] = Ed25519PubKey(rng.randbytes(31))
+    vs = ValidatorSet([Validator(k, 10) for k in keys])
+    bid, commit = _commit(rng, vs, height)
+    sizes = {
+        "signature_63_bytes": {1: 63},
+        "signatures_63_and_65_bytes": {0: 63, 1: 65},
+        "signature_empty": {0: 0},
+    }.get(odd, {})
+    for i, size in sizes.items():
+        commit = _replace_sig(commit, i, signature=rng.randbytes(size))
+    jobs[2] = (vs, bid, height, commit)
+    return jobs
+
+
+@pytest.mark.parametrize("light", [True, False], ids=["light", "full"])
+@pytest.mark.parametrize(
+    "odd",
+    ["signature_63_bytes", "signatures_63_and_65_bytes", "signature_empty",
+     "secp256k1_key", "key_31_bytes"],
+)
+def test_odd_input_takes_the_tuple_form(odd, light, monkeypatch):
+    """What the columns cannot hold goes as the per-signature tuples,
+    the whole batch of it, chosen by what the input IS; the errors are
+    the single-commit verifiers' under the same lane verdicts."""
+    for seed in range(4):
+        rng = random.Random(f"{odd}-{seed}")
+        jobs = _odd_jobs(rng, odd)
+        items, plans, errors = V._coalesce_lanes(CHAIN_ID, _fresh(jobs), light)
+        ref_items, ref_lanes, _ = _per_signature_lanes(
+            CHAIN_ID, _fresh(jobs), light
+        )
+        _same_items(items, ref_items, "tuples")
+        for j, (first, want, _) in enumerate(plans):
+            assert [(first + k, i) for k, i in enumerate(want)] == ref_lanes[j]
+        # the plain commits alone are held by columns
+        plain, _, _ = V._coalesce_lanes(
+            CHAIN_ID, _fresh(jobs[:2] + jobs[3:]), light
+        )
+        assert isinstance(plain, LaneBatch)
+        # a lane of the wrong size is refused, as the device path does
+        refuse = {
+            sig for pk, _, sig in ref_items
+            if len(sig) != 64 or len(pk.key_bytes) not in (32, 33)
+        } | _refusals(rng, jobs[:2], light, "last")
+        want = [_single(CHAIN_ID, job, light, refuse, monkeypatch) for job in jobs]
+        sched = _StubScheduler(refuse)
+        monkeypatch.setattr(V.crypto_sched, "scheduler", lambda: sched)
+        got = V.verify_commits_coalesced(CHAIN_ID, _fresh(jobs), light=light)
+        for (_, _, height, _), g, w in zip(jobs, got, want):
+            if w is None:
+                assert g is None, str(g)
+            else:
+                assert type(g) is type(w)
+                assert str(g) == _coalesced_text(w, height)
+        assert len(sched.submitted) == 1
+        _same_items(sched.submitted[0], ref_items, "tuples")
+
+
+def test_a_run_of_the_set_is_a_slice_and_a_gap_a_take(stub):
+    """The key rows of a commit's lanes: a view of the set's array
+    where the lanes are one run of the set, a take where votes are
+    missing between them; either way the rows of the validators read."""
+    rng = random.Random(12)
+    vs = ValidatorSet([Validator(k, 10) for k in _KEYS[:10]])
+    rows = vs.columns().key_rows
+    for flags in ([COMMIT] * 10, [ABSENT, NIL] + [COMMIT] * 8,
+                  [COMMIT, ABSENT, COMMIT, NIL] + [COMMIT] * 6):
+        bid, commit = _commit(rng, vs, 3, flags)
+        lanes = V._SeamLanes()
+        want, _ = V._plan_commit(CHAIN_ID, vs, commit, True, lanes)
+        (part,) = lanes.key_parts
+        assert np.array_equal(part, rows[want])
+        run = want == list(range(want[0], want[-1] + 1))
+        assert (part.base is not None) == run
 
 
 def test_scenarios_hold_what_they_say():
@@ -575,7 +693,7 @@ def test_seam_with_a_cache_submits_and_leaves_what_per_lane_calls_did(
             assert _cache_state(cache) == _cache_state(ref_cache)
         assert len(sched.submitted) == len(ref_sched.submitted)
         for a, b in zip(sched.submitted, ref_sched.submitted):
-            _same_items(a, b)
+            _same_items(a, b, "columns")
         if size == 10_000:
             assert cache.hits > 0  # the windows did overlap
 
@@ -616,12 +734,37 @@ def _columns_from_scratch(vs):
     )
 
 
+def _lists(cols):
+    """The columns that are lists; ``key_rows`` is held to them."""
+    rows = cols.key_rows
+    assert rows.shape == (len(cols.pub_keys), 32) and rows.dtype == np.uint8
+    assert not rows.flags.writeable  # shared by every copy of the set
+    assert [r.tobytes() for r in rows] == [pk.key_bytes for pk in cols.pub_keys]
+    return cols.addresses, cols.pub_keys, cols.powers
+
+
 def test_columns_are_the_set_in_order_and_memoised():
     vs = _valset(random.Random(4), 10)
     cols = vs.columns()
-    assert tuple(cols) == _columns_from_scratch(vs)
+    assert _lists(cols) == _columns_from_scratch(vs)
     assert sum(cols.powers) == vs.total_voting_power()
     assert vs.columns() is cols
+
+
+@pytest.mark.parametrize("odd", ["secp256k1", "ed25519_31_bytes"])
+def test_a_set_with_an_odd_key_has_no_key_rows(odd):
+    key = (
+        Secp256k1PrivKey.generate().pub_key()
+        if odd == "secp256k1"
+        else Ed25519PubKey(b"k" * 31)
+    )
+    vs = ValidatorSet([Validator(k, 5) for k in _KEYS[:4]] + [Validator(key, 5)])
+    cols = vs.columns()
+    assert cols.key_rows is None
+    assert tuple(cols)[:3] == _columns_from_scratch(vs)
+    # and gets them back when the odd key leaves
+    vs.update_with_change_set([Validator(key, 0)])
+    assert _lists(vs.columns()) == _columns_from_scratch(vs)
 
 
 @pytest.mark.parametrize("change", ["power", "remove", "add", "key"])
@@ -644,8 +787,8 @@ def test_columns_follow_update_with_change_set(change):
     vs.update_with_change_set(delta)
     cols = vs.columns()
     assert cols is not before
-    assert tuple(cols) == _columns_from_scratch(vs)
-    assert tuple(cols) != tuple(before)
+    assert _lists(cols) == _columns_from_scratch(vs)
+    assert _lists(cols) != _lists(before)
 
 
 def test_columns_on_a_copy(stub):
@@ -653,14 +796,14 @@ def test_columns_on_a_copy(stub):
     bare = vs.copy()  # copied before the memo exists
     cols = vs.columns()
     twin = vs.copy()
-    assert tuple(twin.columns()) == tuple(cols) == tuple(bare.columns())
+    assert _lists(twin.columns()) == _lists(cols) == _lists(bare.columns())
     # a copy that moves on does not take the original with it
     twin.update_with_change_set([Validator(twin.validators[0].pub_key, 0)])
     assert twin.size() == 7 and len(twin.columns().addresses) == 7
     assert vs.columns() is cols and len(cols.addresses) == 8
     # rotation changes priorities only: the columns stand
     turned = vs.copy_increment_proposer_priority(3)
-    assert tuple(turned.columns()) == tuple(cols)
+    assert _lists(turned.columns()) == _lists(cols)
     # and the seam plans the moved-on copy from ITS columns
     rng = random.Random(8)
     bid, commit = _commit(rng, twin, 4)
@@ -668,6 +811,7 @@ def test_columns_on_a_copy(stub):
     _same_items(
         stub.submitted[-1],
         _per_signature_lanes(CHAIN_ID, [(twin, bid, 4, commit)], True)[0],
+        "columns",
     )
 
 

@@ -214,8 +214,9 @@ def test_ticket_leaves_every_stage_in_order(
     assert by_name[FOLD]["args"]["jobs"] == len(jobs)
     assert queue_wait["args"]["cls"] == "catchup"
     assert queue_wait["args"]["depth"] == 0
+    # the coalesced seam hands its lanes on by columns
     assert by_name[ROUTE]["args"] == {
-        "ticket": ticket, "lanes": lanes, "path": route,
+        "ticket": ticket, "lanes": lanes, "path": route, "form": "columns",
     }
     assert root["args"]["lanes"] == lanes
     # rows: a thread's role
@@ -224,6 +225,8 @@ def test_ticket_leaves_every_stage_in_order(
     if route == "device":
         assert by_name[PACK]["tid"] == "crypto.sched.dispatcher"
         assert by_name[PACK]["args"]["sigs"] == lanes
+        assert by_name[PACK]["args"]["form"] == "columns"
+        assert by_name[PACK]["args"]["blocks"] == 1
         assert by_name[DEVICE_WAIT]["tid"] == "crypto.sched.watcher"
         assert by_name[RESOLVE]["tid"] == "crypto.sched.watcher"
     else:
@@ -332,6 +335,7 @@ def test_pack_and_enqueue_say_what_last_dispatch_says(
             "ticket": ticket, "sigs": len(items), "lanes": lanes,
             "cap": last["cap"], "mode": last["mode"], "bad": refused,
             "devices": 1, "lanes_per_device": lanes,
+            "form": "tuples", "blocks": 1,
         }
         if last["precomp"]:
             # one distinct 32-byte key, new to the expanded-key LRU
@@ -403,3 +407,37 @@ def test_seam_spans_keep_their_names_rows_and_args(
     if cached:
         # heights 2 (its two good lanes) and 3 were fed by the first
         assert (cache.hits, cache.misses) == (5, 9 + 4)
+
+
+@pytest.mark.parametrize("form", ["tuples", "columns"])
+def test_route_and_pack_say_the_form_and_pack_its_blocks(
+    form, chain, ring, fresh_scheduler, stubbed_kernel, monkeypatch
+):
+    """``form`` on ``crypto.sched.route`` and ``ops.ed25519.pack``:
+    how the ticket's lanes arrived (``verify_commit`` hands tuples, the
+    coalesced seam columns); ``blocks``: in how many blocks of
+    ``PACK_BLOCK`` lanes ``_pack`` transposed them."""
+    from cometbft_tpu.types.validation import verify_commit
+
+    _route("device")
+    monkeypatch.setattr(ops_ed, "PACK_BLOCK", 4)
+    chain_id, jobs = _jobs(chain, range(1, 4))
+    if form == "columns":
+        handle = verify_commits_coalesced_async(chain_id, jobs)
+        assert handle.result() == [None] * 3
+        lanes = 9  # light: 3 of 4 equal validators, three commits
+    else:
+        vals, block_id, height, commit = jobs[0]
+        verify_commit(chain_id, vals, block_id, height, commit)
+        lanes = 4
+    by_name = {
+        e["name"]: e for e in ring.snapshot()
+        if e["name"] in (ROUTE, PACK)
+    }
+    assert by_name[ROUTE]["args"]["form"] == form
+    assert by_name[PACK]["args"]["form"] == form
+    assert by_name[PACK]["args"]["sigs"] == lanes
+    assert by_name[PACK]["args"]["blocks"] == -(-lanes // 4)
+    stats = sched_mod.scheduler().stats()
+    assert stats["columnar_tickets"] == (form == "columns")
+    assert stats["tickets"] == 1

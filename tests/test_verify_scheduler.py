@@ -11,6 +11,7 @@ under test is the scheduler's routing, merging, and degrade paths.
 import threading
 import time
 
+import numpy as np
 import pytest
 
 from cometbft_tpu.crypto import batch as crypto_batch
@@ -18,6 +19,7 @@ from cometbft_tpu.crypto import parallel_verify as pv
 from cometbft_tpu.crypto import scheduler as sched_mod
 from cometbft_tpu.crypto.batch import CpuBatchVerifier
 from cometbft_tpu.crypto.keys import Ed25519PrivKey, Secp256k1PrivKey
+from cometbft_tpu.crypto.lanes import LaneBatch
 from cometbft_tpu.crypto.scheduler import (
     PRIORITY_CATCHUP,
     PRIORITY_LIGHT,
@@ -94,6 +96,11 @@ class FakeDeviceHandle:
     def __init__(self, ed_items):
         from cometbft_tpu.crypto.keys import Ed25519PubKey
 
+        if isinstance(ed_items, LaneBatch):  # the lanes by columns
+            ed_items = [
+                (m, k.tobytes(), s.tobytes())
+                for m, k, s in zip(ed_items.msgs, ed_items.keys, ed_items.sigs)
+            ]
         self.verdicts = [
             Ed25519PubKey(pk).verify(msg, sig)
             for msg, pk, sig in ed_items
@@ -336,6 +343,140 @@ def test_mesh_below_the_floor_stays_on_the_host(
     st = sched.stats()
     assert st["degraded"] == 0 and st["device_dispatches"] == 0
     assert st["host_chunks"] >= 1
+
+
+# --- columnar tickets ----------------------------------------------------
+
+
+def as_columns(items):
+    """The ed25519 lanes ``items`` as the verify seam hands them on."""
+    return LaneBatch(
+        [msg for _, msg, _ in items],
+        np.frombuffer(
+            b"".join(pk.key_bytes for pk, _, _ in items), np.uint8
+        ).reshape(-1, 32),
+        np.frombuffer(
+            b"".join(sig for _, _, sig in items), np.uint8
+        ).reshape(-1, 64),
+    )
+
+
+@pytest.mark.parametrize(
+    "route", ["host", "device", "device-dispatch-fails", "device-resolve-fails"]
+)
+def test_columnar_and_tuple_tickets_give_the_same_oks(
+    route, sched, restore_routing, monkeypatch
+):
+    """The same lanes by columns and as tuples: the same verdicts in
+    submission order on the host plane, on the device and down both
+    degrade paths; a columnar ticket's are a bool array (indexable,
+    listable), it is counted, and class and depth are as a tuple
+    ticket's."""
+    import cometbft_tpu.ops.ed25519 as ops_ed
+
+    if route == "host":
+        crypto_batch.set_default_backend("cpu")
+    else:
+        crypto_batch.set_default_backend("mesh")
+        crypto_batch.set_min_tpu_batch(1)
+        set_device_count(monkeypatch, 8)
+    handed = []
+
+    class Unreadable(FakeDeviceHandle):
+        def result(self):
+            raise RuntimeError("verdicts lost")
+
+    def fake_async(ed_items):
+        handed.append(ed_items)
+        if route == "device-dispatch-fails":
+            raise RuntimeError("no XLA for you")
+        handle = Unreadable if route == "device-resolve-fails" else FakeDeviceHandle
+        return handle(ed_items)
+
+    monkeypatch.setattr(ops_ed, "verify_batch_async", fake_async)
+    items = make_items(23, bad={0, 9, 22})
+    want_all, want = serial_verdicts(items)
+    batch = as_columns(items)
+    with sched._cv:  # both queued before the dispatcher pops either
+        a = sched.submit(items, priority=PRIORITY_LIGHT, label="tuples")
+        b = sched.submit(batch, priority=PRIORITY_LIGHT, label="columns")
+    assert (a.depth_ahead, b.depth_ahead) == (0, 23)
+    assert a.priority == b.priority == PRIORITY_LIGHT
+    got_all, got = a.result(timeout=60)
+    col_all, col = b.result(timeout=60)
+    assert (got_all, got) == (want_all, want) and type(got) is list
+    assert type(col) is np.ndarray and col.dtype == bool
+    assert col_all is want_all and list(col) == want == col.tolist()
+    assert [bool(col[i]) for i in range(len(col))] == want
+    assert b.items is batch and not a.columnar and b.columnar
+    assert a.backend == b.backend
+    st = sched.stats()
+    assert st["columnar_tickets"] == 1 and st["tickets"] == 2
+    assert st["lanes"] == 46
+    if route == "host":
+        assert handed == []
+    else:
+        # the tuple ticket's lanes as (msg, key_bytes, sig); the
+        # columnar ticket's batch as it was handed in
+        assert type(handed[0]) is list and handed[1] is batch
+        assert st["degraded"] == (2 if route == "device-dispatch-fails" else 0)
+
+
+def test_columnar_ticket_is_not_split_by_curve(sched, cpu_backend, monkeypatch):
+    """``_plan`` on a columnar ticket: every lane ed25519 by
+    construction, no pass over them (a tuple ticket's other curves
+    still verify inline)."""
+    seen = []
+    real = crypto_batch.decide
+    monkeypatch.setattr(
+        crypto_batch, "decide", lambda n: seen.append(n) or real(n)
+    )
+    mixed = make_items(10, mixed=True)
+    ed_only = [it for it in mixed if it[0].type_ == "ed25519"]
+    t = VerifyTicket(as_columns(ed_only), PRIORITY_CATCHUP, "")
+    path, ed_idx, ed_items = sched._plan(t)
+    assert ed_idx == range(8) and ed_items is t.items
+    t = VerifyTicket(mixed, PRIORITY_CATCHUP, "")
+    path, ed_idx, ed_items = sched._plan(t)
+    assert ed_idx == [0, 1, 2, 3, 5, 6, 7, 8]
+    assert ed_items == [(m, pk.key_bytes, s) for pk, m, s in ed_only]
+    assert t.oks[[4, 9]].all()  # the other curve, verified inline
+    assert seen == [8, 8]
+
+
+def test_a_commit_gains_no_attribute_from_a_coalesced_verify(cpu_backend):
+    """Through the real scheduler, by columns: what the seam saves it
+    saves on the first visit of a Commit (a node sees one once)."""
+    import dataclasses
+
+    from cometbft_tpu import types as T
+    from cometbft_tpu.types import validation
+    from cometbft_tpu.node.inprocess import make_genesis
+    from cometbft_tpu.utils.chaingen import make_chain
+
+    gen, pvs = make_genesis(4, chain_id="sched-columns")
+    src = make_chain(gen, [pv.priv_key for pv in pvs], 4)
+    try:
+        vs, store = gen.validator_set(), src.block_store
+        jobs = [
+            (vs, store.load_block_meta(h).block_id, h, store.load_seen_commit(h))
+            for h in range(1, 4)
+        ]
+        before = sched_mod.scheduler().stats()["columnar_tickets"]
+        for _ in range(2):
+            errors = validation.verify_commits_coalesced(gen.chain_id, jobs)
+            assert errors == [None] * 3
+        stats = sched_mod.scheduler().stats()
+        assert stats["columnar_tickets"] == before + 2
+        fields = {f.name for f in dataclasses.fields(T.Commit)}
+        for _, _, _, commit in jobs:
+            assert set(vars(commit)) - fields <= {"_sb_parts", "_raw_bytes"}
+            for cs in commit.signatures:
+                assert set(vars(cs)) == {
+                    f.name for f in dataclasses.fields(cs)
+                }
+    finally:
+        src.close_stores()
 
 
 # --- observability -------------------------------------------------------
