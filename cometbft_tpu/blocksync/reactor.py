@@ -118,10 +118,19 @@ class BlockSyncReactor:
         self._task: Optional[asyncio.Task] = None
         self._stopped = False
         # tracing plane (trace/): node wiring swaps in the per-node
-        # tracer; last_window_bps feeds the Prometheus window-
-        # throughput gauge (utils/metrics.py)
+        # tracer (the pool's bans land on it too); last_window_bps
+        # feeds the Prometheus window-throughput gauge
+        # (utils/metrics.py)
         self.tracer = TRACE_NOOP
         self.last_window_bps = 0.0
+
+    @property
+    def tracer(self):
+        return self.pool.tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        self.pool.tracer = tracer
 
     # --- lifecycle ----------------------------------------------------
 
@@ -175,9 +184,13 @@ class BlockSyncReactor:
             window = self.pool.peek_window(self.window * 2)
             if len(window) < 2:
                 # held across the await (like verify_wait below): the
-                # loop's wait for the peer, which no window span covers
+                # loop's wait for the peer, which no window span covers.
+                # With blocks buffered it is a wait for the HEAD, at the
+                # peer named: the window's other heights have come
                 sp = self.tracer.annotated_span(
-                    "blocksync.window.fetch_wait", tid="blocksync"
+                    "blocksync.window.fetch_wait", tid="blocksync",
+                    buffered=len(self.pool.blocks),
+                    head_peer=self.pool.head_peer(),
                 )
                 try:
                     await self.pool.wait_for_block()

@@ -322,6 +322,8 @@ class Tracer:
             s[_SEQ] = None
             s[_NAME] = None
             s[_ARGS] = None
+        # an empty ring has dropped nothing: stats() counts from here
+        self._count = itertools.count()
 
 
 # --- the verify ticket a thread is working for ---------------------------

@@ -258,11 +258,12 @@ def test_pool_soft_exclusion_steers_retry():
     pool = BlockPool(1)
     # direct peer construction: set_peer_range spawns requester tasks,
     # which needs a running loop this sync test doesn't have
+    # 'fast' has delivered at ten times the rate 'slow' has
     pool.peers["fast"] = PoolPeer(
-        "fast", object(), base=1, height=100, latency_ewma=0.01
+        "fast", object(), base=1, height=100, bytes=5_000_000, busy_s=1.0
     )
     pool.peers["slow"] = PoolPeer(
-        "slow", object(), base=1, height=100, latency_ewma=0.9
+        "slow", object(), base=1, height=100, bytes=500_000, busy_s=1.0
     )
     # un-excluded: fastest wins
     assert pool._pick_peer(5).peer_id == "fast"
