@@ -91,22 +91,19 @@ def _straus(ds, dh, A, shape):
     ds / dh: (64, N) int32 window digits, LSB-first."""
     # backend precedence: GRAFT_PALLAS=1/0 forces pallas/XLA; unset =
     # pallas by default on accelerator backends at bulk widths only
-    # (>= pallas_ladder.min_lanes(), the r5-measured win region — the
-    # interpreter stands in off-TPU), else compact on the CPU backend,
-    # else the tuple-form XLA ladder. Every branch condition here is
-    # part of _ladder_backend_key so a mid-process flip retraces (the
-    # width itself re-keys via the per-shape jit trace).
-    if len(shape) == 1 and shape[0] % 128 == 0:
-        from .pallas_ladder import pallas_enabled, straus_pallas
+    # (>= pallas_ladder.min_lanes(), whose docstring holds the chip's
+    # readings — the interpreter stands in off-TPU), else compact on
+    # the CPU backend, else the tuple-form XLA ladder. Every branch
+    # condition here is part of _ladder_backend_key so a mid-process
+    # flip retraces (the width itself re-keys via the per-shape jit
+    # trace). pallas_ladder.ladder_for is the one test: the dispatch's
+    # ``ladder`` tag (_pack) reports what it decides here, so a width
+    # with no VMEM-safe blocking is tagged, and run, as XLA.
+    if len(shape) == 1:
+        from .pallas_ladder import ladder_for, straus_pallas
 
-        if pallas_enabled(shape[0]):
-            res = straus_pallas(ds, dh, A, shape)
-            if res is not None:
-                return res
-            # no VMEM-safe blocking exists for this width (e.g. a
-            # large prime sublane count like r=513 under the default
-            # cap): fall through to the compact/XLA ladder, as the
-            # straus_pallas docstring promises (ADVICE r5 medium)
+        if ladder_for(shape[0]) == "pallas":
+            return straus_pallas(ds, dh, A, shape)
     if fe.compact_mode():
         return _straus_compact(ds, dh, A, shape)
     ident = curve.identity(shape)
@@ -533,7 +530,9 @@ def verify_batch_async(items) -> AsyncVerdicts:
     forms ``keys`` counts the dispatch's distinct keys and ``expanded``
     those ``_expand_pubkey`` did not find in ``_A_CACHE``) and
     ``ops.ed25519.enqueue`` (placement of the host arrays, its child
-    span ``ops.ed25519.put``, and the jitted call)."""
+    span ``ops.ed25519.put``, and the jitted call). Both say the
+    ``ladder`` the program runs the dispatch's lanes on, ``pallas`` or
+    ``xla`` (pallas_ladder.ladder_for)."""
     n = len(items)
     if n == 0:
         return AsyncVerdicts(np.zeros(0, bool), np.zeros(0, bool), 0)
@@ -551,7 +550,7 @@ def verify_batch_async(items) -> AsyncVerdicts:
         d = LAST_DISPATCH
         sp.set(
             lanes=d["lanes"], cap=d["cap"], mode=d["mode"],
-            bad=int(np.count_nonzero(bad)),
+            ladder=d["ladder"], bad=int(np.count_nonzero(bad)),
             devices=d["n_devices"],
             lanes_per_device=d["lanes"] // d["n_devices"],
             blocks=-(-n // PACK_BLOCK),
@@ -562,7 +561,7 @@ def verify_batch_async(items) -> AsyncVerdicts:
     nbytes = sum(a.nbytes for a in arrays)
     with tr.annotated_span(
         "ops.ed25519.enqueue", tid=tid, ticket=ticket,
-        lanes=d["lanes"], bytes=nbytes,
+        lanes=d["lanes"], ladder=d["ladder"], bytes=nbytes,
     ):
         with tr.annotated_span(
             "ops.ed25519.put", tid=tid, ticket=ticket,
@@ -681,15 +680,12 @@ def _pack(batch: LaneBatch):
         for rows in (m_rows, batch.keys, sigs[:, :32], sigs[:, 32:])
     )
 
-    # backend_key[0] reports the ladder the kernel ACTUALLY uses at
-    # this dispatch's per-device width (pallas engages by default only
-    # at bulk widths — pallas_ladder.min_lanes — and only on
-    # 128-multiple lanes), not merely whether pallas may engage
-    from .pallas_ladder import interpret_mode
-    from .pallas_ladder import pallas_enabled as _pallas_on
+    # the ladder the kernel runs at this dispatch's per-device width
+    # (pallas_ladder.ladder_for, the test _straus applies), not merely
+    # whether pallas may engage
+    from .pallas_ladder import interpret_mode, ladder_for
 
-    lane_w = np_ // n_dev
-    eff_pallas = lane_w % 128 == 0 and _pallas_on(lane_w)
+    ladder = ladder_for(np_ // n_dev)
     LAST_DISPATCH.clear()
     LAST_DISPATCH.update(
         sharded=sharded is not None,
@@ -698,10 +694,10 @@ def _pack(batch: LaneBatch):
         cap=cap,
         precomp=use_precomp,
         mode=mode,
-        backend_key=("pallas" if eff_pallas else "xla",)
-        + _ladder_backend_key()[1:],
+        ladder=ladder,
+        backend_key=(ladder,) + _ladder_backend_key()[1:],
         # the Pallas INTERPRETER ran the ladder (CPU platform only)
-        interpret=eff_pallas and interpret_mode(),
+        interpret=ladder == "pallas" and interpret_mode(),
     )
     if use_precomp:
         LAST_DISPATCH.update(keys=len(uniq), expanded=expanded)

@@ -58,27 +58,27 @@ def block_sublanes() -> int:
 
 def min_lanes() -> int:
     """Width floor for the default-on pallas ladder (bulk widths
-    only). The builders' r5 first contact put the VMEM ladder at 2.5x
-    the XLA ladder at 131072 lanes and found the two indistinguishable
-    at replay widths (<=32768 lanes); neither has been re-measured on
-    the current chip (ROADMAP S4), so small widths stay on the XLA
-    ladder by default. Compile cost is no longer a reason either way:
-    on a v5e the whole verify program costs ~13 min per lane bucket
-    with either ladder (757 s XLA at 32768 lanes, 773 s Pallas at
-    65536), the Mosaic kernel alone compiles in seconds, and the
-    persistent compilation cache hits for both — the Pallas program
-    only from the same checkout path with the kernel's sources
-    unmoved, because Mosaic's payload keeps their MLIR locations
-    (PERF.md, PR 24)."""
+    only). The chip's readings (TPU v5e device trace, PERF.md): at
+    16,384 lanes this ladder's verify program took 17.08 ms against
+    the XLA ladder's 50.13 on one chip, 16.97 against 67.76 a device
+    on four, but took longer to trace and lower (156.5 against 105.8 s
+    of set-up) and left the host to set the pace, so the cells built
+    at 16,384 lanes keep the XLA ladder; the floor stays where
+    ``val150.verify-only.512`` runs this ladder, 65,536 lanes on one
+    chip: 64.68 ms a dispatch, 37.80 of them the ladder. Compile cost
+    is no reason either way: ~13 min a lane bucket cold with either
+    ladder (757 s XLA at 32,768 lanes, 785 s Pallas at 65,536); the
+    compilation cache hits for both, the Pallas program only from the
+    same checkout path with the kernel's sources unmoved (Mosaic's
+    payload keeps their MLIR locations)."""
     return int(os.environ.get("GRAFT_PALLAS_MIN_LANES", "65536"))
 
 
 def pallas_enabled(n: "int | None" = None) -> bool:
-    """Ladder backend selection, r5-measured policy: GRAFT_PALLAS=1
-    forces pallas at every width (tests, A/B legs), GRAFT_PALLAS=0
-    forces the XLA ladder; otherwise pallas is the DEFAULT on
-    accelerator backends at bulk widths (n >= min_lanes(), where the
-    r5 silicon A/B measured 2.5x) and off elsewhere. Read dynamically
+    """Ladder backend selection: GRAFT_PALLAS=1 forces pallas at
+    every width (tests, A/B legs), GRAFT_PALLAS=0 forces the XLA
+    ladder; otherwise pallas is the DEFAULT on accelerator backends at
+    bulk widths (n >= min_lanes()) and off elsewhere. Read dynamically
     AND safely flippable mid-process: the verify jit wrappers are
     keyed by (ladder backend, field mode, sublanes, min-lanes) —
     ops/ed25519._ladder_backend_key — so an env flip reaches the next
@@ -261,8 +261,8 @@ def straus_pallas(ds, dh, A, shape, interpret=None):
     so the GRAFT_PALLAS backend flip is exercisable on any platform.
 
     Returns None when no VMEM-safe blocking exists for this width
-    (effective_block) — the caller (ops/ed25519._straus) falls back
-    to the XLA ladder rather than building an unbounded VMEM block.
+    (effective_block) rather than build an unbounded VMEM block; there
+    ladder_for says ``xla`` and ops/ed25519._straus runs that ladder.
     """
     (n,) = shape
     assert n % 128 == 0, n
@@ -303,3 +303,16 @@ def straus_pallas(ds, dh, A, shape, interpret=None):
         tuple(out[2, i] for i in range(fe.NLIMBS)),
         None,
     )
+
+
+def ladder_for(n: int) -> str:
+    """``"pallas"`` where ops/ed25519._straus runs ``n`` lanes on this
+    kernel, else ``"xla"``: ``n`` a multiple of 128, pallas_enabled(n),
+    and a VMEM-safe blocking (effective_block). The one test, shared by
+    _straus and the dispatch's ``ladder`` tag, so the tag never says
+    pallas where the XLA ladder ran."""
+    if n % 128 or not pallas_enabled(n):
+        return "xla"
+    if effective_block(block_sublanes(), n // 128) is None:
+        return "xla"
+    return "pallas"

@@ -502,7 +502,9 @@ def test_lookup_finds_the_cell_its_metrics_and_leaves_the_others_theirs():
     assert per_layer[:-5] == [
         m["name"] for m in lookup.metrics_for(spec, "val150.catchup", "per_layer")
     ]
-    for m in spec["per_layer"][-5:]:
+    links = [m for m in spec["per_layer"] if m["name"] in NEW_METRICS]
+    assert [m["name"] for m in links] == list(NEW_METRICS)
+    for m in links:
         assert m["moves"] == "catchup_rate" and m["workloads"] == [CELL]
         assert m["layer"] in ("entry: blocksync/pool.py", "entry: blocksync/reactor.py")
     for other in (w["name"] for w in spec["workloads"] if w["name"] != CELL):

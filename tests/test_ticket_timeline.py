@@ -333,7 +333,8 @@ def test_pack_and_enqueue_say_what_last_dispatch_says(
         lanes = last["lanes"]
         want_args = {
             "ticket": ticket, "sigs": len(items), "lanes": lanes,
-            "cap": last["cap"], "mode": last["mode"], "bad": refused,
+            "cap": last["cap"], "mode": last["mode"],
+            "ladder": last["ladder"], "bad": refused,
             "devices": 1, "lanes_per_device": lanes,
             "form": "tuples", "blocks": 1,
         }
@@ -347,7 +348,8 @@ def test_pack_and_enqueue_say_what_last_dispatch_says(
         if last["precomp"]:
             want_bytes += 4 * 20 * 4 * lanes
         assert enqueue["args"] == {
-            "ticket": ticket, "lanes": lanes, "bytes": want_bytes,
+            "ticket": ticket, "lanes": lanes, "ladder": last["ladder"],
+            "bytes": want_bytes,
         }
         assert pack["tid"] == enqueue["tid"] == tid
         assert enqueue["ts_ns"] >= pack["ts_ns"] + pack["dur_ns"]
